@@ -1,24 +1,8 @@
-"""JAX version compatibility shims.
-
-The library targets the current ``jax.shard_map`` (with its ``check_vma``
-kwarg), but must also run on jax releases where shard_map still lives at
-``jax.experimental.shard_map.shard_map`` and the same kwarg is spelled
-``check_rep``. Every module imports :func:`shard_map` from here instead of
-from ``jax`` directly.
+"""The one place ``shard_map`` is imported from (52 modules do), kept so
+those imports stay as they are. There is one supported JAX (``jax>=0.9``,
+``pyproject.toml``): the top-level ``jax.shard_map`` with ``check_vma``.
 """
 
-from __future__ import annotations
+from jax import shard_map
 
 __all__ = ["shard_map"]
-
-try:  # current jax: top-level export, kwarg ``check_vma``
-    from jax import shard_map as shard_map  # type: ignore[attr-defined]
-except ImportError:  # older jax: experimental home, kwarg ``check_rep``
-    from jax.experimental.shard_map import shard_map as _exp_shard_map
-
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma=None, **kwargs):
-        if check_vma is not None and "check_rep" not in kwargs:
-            kwargs["check_rep"] = check_vma
-        return _exp_shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs
-        )

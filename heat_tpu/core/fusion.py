@@ -3582,6 +3582,9 @@ class _TracedStep:
         record = program_cache().get_custom(
             key, lambda: self._build(args, treedef, metas))
         primed = record.out_meta is not None  # this program ran before
+        # a donated tree reused after its step is refused HERE, before
+        # dispatch, for primed and first-call programs alike
+        refuse_deleted(phys, "trace_step")
         try:
             _faults().check("fusion.step.dispatch" if primed
                             else "fusion.step.trace")
@@ -3766,6 +3769,20 @@ def trace_step(fn, donate_argnums=(), block=True):
     return _TracedStep(fn, donate_argnums, block=block)
 
 
+def refuse_deleted(args, who):
+    """Raise before dispatch if any of ``args`` is an already-deleted
+    (donated) buffer. The runtime would refuse it too, but on jax 0.9
+    XLA:CPU a multi-device executable refused at dispatch leaves the
+    process unable to complete the NEXT multi-device program — the one
+    deadlock that used to cut tier-1 at its limit."""
+    for a in args:
+        if getattr(a, "is_deleted", lambda: False)():
+            raise RuntimeError(
+                f"{who}: an input buffer has been deleted — a donated "
+                "argument was reused after the call that consumed it; "
+                "rebind the outputs (p, l = step(p, ...))")
+
+
 # ---------------------------------------------------------------------- #
 # tape-compiled analytics fit steps                                      #
 # ---------------------------------------------------------------------- #
@@ -3801,6 +3818,7 @@ def fit_step_call(key, build, args, eager):
         prog = program_cache().get_custom(
             full_key, lambda: build(qk, ck, hk))
         _faults().check("fit.step.dispatch")
+        refuse_deleted(args, "fit_step_call")
         out = prog(*args)
     except Exception:
         for a in args:

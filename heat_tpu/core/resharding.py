@@ -155,7 +155,17 @@ def gspmd_reshard_fn(phys_shape, jdt, gshape, from_split, to_split, comm):
             x = _pad_axis(x, to_split, comm.padded_size(gshape[to_split]))
         return x
 
-    fn = jax.jit(_go, out_shardings=out_sharding)
+    if any(s == 0 for s in gshape):
+        # a zero-size result holds no data: XLA makes the empty output
+        # replicated whatever ``out_shardings`` asks, and jax >= 0.9
+        # asserts on that override. Nothing moves, so compute the empty
+        # shape and place it under the target layout directly.
+        go = jax.jit(_go)
+
+        def fn(x):
+            return jax.device_put(go(x), out_sharding)
+    else:
+        fn = jax.jit(_go, out_shardings=out_sharding)
     _GSPMD_CACHE[key] = fn
     return fn
 

@@ -105,6 +105,7 @@ def engine_call(key, build, args, eager, *, site="data.exchange.dispatch",
     try:
         prog = _CACHE.get_custom(full_key, lambda: build(qk, ck, hk))
         _faults.check(site)
+        fusion.refuse_deleted(args, "data.engine_call")
         out = prog(*args)
     except Exception:
         for a in args:

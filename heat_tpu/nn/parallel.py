@@ -184,18 +184,6 @@ def switch_moe(x, router_w, expert_up_shard, expert_down_shard, *, axis: str,
 # GPipe pipeline parallelism                                            #
 # --------------------------------------------------------------------- #
 
-def vma_capable() -> bool:
-    """Whether this jax can express varying-across-mesh-axes (vma/rep)
-    typing — the single capability gate for keeping identity psums whose
-    only job is clearing an axis-varying type (``pipeline_apply``'s
-    pp==1 branch, ``TransformerLM._psum_tp``). Superset probe: any of
-    the vma-era APIs present means the typing system may be live."""
-    import jax as _jax
-
-    return (hasattr(_jax, "typeof") or hasattr(lax, "pcast")
-            or hasattr(lax, "pvary"))
-
-
 def pipeline_apply(stage_fn: Callable, stage_params, x_micro, *, axis: str):
     """Run ``pp`` pipeline stages over microbatches (per-device, shard_map).
 
@@ -247,26 +235,15 @@ def pipeline_apply(stage_fn: Callable, stage_params, x_micro, *, axis: str):
         # degenerate pipeline: run the stage per microbatch (scan, not vmap —
         # the stage may contain collectives over other axes). The identity
         # psum clears the axis-varying type the (pp-sharded) stage params
-        # impart under vma tracking, matching the pp>1 branch's out type;
-        # without vma tracking it is a pure identity that still lowers to
-        # a singleton-group all-reduce PAIR through forward+backward —
-        # skip it there so the packed train step's collective audit stays
-        # exactly the plan's count (same capability gate as below)
+        # impart under vma tracking, matching the pp>1 branch's out type
+        # (audits count it with ``communicating_collective_stats``, which
+        # ignores singleton-group all-reduces)
         _, out = lax.scan(
             lambda c, xm: (c, stage_fn(stage_params, xm)), 0, x_micro)
-        if vma_capable():
-            out = lax.psum(out, axis)
-        return out
+        return lax.psum(out, axis)
 
-    # initial carries are device-varying (they hold per-stage activations);
-    # on jax versions without vma tracking (no pcast/pvary) the annotation
-    # is unnecessary and the identity is correct
-    if hasattr(lax, "pcast"):
-        _vary = partial(lax.pcast, to="varying")
-    elif hasattr(lax, "pvary"):
-        _vary = lax.pvary
-    else:
-        _vary = lambda x, _axis: x  # noqa: E731
+    # initial carries are device-varying (they hold per-stage activations)
+    _vary = partial(lax.pcast, to="varying")
     out_buf = _vary(jnp.zeros_like(x_micro), axis)
     recv = _vary(jnp.zeros_like(x_micro[0]), axis)
 

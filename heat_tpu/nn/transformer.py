@@ -327,13 +327,10 @@ class TransformerLM:
         return q, k, v
 
     def _psum_tp(self, x, wire=None):
-        """The Megatron-block tp reduction — skipped on tp=1 grids when
-        the jax has no vma tracking: a size-1-axis psum is a value
-        identity but still lowers to a (singleton-group) all-reduce pair
-        through forward+backward. Under vma tracking the identity psum
-        stays — ``check_vma=True`` needs it to clear the tp-varying type
-        (the SAME capability gate as ``pipeline_apply``'s pp==1 branch:
-        :func:`heat_tpu.nn.parallel.vma_capable`).
+        """The Megatron-block tp reduction. On tp=1 grids the psum is a
+        value identity (a singleton-group all-reduce) and stays:
+        ``check_vma=True`` needs it to clear the tp-varying type, as in
+        ``pipeline_apply``'s pp==1 branch.
 
         ``wire``: a ``(quant_key, chunk_key, hier_key)`` triple pinned by
         a builder that cache-keyed on it (the serving decode engine) —
@@ -349,11 +346,7 @@ class TransformerLM:
             qk, ck, hk = wire
             return fusion.packed_psum([x], ("tp",), quant=qk, chunks=ck,
                                       hier=hk)[0]
-        from .parallel import vma_capable
-
-        if self.tp > 1 or vma_capable():
-            return lax.psum(x, "tp")
-        return x
+        return lax.psum(x, "tp")
 
     def _attn_residual(self, p, x, attn, wire=None):
         """Row-parallel output projection (one tp psum) + residual."""
@@ -671,8 +664,16 @@ class TransformerLM:
                 out_specs=(specs, P(), P()),
                 check_vma=False)
             jitted = jax.jit(sm, donate_argnums=(0, 1))
+            replicated = NamedSharding(self.grid.mesh, P())
 
             def step(params, opt_state, toks):
+                # ``tx.init(params)`` makes its scalars (adam's count) off
+                # the mesh and its moments laid out like the params; this
+                # program emits the state replicated ON the mesh. jax >= 0.9
+                # types arrays by their mesh, so without this placement
+                # step 2 re-traces and compiles the whole step a second
+                # time. Already-placed leaves pass through untouched.
+                opt_state = jax.device_put(opt_state, replicated)
                 out = jitted(params, opt_state, toks)
                 # the model-level fused step counts like a traced step
                 # (DataParallel's packed path does the same), so the

@@ -15,7 +15,7 @@ pins the mirrored-counter namespace to ``serve.program_hits`` /
 ``_misses`` / ``_compiles`` regardless of the cache's display name — the
 adapters build executors with per-model cache names ("transformer", the
 estimator class), and the ladder's per-test ``serve_program_compiles``
-log line (NEXT.md §2b correlation) must keep counting all of them under
+log line (the per-process executable budget correlation) must keep counting all of them under
 one family, as it always has.
 """
 

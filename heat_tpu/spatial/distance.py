@@ -170,7 +170,7 @@ def _ring_kernel(X, Y, tile_fn, expand, jdt, comm, metric_key):
                 # single-device (the bench configuration): the tile IS the
                 # whole output — the zeros buffer + dynamic_update_slice +
                 # final slice of the general ring would each risk a full
-                # extra pass over the n*m matrix (PERF_r04.md §cdist)
+                # extra pass over the n*m matrix
                 return tile_fn(x_blk, y_cur, expand)[:, :m]
             me = jax.lax.axis_index(axis)
             out = jnp.zeros((x_blk.shape[0], m_pad), jdt)

@@ -380,7 +380,7 @@ def _mesh_sizes():
 # one shared model/toks/params per mesh size for the WHOLE class: the
 # transformer step programs are the largest compiles in this module, and
 # per-process executable count is a suite-wide budget under watch
-# (NEXT.md §2b — an XLA:CPU compile near the END of a full tier-1 run
+# (the per-process executable budget — an XLA:CPU compile near the END of a full tier-1 run
 # crashes when the accumulated state crosses the box's threshold, so
 # every test here reuses the same compiled set instead of re-lowering)
 _ACCEPT: dict = {}

@@ -28,7 +28,7 @@ The contract under test, per the overload-robustness tentpole:
   volume on the low-priority tenant, hi-p99 within its SLO. The full
   1x/2x ladder/bench form lives in ``scripts/soak_serve.py``.
 
-NEXT.md §2b discipline: one shared elemwise model program family + one
+the per-process executable budget discipline: one shared elemwise model program family + one
 shared ProgramCache across the module, tiny bucket ladders, and a
 module teardown that drops the cache and gc-collects.
 """

@@ -103,7 +103,18 @@ MIX = ((3, 6), (9, 3), (5, 10), (12, 4), (7, 8), (4, 2))
 def test_greedy_matches_generate_mixed_lengths():
     """THE acceptance parity: continuous batching with mixed prompt and
     output lengths produces, per request, exactly generate()'s greedy
-    tokens (prompt + continuation)."""
+    tokens (prompt + continuation).
+
+    CPU-ONLY ORACLE. The engine (S_cap-row cache, bucketed prefill) and
+    generate() (Sb+max_new rows, one scan) are differently shaped
+    programs; their greedy tokens are bitwise-equal here because XLA:CPU
+    computes both in float32. On the TPU (bf16 compute, default matmul
+    precision) an argmax flips on rounding alone — measured on a v5e at
+    d1024/L8: 1 token of 3 requests differed while both programs stayed
+    within 0.0083 logits of the float32 reference's maximum (PR 27). The
+    on-chip check is therefore logit-level (``chip_smoke.py`` decode
+    phase, ``tests/test_reference.py``); never port this equality to the
+    chip."""
     with _engine() as eng:
         eng.warmup()
         futs = [eng.submit(_prompt(40 + i, s0), mn)
