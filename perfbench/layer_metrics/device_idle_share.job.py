@@ -1,0 +1,8 @@
+"""Device: 1 - (union of the devices' operation intervals) / traced window,
+mean over the chips used, in the analytics cells."""
+
+from perfbench.trace_reduce import idle_share_percent
+
+
+def read(run):
+    return idle_share_percent(run.trace) if run.trace else None

@@ -1,0 +1,55 @@
+"""Operations and bytes that the algorithms NEED, from shapes alone.
+
+The counts are of the mathematics, not of the program: recomputation, padding
+rows, a second pass over the data or an upcast that the compiler did not fuse
+are the program's business and lower the share it reaches.
+"""
+
+from __future__ import annotations
+
+
+def lm_matmul_params(cfg: dict) -> int:
+    """Parameters that sit in matrix multiplications of one forward pass of
+    the causal LM: per layer the fused QKV (d x 3d), the output projection
+    (d x d) and the two MLP matrices (d x f, f x d), plus the unembedding
+    (d x V). The embedding table is a gather and the norm scales are
+    elementwise: neither counts."""
+    d, f = cfg["hidden_size"], cfg["intermediate_size"]
+    per_layer = d * 3 * d + d * d + 2 * d * f
+    return cfg["num_hidden_layers"] * per_layer + d * cfg["vocab_size"]
+
+
+def lm_forward_flops_per_token(cfg: dict, context: float) -> float:
+    """Forward FLOPs for ONE token that attends over ``context`` positions
+    (itself included): 2 per matrix parameter, plus per layer QK^T and PV at
+    2 * d FLOPs per attended position each."""
+    d, L = cfg["hidden_size"], cfg["num_hidden_layers"]
+    return 2.0 * lm_matmul_params(cfg) + L * 4.0 * d * context
+
+
+def lm_train_flops_per_token(cfg: dict, seq: int) -> float:
+    """Forward + backward of a causal sequence of ``seq`` tokens, per token:
+    three times the forward, whose mean attended context is (seq + 1) / 2.
+    Recomputed forwards (remat) do not count."""
+    return 3.0 * lm_forward_flops_per_token(cfg, (seq + 1) / 2.0)
+
+
+def lm_decode_flops(cfg: dict, prompt_len: int, n_out: int) -> float:
+    """Forward FLOPs one request NEEDS: a causal prefill of its prompt (the
+    first output token comes from it) and one cached step per further
+    output token, the i-th of which attends over prompt_len + i positions."""
+    prefill = prompt_len * lm_forward_flops_per_token(
+        cfg, (prompt_len + 1) / 2.0)
+    steps = n_out - 1
+    mean_ctx = prompt_len + (steps + 1) / 2.0
+    return prefill + steps * lm_forward_flops_per_token(cfg, mean_ctx)
+
+
+def lloyd_bytes_per_iteration(rows: int, features: int, itemsize: int) -> int:
+    """Bytes ONE Lloyd iteration has to move on one device: one read of its
+    rows of X. Centroids, sums and counts are k x features and vanish."""
+    return rows * features * itemsize
+
+
+def mfu_percent(flops: float, seconds: float, chips: int, peak: float) -> float:
+    return 100.0 * flops / (seconds * chips * peak)
