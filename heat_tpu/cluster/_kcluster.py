@@ -28,6 +28,7 @@ import jax.numpy as jnp
 from ..core import factories, fusion, random as ht_random, types
 from ..core.base import BaseEstimator, ClusteringMixin
 from ..core.dndarray import DNDarray
+from ..utils.profiling import span
 
 __all__ = ["_KCluster"]
 
@@ -113,8 +114,11 @@ class _KCluster(ClusteringMixin, BaseEstimator):
         it = 0
         aux = None
         for it in range(1, self.max_iter + 1):
-            centroids, shift, aux = step(xp, centroids)
-            if self._converged(float(shift)):
+            with span("kmeans.iter", it=it):
+                centroids, shift, aux = step(xp, centroids)
+                with span("kmeans.sync"):
+                    shift = float(shift)    # the host waits for the device
+            if self._converged(shift):
                 break
         return centroids, aux, it
 

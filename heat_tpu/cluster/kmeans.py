@@ -32,6 +32,7 @@ from ..core.dndarray import DNDarray
 from ..core import fusion, types
 from ..core.pallas_kernels import (kmeans_step_tile, kmeans_pallas_enabled,
                                    _kmeans_sums_mode, _kmeans_block_rows)
+from ..utils.profiling import scope, span
 from ._kcluster import _KCluster
 
 __all__ = ["KMeans"]
@@ -43,6 +44,7 @@ _STEP_CACHE: dict = {}
 _acc_dtype = types.accumulation_dtype
 
 
+@scope("lloyd.update")
 def _finish_update(sums, counts, centroids):
     """Centroid division + empty-cluster keep + shift (replicated inputs).
     Runs in the accumulation dtype; the returned centroids match the
@@ -61,24 +63,29 @@ def _lloyd_partial(xp, centroids, valid, k, jdt, acc):
     ``(rows, 1)`` bool row mask (canonical padding / chunk tail); the
     same function serves the global GSPMD body, the shard_map block body
     and the streaming partial program."""
-    xf = xp.astype(acc)
-    x2 = jnp.sum(xf * xf, axis=1, keepdims=True)
-    cacc = centroids.astype(acc)
-    c2 = jnp.sum(cacc * cacc, axis=1, keepdims=True).T
-    xc = jax.lax.dot_general(
-        xp, centroids.astype(jdt),
-        dimension_numbers=(((1,), (1,)), ((), ())),
-        preferred_element_type=acc)
-    d2 = x2 + c2 - 2.0 * xc  # (rows, k) distances in acc
-    labels = jnp.argmin(d2, axis=1)
-    onehot = (labels[:, None] == jnp.arange(k)[None, :]) & valid
-    counts = jnp.sum(onehot.astype(acc), axis=0)  # (k,)
-    sums = jax.lax.dot_general(  # (k, d) GEMM
-        onehot.astype(jdt), xp,
-        dimension_numbers=(((0,), (0,)), ((), ())),
-        preferred_element_type=acc)
-    inertia = jnp.sum(jnp.where(valid[:, 0], jnp.min(d2, axis=1),
-                                jnp.zeros((), acc)))
+    with scope("lloyd.norms"):
+        xf = xp.astype(acc)
+        x2 = jnp.sum(xf * xf, axis=1, keepdims=True)
+        cacc = centroids.astype(acc)
+        c2 = jnp.sum(cacc * cacc, axis=1, keepdims=True).T
+    with scope("lloyd.dist"):
+        xc = jax.lax.dot_general(
+            xp, centroids.astype(jdt),
+            dimension_numbers=(((1,), (1,)), ((), ())),
+            preferred_element_type=acc)
+        d2 = x2 + c2 - 2.0 * xc  # (rows, k) distances in acc
+    with scope("lloyd.argmin"):
+        labels = jnp.argmin(d2, axis=1)
+    with scope("lloyd.sums"):
+        onehot = (labels[:, None] == jnp.arange(k)[None, :]) & valid
+        counts = jnp.sum(onehot.astype(acc), axis=0)  # (k,)
+        sums = jax.lax.dot_general(  # (k, d) GEMM
+            onehot.astype(jdt), xp,
+            dimension_numbers=(((0,), (0,)), ((), ())),
+            preferred_element_type=acc)
+    with scope("lloyd.inertia"):
+        inertia = jnp.sum(jnp.where(valid[:, 0], jnp.min(d2, axis=1),
+                                    jnp.zeros((), acc)))
     return sums, counts, inertia
 
 
@@ -94,7 +101,7 @@ def _make_step_body(phys_shape, jdt, k, n_valid, comm, sums_mode,
         chunk = phys_shape[0] // comm.size
         axis = comm.axis_name
 
-        def device_step(xp_blk, centroids):
+        def lloyd_step(xp_blk, centroids):
             rank = jax.lax.axis_index(axis)
             row = rank * chunk + jax.lax.broadcasted_iota(
                 jnp.int32, (chunk, 1), 0)
@@ -102,21 +109,22 @@ def _make_step_body(phys_shape, jdt, k, n_valid, comm, sums_mode,
             sums, counts, inertia = kmeans_step_tile(
                 xp_blk, centroids, mask, block_rows=block_rows,
                 sums_mode=sums_mode)
-            sums = jax.lax.psum(sums, axis)
-            counts = jax.lax.psum(counts, axis)
-            inertia = jax.lax.psum(inertia, axis)
+            with scope("lloyd.psum"):
+                sums = jax.lax.psum(sums, axis)
+                counts = jax.lax.psum(counts, axis)
+                inertia = jax.lax.psum(inertia, axis)
             new_centroids, shift = _finish_update(sums, counts, centroids)
             return new_centroids, inertia, shift
 
         return shard_map(
-            device_step, mesh=comm.mesh,
+            lloyd_step, mesh=comm.mesh,
             in_specs=(comm.spec(2, 0), P()),
             out_specs=(P(), P(), P()),
             check_vma=False)
 
     acc = _acc_dtype(jdt)
 
-    def _step(xp, centroids):
+    def lloyd_step(xp, centroids):
         # valid-row mask for canonical padding; elementwise consumers
         # cast in-register (HBM reads stay bf16 for half-precision
         # storage); GEMMs take the narrow inputs at MXU rate and
@@ -128,7 +136,7 @@ def _make_step_body(phys_shape, jdt, k, n_valid, comm, sums_mode,
         new_centroids, shift = _finish_update(sums, counts, centroids)
         return new_centroids, inertia, shift
 
-    return _step
+    return lloyd_step
 
 
 def _use_pallas_step(jdt) -> bool:
@@ -174,7 +182,7 @@ def _lloyd_fused_fn(phys_shape, jdt, k, n_valid, comm, qk, ck, hk):
     chunk = phys_shape[0] // comm.size
     axis = comm.axis_name
 
-    def device_step(xp_blk, centroids):
+    def lloyd_step(xp_blk, centroids):
         rank = jax.lax.axis_index(axis)
         row = rank * chunk + jax.lax.broadcasted_iota(
             jnp.int32, (chunk, 1), 0)
@@ -186,14 +194,15 @@ def _lloyd_fused_fn(phys_shape, jdt, k, n_valid, comm, qk, ck, hk):
         else:
             sums, counts, inertia = _lloyd_partial(
                 xp_blk, centroids, row < n_valid, k, jdt, acc)
-        sums, counts, inertia = fusion.packed_psum(
-            [sums, counts, inertia], (axis,), quant=qk, chunks=ck,
-            hier=hk)
+        with scope("lloyd.psum"):
+            sums, counts, inertia = fusion.packed_psum(
+                [sums, counts, inertia], (axis,), quant=qk, chunks=ck,
+                hier=hk)
         new_centroids, shift = _finish_update(sums, counts, centroids)
         return new_centroids, shift, inertia
 
     fn = jax.jit(
-        shard_map(device_step, mesh=comm.mesh,
+        shard_map(lloyd_step, mesh=comm.mesh,
                   in_specs=(comm.spec(2, 0), P()),
                   out_specs=(P(), P(), P()), check_vma=False),
         donate_argnums=(1,))
@@ -213,11 +222,11 @@ def _lloyd_fused_gspmd_fn(phys_shape, jdt, k, n_valid, comm, qk, ck, hk):
         return fn
     single = _make_step_body(phys_shape, jdt, k, n_valid, comm, False)
 
-    def step(xp, centroids):
+    def lloyd_step(xp, centroids):
         new_centroids, inertia, shift = single(xp, centroids)
         return new_centroids, shift, inertia
 
-    fn = jax.jit(step, donate_argnums=(1,))
+    fn = jax.jit(lloyd_step, donate_argnums=(1,))
     _STEP_CACHE[key] = fn
     return fn
 
@@ -257,19 +266,20 @@ def _stream_partial_fn(phys_shape, jdt, k, comm, split, qk, ck, hk):
         chunk = phys_shape[0] // comm.size
         axis = comm.axis_name
 
-        def pbody(xp_blk, centroids, n_valid, s_acc, c_acc, i_acc):
+        def lloyd_stream(xp_blk, centroids, n_valid, s_acc, c_acc, i_acc):
             rank = jax.lax.axis_index(axis)
             row = rank * chunk + jax.lax.broadcasted_iota(
                 jnp.int32, (chunk, 1), 0)
             sums, counts, inertia = _lloyd_partial(
                 xp_blk, centroids, row < n_valid, k, jdt, acc)
-            sums, counts, inertia = fusion.packed_psum(
-                [sums, counts, inertia], (axis,), quant=qk, chunks=ck,
-                hier=hk)
+            with scope("lloyd.psum"):
+                sums, counts, inertia = fusion.packed_psum(
+                    [sums, counts, inertia], (axis,), quant=qk, chunks=ck,
+                    hier=hk)
             return s_acc + sums, c_acc + counts, i_acc + inertia
 
         fn = jax.jit(
-            shard_map(pbody, mesh=comm.mesh,
+            shard_map(lloyd_stream, mesh=comm.mesh,
                       in_specs=(comm.spec(2, 0), P(), P(), P(), P(), P()),
                       out_specs=(P(), P(), P()), check_vma=False),
             donate_argnums=(3, 4, 5))
@@ -285,13 +295,13 @@ def _stream_partial_eager(phys_shape, jdt, k):
     chunk program's eager degrade path."""
     acc = _acc_dtype(jdt)
 
-    def pbody(xp, centroids, n_valid, s_acc, c_acc, i_acc):
+    def lloyd_stream(xp, centroids, n_valid, s_acc, c_acc, i_acc):
         row = jax.lax.broadcasted_iota(jnp.int32, (phys_shape[0], 1), 0)
         sums, counts, inertia = _lloyd_partial(
             xp, centroids, row < n_valid, k, jdt, acc)
         return s_acc + sums, c_acc + counts, i_acc + inertia
 
-    return pbody
+    return lloyd_stream
 
 
 def _stream_partial_legacy_fn(phys_shape, jdt, k):
@@ -318,25 +328,29 @@ def _assign_fn(phys_shape, jdt, k, n_valid, comm):
 
         acc = _acc_dtype(jdt)
 
-        def _assign(xp, centroids):
+        def lloyd_assign(xp, centroids):
             row = jax.lax.broadcasted_iota(jnp.int32, (phys_shape[0],), 0)
             valid = row < n_valid
-            cacc = centroids.astype(acc)
-            c2 = jnp.sum(cacc * cacc, axis=1)[None, :]
-            xc = jax.lax.dot_general(
-                xp, centroids.astype(jdt),
-                dimension_numbers=(((1,), (1,)), ((), ())),
-                preferred_element_type=acc)
-            scores = c2 - 2.0 * xc
-            labels = jnp.argmin(scores, axis=1)
-            xf = xp.astype(acc)
-            x2 = jnp.sum(xf * xf, axis=1)
-            inertia = jnp.sum(
-                jnp.where(valid, x2 + jnp.min(scores, axis=1),
-                          jnp.zeros((), acc)))
+            with scope("lloyd.norms"):
+                cacc = centroids.astype(acc)
+                c2 = jnp.sum(cacc * cacc, axis=1)[None, :]
+                xf = xp.astype(acc)
+                x2 = jnp.sum(xf * xf, axis=1)
+            with scope("lloyd.dist"):
+                xc = jax.lax.dot_general(
+                    xp, centroids.astype(jdt),
+                    dimension_numbers=(((1,), (1,)), ((), ())),
+                    preferred_element_type=acc)
+                scores = c2 - 2.0 * xc
+            with scope("lloyd.argmin"):
+                labels = jnp.argmin(scores, axis=1)
+            with scope("lloyd.inertia"):
+                inertia = jnp.sum(
+                    jnp.where(valid, x2 + jnp.min(scores, axis=1),
+                              jnp.zeros((), acc)))
             return labels, inertia
 
-        fn = jax.jit(_assign)
+        fn = jax.jit(lloyd_assign)
         _STEP_CACHE[key] = fn
     return fn
 
@@ -361,7 +375,7 @@ def _lloyd_fori_fn(phys_shape, jdt, k, n_valid, comm):
             chunk = phys_shape[0] // comm.size
             axis = comm.axis_name
 
-            def _run_device(xp_blk, centroids, iters):
+            def lloyd_fori(xp_blk, centroids, iters):
                 rank = jax.lax.axis_index(axis)
                 row = rank * chunk + jax.lax.broadcasted_iota(
                     jnp.int32, (chunk, 1), 0)
@@ -372,9 +386,10 @@ def _lloyd_fori_fn(phys_shape, jdt, k, n_valid, comm):
                     sums, counts, inertia = kmeans_step_tile(
                         xp_blk, c, mask, block_rows=block_rows,
                         sums_mode=sums_mode)
-                    sums = jax.lax.psum(sums, axis)
-                    counts = jax.lax.psum(counts, axis)
-                    inertia = jax.lax.psum(inertia, axis)
+                    with scope("lloyd.psum"):
+                        sums = jax.lax.psum(sums, axis)
+                        counts = jax.lax.psum(counts, axis)
+                        inertia = jax.lax.psum(inertia, axis)
                     new_c, shift = _finish_update(sums, counts, c)
                     return new_c, inertia, shift
 
@@ -382,7 +397,7 @@ def _lloyd_fori_fn(phys_shape, jdt, k, n_valid, comm):
                 return jax.lax.fori_loop(0, iters, body, (centroids, z, z))
 
             fn = jax.jit(shard_map(
-                _run_device, mesh=comm.mesh,
+                lloyd_fori, mesh=comm.mesh,
                 in_specs=(comm.spec(2, 0), P(), P()),
                 out_specs=(P(), P(), P()),
                 check_vma=False))
@@ -390,7 +405,7 @@ def _lloyd_fori_fn(phys_shape, jdt, k, n_valid, comm):
             single = _make_step_body(phys_shape, jdt, k, n_valid, comm,
                                      sums_mode)
 
-            def _run(xp, centroids, iters):
+            def lloyd_fori(xp, centroids, iters):
                 def body(_, carry):
                     c, _, _ = carry
                     return single(xp, c)
@@ -400,7 +415,7 @@ def _lloyd_fori_fn(phys_shape, jdt, k, n_valid, comm):
                     0, iters, body, (centroids, z, z))
                 return c, inertia, shift
 
-            fn = jax.jit(_run)
+            fn = jax.jit(lloyd_fori)
         _STEP_CACHE[key] = fn
     return fn
 
@@ -474,6 +489,10 @@ class KMeans(_KCluster):
             raise TypeError(f"input needs to be a DNDarray, but was {type(x)}")
         if x.ndim != 2:
             raise ValueError("input needs to be 2-dimensional (n_samples, n_features)")
+        with span("kmeans.fit"):
+            return self._fit(x)
+
+    def _fit(self, x: DNDarray) -> "KMeans":
         if x.split not in (None, 0):
             x = x.resplit(0)
 
@@ -490,13 +509,14 @@ class KMeans(_KCluster):
         centroids, _, it = self._run_lloyd(step, xp, centroids)
 
         self._cluster_centers = DNDarray.from_logical(centroids, None, x.device, x.comm)
-        labels, inertia = _assign_fn(
-            xp.shape, jdt, self.n_clusters, n, x.comm)(xp, centroids)
-        self._labels = DNDarray(
-            labels, (n,), types.canonical_heat_type(labels.dtype), 0 if x.split == 0 else None,
-            x.device, x.comm,
-        )
-        self._inertia = float(inertia)
+        with span("kmeans.assign"):
+            labels, inertia = _assign_fn(
+                xp.shape, jdt, self.n_clusters, n, x.comm)(xp, centroids)
+            self._labels = DNDarray(
+                labels, (n,), types.canonical_heat_type(labels.dtype),
+                0 if x.split == 0 else None, x.device, x.comm,
+            )
+            self._inertia = float(inertia)
         self._n_iter = it
         return self
 

@@ -237,6 +237,8 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from ..utils import profiling as _prof
+
 __all__ = [
     "enabled",
     "set_enabled",
@@ -1636,6 +1638,11 @@ def _flush(root: _Node) -> None:
 
 
 def _flush_locked(root: _Node) -> None:
+    with _prof.span("flush") as sp:
+        _flush_spanned(root, sp)
+
+
+def _flush_spanned(root: _Node, sp) -> None:
     order, in_refs = _topo(root)
     has_reduce = any(n.kind == "reduce" for n in order)
     has_contract = any(n.kind == "contract" for n in order)
@@ -1765,8 +1772,9 @@ def _flush_locked(root: _Node) -> None:
             from ._compat import shard_map
 
             sched, instrs, phases, in_specs, out_specs = sm
-            fn = shard_map(replay, mesh=comm.mesh, in_specs=in_specs,
-                           out_specs=out_specs, check_vma=False)
+            fn = shard_map(_prof.named(replay, "flush"), mesh=comm.mesh,
+                           in_specs=in_specs, out_specs=out_specs,
+                           check_vma=False)
             jitted = jax.jit(fn)
         else:
             def replay(*leaf_vals):
@@ -1777,7 +1785,8 @@ def _flush_locked(root: _Node) -> None:
                     vals.append(fn(*args, **kwargs))
                 return tuple(vals[i] for i in out_idx)
 
-            jitted = jax.jit(replay, donate_argnums=donate)
+            jitted = jax.jit(_prof.named(replay, "flush"),
+                             donate_argnums=donate)
         if _capture_hlo:
             global _last_hlo
             try:
@@ -1788,10 +1797,18 @@ def _flush_locked(root: _Node) -> None:
                 pass
         return jitted
 
+    def spanned_build():
+        with _prof.span("flush.build"):
+            return build()
+
+    sp.set(n_nodes=len(order))
     try:
-        program = program_cache().get_custom(key, build)
+        misses = program_cache().misses
+        program = program_cache().get_custom(key, spanned_build)
+        sp.set(hit=program_cache().misses == misses)
         _faults().check("fusion.flush.dispatch")
-        results = program(*leaves)
+        with _prof.span("flush.dispatch"):
+            results = program(*leaves)
     except Exception:
         # HARDENED FAILURE DOMAIN (doc/robustness.md): a failed fused
         # compile or dispatch must not strand the tape. No node has been
@@ -3562,6 +3579,10 @@ class _TracedStep:
     def __call__(self, *args, **kwargs):
         if not (_ENABLED and _STEP):
             return self.fn(*args, **kwargs)
+        with _prof.span("traced_step") as sp:
+            return self._call(sp, args, kwargs)
+
+    def _call(self, sp, args, kwargs):
         try:
             flat, treedef = jax.tree_util.tree_flatten((args, kwargs),
                                                        is_leaf=_isdnd)
@@ -3582,13 +3603,18 @@ class _TracedStep:
         record = program_cache().get_custom(
             key, lambda: self._build(args, treedef, metas))
         primed = record.out_meta is not None  # this program ran before
+        sp.set(hit=primed)
         # a donated tree reused after its step is refused HERE, before
         # dispatch, for primed and first-call programs alike
         refuse_deleted(phys, "trace_step")
         try:
             _faults().check("fusion.step.dispatch" if primed
                             else "fusion.step.trace")
-            results = record.jitted(*phys)
+            # the first call of a signature traces and compiles inside the
+            # jitted call: that one is `prime`, every later one `dispatch`
+            with _prof.span("traced_step.dispatch" if primed
+                            else "traced_step.prime"):
+                results = record.jitted(*phys)
         except Exception:
             if primed:
                 # a previously-successful program failed at DISPATCH
@@ -3698,6 +3724,7 @@ class _TracedStep:
             return tuple(oarrs)
 
         donate = self._donate_slots(args, metas)
+        _prof.named(pure, "traced_step")
         if self.block:
             record[0] = _StepRecord(jax.jit(pure, donate_argnums=donate))
         else:
@@ -3812,22 +3839,28 @@ def fit_step_call(key, build, args, eager):
     switch), callers run their legacy step programs and never reach
     here — see :func:`fit_enabled`.
     """
-    qk, ck, hk = quant_key(), chunk_key(), hier_key()
-    full_key = ("fit",) + tuple(key) + (qk, ck, hk)
-    try:
-        prog = program_cache().get_custom(
-            full_key, lambda: build(qk, ck, hk))
-        _faults().check("fit.step.dispatch")
-        refuse_deleted(args, "fit_step_call")
-        out = prog(*args)
-    except Exception:
-        for a in args:
-            if getattr(a, "is_deleted", lambda: False)():
-                raise  # donated buffer already invalidated — no replay
-        _metrics().inc("op_engine.fit_step_fallbacks")
-        return eager(*args)
-    _metrics().inc("op_engine.fit_step_flushes")
-    return out
+    with _prof.span("fit_step") as sp:
+        qk, ck, hk = quant_key(), chunk_key(), hier_key()
+        full_key = ("fit",) + tuple(key) + (qk, ck, hk)
+        try:
+            misses = program_cache().misses
+            with _prof.span("fit_step.lookup"):
+                prog = program_cache().get_custom(
+                    full_key, lambda: build(qk, ck, hk))
+            sp.set(hit=program_cache().misses == misses)
+            _faults().check("fit.step.dispatch")
+            refuse_deleted(args, "fit_step_call")
+            with _prof.span("fit_step.dispatch"):
+                out = prog(*args)
+        except Exception:
+            for a in args:
+                if getattr(a, "is_deleted", lambda: False)():
+                    raise  # donated buffer already invalidated — no replay
+            _metrics().inc("op_engine.fit_step_fallbacks")
+            with _prof.span("fit_step.fallback"):
+                return eager(*args)
+        _metrics().inc("op_engine.fit_step_flushes")
+        return out
 
 
 # ---------------------------------------------------------------------- #

@@ -183,6 +183,7 @@ def cdist_tile(x, y, sqrt: bool = True, block_m: int = 256,
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (_i32(i), _i32(j))),
         out_shape=_sds((mp, np_), out_dtype, vma=_vma(xp, yp)),
+        name="cdist_tile",
         interpret=_interpret(),
     )(xp, yp)
     return out[:m, :n]
@@ -356,6 +357,7 @@ def _flash_impl(
             pltpu.VMEM((bq, 1), acc_dtype),
             pltpu.VMEM((bq, 1), acc_dtype),
         ],
+        name="flash_fwd",
         interpret=_interpret(),
     )(qf, kf, vf)
 
@@ -566,6 +568,7 @@ def _flash_bwd_impl(q, k, v, out, lse, dout, dlse, scale: float, causal: bool,
             pltpu.VMEM((bk, dp), acc_dtype),
             pltpu.VMEM((bk, dp), acc_dtype),
         ],
+        name="flash_bwd_dkv",
         interpret=_interpret(),
     )(qf, kf, vf, dof, lse_r, dmb_r)
 
@@ -585,6 +588,7 @@ def _flash_bwd_impl(q, k, v, out, lse, dout, dlse, scale: float, causal: bool,
         ],
         out_shape=[_sds((BH, sqp, dp), q.dtype, vma=vma)],
         scratch_shapes=[pltpu.VMEM((bq, dp), acc_dtype)],
+        name="flash_bwd_dq",
         interpret=_interpret(),
     )(qf, kf, vf, dof, lse_c, dmb_c)[0]
 
@@ -878,6 +882,7 @@ def _kmeans_step_tile(x, centroids, valid_mask, block_rows: int,
                        acc_dtype),
             pltpu.VMEM((8, 128), acc_dtype),  # scalar held in every lane (native tile)
         ],
+        name="kmeans_step_tile",
         interpret=_interpret(),
     )(xp, cp, maskp)
     return (sums[:k].astype(x.dtype), counts[0, :k].astype(x.dtype),

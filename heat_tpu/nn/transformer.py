@@ -47,6 +47,7 @@ from ..core._compat import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..core.communication import MeshGrid
+from ..utils.profiling import scope, span
 from .attention import (_ring_body, _ulysses_core, _zigzag_core,
                         local_attention, zigzag_layout, zigzag_unlayout)
 from .parallel import pipeline_apply, switch_moe
@@ -270,50 +271,67 @@ class TransformerLM:
         p = self._cast_params(p)
         q, k, v = self._qkv(p, x, pos)
         scale = 1.0 / math.sqrt(c.head_dim)
-        if c.attn_schedule == "zigzag" and sp_comm.size > 1:
-            # load-balanced causal ring: every sp device does identical live
-            # work per step. The token stream is ALREADY in zigzag layout —
-            # _loss_device relayouts once after embedding and inverts once
-            # before the loss, so each layer pays zero layout ppermutes
-            # (every non-attention op in the block is positionwise)
-            attn = _zigzag_core(q, k, v, comm=sp_comm, scale=scale)
-        elif c.attn_schedule == "ulysses" and sp_comm.size > 1:
-            # all_to_all head-parallel: two collectives per layer instead of
-            # sp-1 ppermute steps — often wins at moderate S on fast ICI
-            attn = _ulysses_core(q, k, v, comm=sp_comm, scale=scale,
-                                 causal=True)
-        else:
-            attn = _ring_body(q, k, v, comm=sp_comm, scale=scale, causal=True)
+        with scope("attn.core"):
+            if c.attn_schedule == "zigzag" and sp_comm.size > 1:
+                # load-balanced causal ring: every sp device does identical
+                # live work per step. The token stream is ALREADY in zigzag
+                # layout — _loss_device relayouts once after embedding and
+                # inverts once before the loss, so each layer pays zero
+                # layout ppermutes (every non-attention op in the block is
+                # positionwise)
+                attn = _zigzag_core(q, k, v, comm=sp_comm, scale=scale)
+            elif c.attn_schedule == "ulysses" and sp_comm.size > 1:
+                # all_to_all head-parallel: two collectives per layer
+                # instead of sp-1 ppermute steps — often wins at moderate S
+                # on fast ICI
+                attn = _ulysses_core(q, k, v, comm=sp_comm, scale=scale,
+                                     causal=True)
+            else:
+                attn = _ring_body(q, k, v, comm=sp_comm, scale=scale,
+                                  causal=True)
         x = self._attn_residual(p, x, attn)
 
-        m_in = _rmsnorm(x, p["ln2"])
         if c.moe_experts:
-            flat = m_in.reshape(mb * S_local, D)
-            # expert hidden dim is tp-sharded: partial down-projections sum
-            # over tp (one psum, mirroring the dense Megatron block)
-            moe_out = self._psum_tp(
-                switch_moe(
-                    flat, p["router"], p["w_up"], p["w_down"], axis="dp",
-                    capacity_factor=c.capacity_factor))
-            return x + moe_out.reshape(mb, S_local, D)
-        return self._dense_mlp_residual(p, x, m_in)
+            with scope("moe"):
+                flat = _rmsnorm(x, p["ln2"]).reshape(mb * S_local, D)
+                # expert hidden dim is tp-sharded: partial down-projections
+                # sum over tp (one psum, mirroring the dense Megatron block)
+                moe_out = self._psum_tp(
+                    switch_moe(
+                        flat, p["router"], p["w_up"], p["w_down"], axis="dp",
+                        capacity_factor=c.capacity_factor))
+                return x + moe_out.reshape(mb, S_local, D)
+        return self._dense_mlp_residual(p, x)
 
     # shared layer math — _block (training), the prefill pass and the
     # cached decode step (generate) all call these, so an architecture
     # change lands everywhere at once
 
-    def _cast_params(self, p):
+    @scope("cast")
+    def _stage_params(self, params):
+        """This device's stage out of the pp-stacked parameters (inside
+        shard_map the leading axis is 1). Under ``cast``: XLA fuses it with
+        the per-layer slice and cast that follow."""
+        return jax.tree.map(lambda a: a[0], params["stages"])
+
+    @scope("cast")
+    def _cast_params(self, p, layer=None):
         """Mixed precision: master params stay f32 in the optimizer; compute
         runs in compute_dtype (bf16 on real TPUs for MXU rate). Without this
         cast f32 params silently promote every activation back to f32 and
-        compute_dtype never takes effect."""
+        compute_dtype never takes effect. ``layer``: ``p`` is a stage's
+        stacked parameters, take that layer's out of the stack first (the
+        slice and the cast are one pass over the weights, under one scope)."""
         c = self.cfg
+        if layer is not None:
+            p = jax.tree.map(lambda a: a[layer], p)
         if c.compute_dtype == jnp.float32:
             return p
         return jax.tree.map(
             lambda a: a.astype(c.compute_dtype)
             if jnp.issubdtype(a.dtype, jnp.floating) else a, p)
 
+    @scope("attn.qkv")
     def _qkv(self, p, x, pos):
         """Pre-norm qkv projection for the local head subset, with rotary
         rotation by the GLOBAL positions ``pos``."""
@@ -348,15 +366,19 @@ class TransformerLM:
                                       hier=hk)[0]
         return lax.psum(x, "tp")
 
+    @scope("attn.proj")
     def _attn_residual(self, p, x, attn, wire=None):
         """Row-parallel output projection (one tp psum) + residual."""
         return x + self._psum_tp(
             jnp.einsum("bshk,hkd->bsd", attn, p["wproj"]), wire=wire)
 
-    def _dense_mlp_residual(self, p, x, m_in, wire=None):
-        h = jax.nn.gelu(m_in @ p["w_up"])
+    @scope("mlp")
+    def _dense_mlp_residual(self, p, x, wire=None):
+        """Pre-norm dense MLP (one tp psum) + residual."""
+        h = jax.nn.gelu(_rmsnorm(x, p["ln2"]) @ p["w_up"])
         return x + self._psum_tp(h @ p["w_down"], wire=wire)
 
+    @scope("head")
     def _head(self, params, h):
         """Final norm + unembed; logits upcast to f32 only after the GEMM —
         an f32 norm scale would push the largest matmul off the bf16 path."""
@@ -376,7 +398,8 @@ class TransformerLM:
                 f"local batch ({B_local}) must divide into n_micro ({c.n_micro})")
         mb = B_local // c.n_micro
 
-        x = params["embed"][toks].astype(c.compute_dtype)
+        with scope("embed"):
+            x = params["embed"][toks].astype(c.compute_dtype)
         zigzag = c.attn_schedule == "zigzag" and sp_comm.size > 1
         sp_idx = lax.axis_index("sp")
         if zigzag:
@@ -396,7 +419,7 @@ class TransformerLM:
             pos = sp_idx * S_local + jnp.arange(S_local)
         x_micro = x.reshape(c.n_micro, mb, S_local, c.d_model)
 
-        stage_params = jax.tree.map(lambda a: a[0], params["stages"])
+        stage_params = self._stage_params(params)
 
         def block(p_l, xm):
             return self._block(p_l, xm, sp_comm, pos)
@@ -413,11 +436,16 @@ class TransformerLM:
 
         def stage_fn(sp_params, xm):
             for l in range(self.layers_per_stage):
-                p_l = jax.tree.map(lambda a: a[l], sp_params)
+                with scope("cast"):
+                    p_l = jax.tree.map(lambda a: a[l], sp_params)
                 xm = block(p_l, xm)
             return xm
 
-        out = pipeline_apply(stage_fn, stage_params, x_micro, axis="pp")
+        # what the schedule itself costs (the scan's stacked residuals and
+        # gradient accumulators, the pp psum) reads under `pipeline`; the
+        # layers inside keep their own, inner scopes
+        with scope("pipeline"):
+            out = pipeline_apply(stage_fn, stage_params, x_micro, axis="pp")
         h = out.reshape(B_local, S_local, c.d_model)
         if zigzag:
             h = zigzag_unlayout(h, sp_comm)
@@ -446,19 +474,21 @@ class TransformerLM:
         else:
             nxt = first
         targets = jnp.concatenate([toks[:, 1:], nxt], axis=1)
-        logp = jax.nn.log_softmax(logits, axis=-1)
-        nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
-        # the global last position has no next token
-        is_last_shard = lax.axis_index(sp_axis) == sp - 1
-        pos_mask = jnp.arange(S_local) < S_local - 1
-        mask = jnp.where(is_last_shard, pos_mask, jnp.ones_like(pos_mask))
-        mask = jnp.broadcast_to(mask[None, :], nll.shape).astype(nll.dtype)
+        with scope("loss"):
+            logp = jax.nn.log_softmax(logits, axis=-1)
+            nll = -jnp.take_along_axis(
+                logp, targets[..., None], axis=-1)[..., 0]
+            # the global last position has no next token
+            is_last_shard = lax.axis_index(sp_axis) == sp - 1
+            pos_mask = jnp.arange(S_local) < S_local - 1
+            mask = jnp.where(is_last_shard, pos_mask, jnp.ones_like(pos_mask))
+            mask = jnp.broadcast_to(mask[None, :], nll.shape).astype(nll.dtype)
 
-        # the count is static — B_global rows each lose one position —
-        # which also keeps it out of the vma system (a mask-sum would be
-        # invarying over dp and unreducible there)
-        count = B_local * self.dp_world * (S_local * sp - 1)
-        return jnp.sum(nll * mask) / count
+            # the count is static — B_global rows each lose one position —
+            # which also keeps it out of the vma system (a mask-sum would be
+            # invarying over dp and unreducible there)
+            count = B_local * self.dp_world * (S_local * sp - 1)
+            return jnp.sum(nll * mask) / count
 
     def _data_axes(self):
         """The data axes (the loss psum scope): dp and sp, plus the dcn
@@ -527,19 +557,20 @@ class TransformerLM:
 
         axes = self._batch_axes()
 
-        def body(params, toks):
+        def loss_and_grad(params, toks):
             if qinfo is not None:
                 fusion.reset_qinfo(qinfo)
             lval, grads = jax.value_and_grad(
                 self._local_loss_device)(params, toks)
             leaves, treedef = jax.tree_util.tree_flatten(grads)
-            packed = fusion.packed_psum(leaves + [lval], axes, qinfo=qinfo,
-                                        quant=quant, chunks=chunks,
-                                        hier=hier)
+            with scope("grad_psum"):
+                packed = fusion.packed_psum(leaves + [lval], axes,
+                                            qinfo=qinfo, quant=quant,
+                                            chunks=chunks, hier=hier)
             return packed[-1], jax.tree_util.tree_unflatten(
                 treedef, packed[:-1])
 
-        return body
+        return loss_and_grad
 
     def loss_and_grad_fn(self):
         """jitted (params, toks) -> (loss, grads) over the full grid.
@@ -589,7 +620,7 @@ class TransformerLM:
                 self._step_cache[key] = fn
                 return fn
             else:
-                def body(params, toks):
+                def loss_and_grad(params, toks):
                     return jax.value_and_grad(self._loss_device)(params, toks)
 
                 # check_vma=True: replication (varying-across-mesh-axes)
@@ -598,7 +629,7 @@ class TransformerLM:
                 # exactly the axes they are replicated over, with no
                 # seed-count factors
                 sm = shard_map(
-                    body, mesh=self.grid.mesh,
+                    loss_and_grad, mesh=self.grid.mesh,
                     in_specs=(specs, self._data_spec()),
                     out_specs=(P(), specs),
                     check_vma=True)
@@ -617,8 +648,11 @@ class TransformerLM:
         key = "logits"
         fn = self._step_cache.get(key)
         if fn is None:
+            def logits(params, toks):
+                return self._forward_device(params, toks)
+
             sm = shard_map(
-                self._forward_device, mesh=self.grid.mesh,
+                logits, mesh=self.grid.mesh,
                 in_specs=(self.param_specs(), self._data_spec()),
                 out_specs=P("dp", "sp", None),
                 check_vma=False)
@@ -651,15 +685,17 @@ class TransformerLM:
                 qinfo=qinfo, quant=fusion.quant_key(),
                 chunks=fusion.chunk_key(), hier=fusion.hier_key())
 
-            def body(params, opt_state, toks):
+            def train_step(params, opt_state, toks):
                 loss, grads = lg_body(params, toks)
-                updates, opt_state = tx.update(grads, opt_state, params)
-                return optax.apply_updates(params, updates), opt_state, loss
+                with scope("optimizer"):
+                    updates, opt_state = tx.update(grads, opt_state, params)
+                    params = optax.apply_updates(params, updates)
+                return params, opt_state, loss
 
             # opt_state rides as a replicated pytree (P() spec prefix):
             # the update math is identical on every device, like params
             sm = shard_map(
-                body, mesh=self.grid.mesh,
+                train_step, mesh=self.grid.mesh,
                 in_specs=(specs, P(), self._data_spec()),
                 out_specs=(specs, P(), P()),
                 check_vma=False)
@@ -673,17 +709,20 @@ class TransformerLM:
                 # types arrays by their mesh, so without this placement
                 # step 2 re-traces and compiles the whole step a second
                 # time. Already-placed leaves pass through untouched.
-                opt_state = jax.device_put(opt_state, replicated)
-                out = jitted(params, opt_state, toks)
-                # the model-level fused step counts like a traced step
-                # (DataParallel's packed path does the same), so the
-                # ladder's per-test fusion_step_flushes line shows the
-                # packed path actually ran
-                from ..utils import metrics
+                with span("train_step"):
+                    with span("train_step.place"):
+                        opt_state = jax.device_put(opt_state, replicated)
+                    with span("train_step.dispatch"):
+                        out = jitted(params, opt_state, toks)
+                    # the model-level fused step counts like a traced step
+                    # (DataParallel's packed path does the same), so the
+                    # ladder's per-test fusion_step_flushes line shows the
+                    # packed path actually ran
+                    from ..utils import metrics
 
-                metrics.inc("op_engine.fusion_step_flushes")
-                fusion.tick_quant(qinfo)
-                return out
+                    metrics.inc("op_engine.fusion_step_flushes")
+                    fusion.tick_quant(qinfo)
+                    return out
 
             # the audit/steady-state surface of the underlying program
             step.lower = jitted.lower
@@ -697,11 +736,21 @@ class TransformerLM:
         # step, so XLA updates them in place — halves their HBM footprint
         # (matches nn/data_parallel.py's train step)
         @partial(jax.jit, donate_argnums=(0, 1))
-        def step(params, opt_state, toks):
+        def train_step(params, opt_state, toks):
             loss, grads = lg(params, toks)
-            updates, opt_state = tx.update(grads, opt_state, params)
-            return optax.apply_updates(params, updates), opt_state, loss
+            with scope("optimizer"):
+                updates, opt_state = tx.update(grads, opt_state, params)
+                params = optax.apply_updates(params, updates)
+            return params, opt_state, loss
 
+        def step(params, opt_state, toks):
+            with span("train_step"):
+                with span("train_step.dispatch"):
+                    return train_step(params, opt_state, toks)
+
+        step.lower = train_step.lower
+        if hasattr(train_step, "_cache_size"):
+            step._cache_size = train_step._cache_size
         return step
 
     # ------------------------------------------------------------- #
@@ -738,6 +787,7 @@ class TransformerLM:
         if self.cfg.moe_experts:
             raise NotImplementedError("generate supports the dense MLP only")
 
+    @scope("attn.core")
     def _attn_from_cache(self, q, ck, cv, upto):
         """q (Bl, 1, Hs, Dh) against cached rows < ``upto`` (a scalar, or
         a (Bl,) vector when every row is at its own decode depth — the
@@ -761,12 +811,12 @@ class TransformerLM:
         col < upto discipline."""
         Bl = x.shape[0]
         q, k, v = self._qkv(p_l, x, pos[:, None])
-        ck = ck.at[jnp.arange(Bl), pos].set(k[:, 0].astype(ck.dtype))
-        cv = cv.at[jnp.arange(Bl), pos].set(v[:, 0].astype(cv.dtype))
+        with scope("cache.write"):
+            ck = ck.at[jnp.arange(Bl), pos].set(k[:, 0].astype(ck.dtype))
+            cv = cv.at[jnp.arange(Bl), pos].set(v[:, 0].astype(cv.dtype))
         x = self._attn_residual(
             p_l, x, self._attn_from_cache(q, ck, cv, pos + 1), wire=wire)
-        x = self._dense_mlp_residual(
-            p_l, x, _rmsnorm(x, p_l["ln2"]), wire=wire)
+        x = self._dense_mlp_residual(p_l, x, wire=wire)
         return x, ck, cv
 
     def _prompt_kv_logits(self, params, toks, n_valid, wire=None):
@@ -780,23 +830,23 @@ class TransformerLM:
         its own decode writes overwrite them."""
         c = self.cfg
         dtype = c.compute_dtype
-        stage_params = jax.tree.map(lambda a: a[0], params["stages"])
+        stage_params = self._stage_params(params)
         Sp = toks.shape[1]
-        x = params["embed"][toks].astype(dtype)
+        with scope("embed"):
+            x = params["embed"][toks].astype(dtype)
         pos0 = jnp.arange(Sp)
         ks, vs = [], []
         for l in range(c.n_layers):
-            p_l = self._cast_params(
-                jax.tree.map(lambda a: a[l], stage_params))
+            p_l = self._cast_params(stage_params, l)
             q, k, v = self._qkv(p_l, x, pos0)
             ks.append(k.astype(dtype))
             vs.append(v.astype(dtype))
-            attn = jnp.moveaxis(local_attention(
-                jnp.moveaxis(q, 2, 1), jnp.moveaxis(k, 2, 1),
-                jnp.moveaxis(v, 2, 1), causal=True), 1, 2)
+            with scope("attn.core"):
+                attn = jnp.moveaxis(local_attention(
+                    jnp.moveaxis(q, 2, 1), jnp.moveaxis(k, 2, 1),
+                    jnp.moveaxis(v, 2, 1), causal=True), 1, 2)
             x = self._attn_residual(p_l, x, attn, wire=wire)
-            x = self._dense_mlp_residual(
-                p_l, x, _rmsnorm(x, p_l["ln2"]), wire=wire)
+            x = self._dense_mlp_residual(p_l, x, wire=wire)
         h_last = lax.dynamic_slice_in_dim(x, n_valid - 1, 1, axis=1)
         return ks, vs, self._head(params, h_last)[:, 0]
 
@@ -838,7 +888,7 @@ class TransformerLM:
         Sb = self.prompt_bucket(S0)
         S_max = Sb + max_new_tokens
 
-        def body(params, toks, n_valid, key):
+        def generate(params, toks, n_valid, key):
             Bl = toks.shape[0]
             # independent sampling noise per data-parallel shard — a
             # replicated key would draw IDENTICAL continuations for equal
@@ -847,7 +897,7 @@ class TransformerLM:
             if self._has_dcn:
                 dp_idx = lax.axis_index("dcn") * self.dp + dp_idx
             key = jax.random.fold_in(key, dp_idx)
-            stage_params = jax.tree.map(lambda a: a[0], params["stages"])
+            stage_params = self._stage_params(params)
             dtype = c.compute_dtype
             Hs = c.n_heads // self.tp
             caches_k = jnp.zeros((c.n_layers, Bl, S_max, Hs, c.head_dim),
@@ -856,15 +906,17 @@ class TransformerLM:
 
             # ---- prefill: causal pass over the padded prompt ---- #
             ks, vs, logits0 = self._prompt_kv_logits(params, toks, n_valid)
-            for l in range(c.n_layers):
-                caches_k = caches_k.at[l, :, :Sb].set(ks[l])
-                caches_v = caches_v.at[l, :, :Sb].set(vs[l])
+            with scope("cache.write"):
+                for l in range(c.n_layers):
+                    caches_k = caches_k.at[l, :, :Sb].set(ks[l])
+                    caches_v = caches_v.at[l, :, :Sb].set(vs[l])
 
             def sample(logits, key):
-                if temperature == 0.0:
-                    return jnp.argmax(logits, axis=-1).astype(jnp.int32)
-                return jax.random.categorical(
-                    key, logits / temperature, axis=-1).astype(jnp.int32)
+                with scope("sample"):
+                    if temperature == 0.0:
+                        return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                    return jax.random.categorical(
+                        key, logits / temperature, axis=-1).astype(jnp.int32)
 
             key0, key = jax.random.split(key)
             first = sample(logits0, key0)
@@ -872,17 +924,19 @@ class TransformerLM:
             # ---- decode scan ---- #
             def step(carry, key_t):
                 caches_k, caches_v, tok, t = carry
-                x = params["embed"][tok].astype(dtype)[:, None, :]
+                with scope("embed"):
+                    x = params["embed"][tok].astype(dtype)[:, None, :]
                 pos = jnp.full((Bl,), t, jnp.int32)
                 new_k, new_v = caches_k, caches_v
                 for l in range(c.n_layers):
-                    p_l = self._cast_params(
-                        jax.tree.map(lambda a: a[l], stage_params))
-                    xl, ckl, cvl = self._cache_layer_step(
-                        p_l, x, new_k[l], new_v[l], pos)
-                    x = xl
-                    new_k = new_k.at[l].set(ckl)
-                    new_v = new_v.at[l].set(cvl)
+                    p_l = self._cast_params(stage_params, l)
+                    with scope("cache.read"):
+                        ck_l, cv_l = new_k[l], new_v[l]
+                    x, ckl, cvl = self._cache_layer_step(
+                        p_l, x, ck_l, cv_l, pos)
+                    with scope("cache.write"):
+                        new_k = new_k.at[l].set(ckl)
+                        new_v = new_v.at[l].set(cvl)
                 logits = self._head(params, x)[:, 0]
                 nxt = sample(logits, key_t)
                 return (new_k, new_v, nxt, t + 1), tok
@@ -902,7 +956,7 @@ class TransformerLM:
         fn = self._step_cache.get(cache_key)
         if fn is None:
             fn = jax.jit(shard_map(
-                body, mesh=self.grid.mesh,
+                generate, mesh=self.grid.mesh,
                 in_specs=(self.param_specs(), data_spec, P(), P()),
                 out_specs=data_spec, check_vma=False))
             self._step_cache[cache_key] = fn

@@ -65,6 +65,7 @@ from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..core._compat import shard_map
+from ..utils.profiling import begin, scope, span
 from .errors import ServeClosed, ServeDeadlineExceeded, ServeOverloaded
 from .program_cache import ProgramCache
 
@@ -107,7 +108,8 @@ _SEQ = itertools.count()  # FIFO tiebreaker within a priority
 
 class _DecodeRequest:
     __slots__ = ("prompt", "max_new", "eos_id", "tenant", "priority", "seq",
-                 "enq_t", "deadline_t", "future", "generated", "slot")
+                 "enq_t", "deadline_t", "future", "generated", "slot",
+                 "span", "stage")
 
     def __init__(self, prompt: np.ndarray, max_new: int,
                  eos_id: Optional[int], deadline_t: Optional[float],
@@ -123,6 +125,17 @@ class _DecodeRequest:
         self.future = Future()
         self.generated: List[int] = []
         self.slot = -1
+        # profiling: the request's life (`decode.request`, token times as
+        # its events) and the stage it is in (`decode.queue`, then
+        # `decode.prefill`); the shared null span while recording is off
+        self.span = begin("decode.request", rid=self.seq,
+                          prompt=int(prompt.size))
+        self.stage = begin("decode.queue", parent=self.span, rid=self.seq)
+
+    def end_spans(self) -> None:
+        self.stage.end()
+        self.span.set(n_out=len(self.generated))
+        self.span.end()
 
 
 class DecodeEngine:
@@ -247,6 +260,7 @@ class DecodeEngine:
         deadline_t = (None if deadline_ms is None
                       else time.monotonic() + deadline_ms / 1e3)
         req = _DecodeRequest(prompt, max_new, eos_id, deadline_t, tname)
+        req.span.set(bucket=need - max_new)
         with self._cv_lock:
             if self._closed:
                 raise ServeClosed(f"decode engine {self.name!r} is closed")
@@ -473,29 +487,32 @@ class DecodeEngine:
         def build():
             m, c = self.model, self.model.cfg
 
-            def body(params, ck, cv, pos, live, toks, skey):
+            def decode_step(params, ck, cv, pos, live, toks, skey):
                 Bl = toks.shape[0]
                 dtype = c.compute_dtype
-                stage_params = jax.tree.map(lambda a: a[0],
-                                            params["stages"])
-                x = params["embed"][toks].astype(dtype)[:, None, :]
+                stage_params = m._stage_params(params)
+                with scope("embed"):
+                    x = params["embed"][toks].astype(dtype)[:, None, :]
                 new_k, new_v = ck, cv
                 for l in range(c.n_layers):
-                    p_l = m._cast_params(
-                        jax.tree.map(lambda a: a[l], stage_params))
+                    p_l = m._cast_params(stage_params, l)
+                    with scope("cache.read"):
+                        ck_l, cv_l = new_k[l], new_v[l]
                     x, ckl, cvl = m._cache_layer_step(
-                        p_l, x, new_k[l], new_v[l], pos, wire=wire)
-                    new_k = new_k.at[l].set(ckl)
-                    new_v = new_v.at[l].set(cvl)
+                        p_l, x, ck_l, cv_l, pos, wire=wire)
+                    with scope("cache.write"):
+                        new_k = new_k.at[l].set(ckl)
+                        new_v = new_v.at[l].set(cvl)
                 logits = m._head(params, x)[:, 0]
-                if temp == 0.0:
-                    nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-                else:
-                    gsl = self._dp_index() * Bl + jnp.arange(Bl)
-                    keys = jax.vmap(
-                        lambda i: jax.random.fold_in(skey, i))(gsl)
-                    nxt = jax.vmap(lambda k, lg: jax.random.categorical(
-                        k, lg / temp))(keys, logits).astype(jnp.int32)
+                with scope("sample"):
+                    if temp == 0.0:
+                        nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                    else:
+                        gsl = self._dp_index() * Bl + jnp.arange(Bl)
+                        keys = jax.vmap(
+                            lambda i: jax.random.fold_in(skey, i))(gsl)
+                        nxt = jax.vmap(lambda k, lg: jax.random.categorical(
+                            k, lg / temp))(keys, logits).astype(jnp.int32)
                 # join/leave is a MASK, not a program change: dead slots
                 # keep their token and position (their cache write lands
                 # on the same already-masked row every step)
@@ -505,7 +522,7 @@ class DecodeEngine:
 
             cs, vs = self._cache_spec, self._vec_spec
             sm = shard_map(
-                body, mesh=self.model.grid.mesh,
+                decode_step, mesh=self.model.grid.mesh,
                 in_specs=(self.model.param_specs(), cs, cs, vs, vs, vs,
                           P()),
                 out_specs=(cs, cs, vs, vs), check_vma=False)
@@ -532,16 +549,17 @@ class DecodeEngine:
         def build():
             m = self.model
 
-            def body(params, ck, cv, pos, toks, prompt, n_valid, slot,
-                     skey):
+            def decode_prefill(params, ck, cv, pos, toks, prompt, n_valid,
+                               slot, skey):
                 ks, vs, logits = m._prompt_kv_logits(
                     params, prompt[None], n_valid, wire=wire)
-                if temp == 0.0:
-                    first = jnp.argmax(logits[0]).astype(jnp.int32)
-                else:
-                    first = jax.random.categorical(
-                        jax.random.fold_in(skey, slot),
-                        logits[0] / temp).astype(jnp.int32)
+                with scope("sample"):
+                    if temp == 0.0:
+                        first = jnp.argmax(logits[0]).astype(jnp.int32)
+                    else:
+                        first = jax.random.categorical(
+                            jax.random.fold_in(skey, slot),
+                            logits[0] / temp).astype(jnp.int32)
                 ls = ck.shape[1]  # local slots on this dp shard
                 local = slot - self._dp_index() * ls
                 ok = (local >= 0) & (local < ls)
@@ -555,11 +573,12 @@ class DecodeEngine:
                            jnp.int32(0))
                     for buf_i, new in ((0, ks[l]), (1, vs[l])):
                         buf = (ck, cv)[buf_i]
-                        cur = lax.dynamic_slice(
-                            buf, idx, (1, 1) + new.shape[1:])
-                        upd = jnp.where(ok, new[None].astype(buf.dtype),
-                                        cur)
-                        buf = lax.dynamic_update_slice(buf, upd, idx)
+                        with scope("cache.write"):
+                            cur = lax.dynamic_slice(
+                                buf, idx, (1, 1) + new.shape[1:])
+                            upd = jnp.where(
+                                ok, new[None].astype(buf.dtype), cur)
+                            buf = lax.dynamic_update_slice(buf, upd, idx)
                         if buf_i == 0:
                             ck = buf
                         else:
@@ -571,7 +590,7 @@ class DecodeEngine:
 
             cs, vs = self._cache_spec, self._vec_spec
             sm = shard_map(
-                body, mesh=self.model.grid.mesh,
+                decode_prefill, mesh=self.model.grid.mesh,
                 in_specs=(self.model.param_specs(), cs, cs, vs, vs, P(),
                           P(), P(), P()),
                 out_specs=(cs, cs, vs, vs, P()), check_vma=False)
@@ -588,7 +607,8 @@ class DecodeEngine:
         stays on device, so a test wrapping the engine in
         ``jax.transfer_guard_device_to_host("disallow")`` proves the
         per-step fetch is only the sampled-token vector."""
-        with jax.transfer_guard_device_to_host("allow"):
+        with span("decode.fetch"), \
+                jax.transfer_guard_device_to_host("allow"):
             return np.asarray(arr)
 
     # ------------------------------------------------------------------ #
@@ -610,14 +630,18 @@ class DecodeEngine:
                         and (self._q or self._live.any())):
                     return
                 if not self._paused:
-                    grants, expired = self._grant_locked()
+                    with span("decode.grant"):
+                        grants, expired = self._grant_locked()
             for req in expired:
                 self._fail_deadline(req)
             try:
-                for req, slot in grants:
-                    self._do_prefill(req, slot)
-                if self._live.any():
-                    self._do_step()
+                # one turn of the loop once there is work: the wait above
+                # is the engine with nothing to do, and carries no span
+                with span("decode.loop", n_live=int(self._live.sum())):
+                    for req, slot in grants:
+                        self._do_prefill(req, slot)
+                    if self._live.any():
+                        self._do_step()
             except Exception as exc:
                 # backstop: NOTHING kills the worker. The donated device
                 # state may be gone — fail every in-flight future typed,
@@ -646,6 +670,9 @@ class DecodeEngine:
             slot = free.pop(0)
             req.slot = slot
             self._slot_req[slot] = req
+            req.stage.end()             # queued until here
+            req.stage = begin("decode.prefill", parent=req.span,
+                              rid=req.seq, slot=slot)
             grants.append((req, slot))
         return grants, expired
 
@@ -655,6 +682,7 @@ class DecodeEngine:
         _pm.inc("serve.decode_deadline_expired")
         if self._admission is not None:
             self._admission.count(req.tenant, "deadline_expired")
+        req.end_spans()
         req.future.set_exception(ServeDeadlineExceeded(
             f"decode request expired after "
             f"{(time.monotonic() - req.enq_t) * 1e3:.1f} ms in queue"))
@@ -673,9 +701,11 @@ class DecodeEngine:
         padded = np.zeros(Sp, np.int32)
         padded[:S0] = prompt
         self._prefill_seq += 1
-        out = prog(self.params, self._ck, self._cv, self._pos, self._toks,
-                   jnp.asarray(padded), jnp.int32(S0), jnp.int32(slot),
-                   self._next_key(2 * self._prefill_seq + 1))
+        with span("decode.prefill.dispatch", slot=slot, bucket=Sp):
+            out = prog(self.params, self._ck, self._cv, self._pos,
+                       self._toks, jnp.asarray(padded), jnp.int32(S0),
+                       jnp.int32(slot),
+                       self._next_key(2 * self._prefill_seq + 1))
         self._ck, self._cv, self._pos, self._toks, first = out
         if record:
             self._prefills += 1
@@ -693,8 +723,11 @@ class DecodeEngine:
             if self._donated_gone():
                 raise  # state lost mid-donation: the backstop rebuilds
             self._slot_req[slot] = None
+            req.end_spans()
             req.future.set_exception(exc)
             return
+        req.stage.end()                 # granted until the first token
+        req.span.event("token")
         req.generated = [first]
         self._tokens_out += 1
         _pm.inc("serve.decode_tokens_out")
@@ -724,8 +757,9 @@ class DecodeEngine:
                 live, NamedSharding(self.model.grid.mesh, self._vec_spec))
         try:
             _faults.check("serve.decode.step")
-            out = prog(self.params, self._ck, self._cv, self._pos,
-                       self._live_dev, self._toks, skey)
+            with span("decode.step.dispatch"):
+                out = prog(self.params, self._ck, self._cv, self._pos,
+                           self._live_dev, self._toks, skey)
         except Exception:
             if self._donated_gone():
                 raise  # donated buffers invalidated mid-dispatch (PR 8)
@@ -746,32 +780,36 @@ class DecodeEngine:
 
         live = self._live.copy()
         n_live = int(live.sum())
-        toks_np = self._dispatch_step(live)
-        self._occupancy.append(n_live / self.slots)
-        self._tokens_out += n_live
-        _pm.inc("serve.decode_tokens_out", n_live)
-        for slot in np.nonzero(live)[0]:
-            req = self._slot_req[slot]
-            if req is None:
-                continue
-            t = int(toks_np[slot])
-            req.generated.append(t)
-            done = (len(req.generated) >= req.max_new
-                    or (req.eos_id is not None and t == req.eos_id))
-            if done:
-                self._finish(slot, req)
+        with span("decode.step", n_live=n_live):
+            toks_np = self._dispatch_step(live)
+            self._occupancy.append(n_live / self.slots)
+            self._tokens_out += n_live
+            _pm.inc("serve.decode_tokens_out", n_live)
+            for slot in np.nonzero(live)[0]:
+                req = self._slot_req[slot]
+                if req is None:
+                    continue
+                t = int(toks_np[slot])
+                req.generated.append(t)
+                req.span.event("token")
+                done = (len(req.generated) >= req.max_new
+                        or (req.eos_id is not None and t == req.eos_id))
+                if done:
+                    self._finish(slot, req)
 
     def _finish(self, slot: int, req: _DecodeRequest) -> None:
         from ..utils import metrics as _pm
 
-        self._live[slot] = False
-        self._live_dev = None  # membership changed: re-upload
-        self._slot_req[slot] = None
-        _pm.inc("serve.decode_completed")
-        if self._admission is not None:
-            self._admission.count(req.tenant, "completed")
-        req.future.set_result(np.concatenate(
-            [req.prompt, np.asarray(req.generated, np.int32)]))
+        with span("decode.emit", slot=slot):
+            self._live[slot] = False
+            self._live_dev = None  # membership changed: re-upload
+            self._slot_req[slot] = None
+            _pm.inc("serve.decode_completed")
+            if self._admission is not None:
+                self._admission.count(req.tenant, "completed")
+            req.end_spans()
+            req.future.set_result(np.concatenate(
+                [req.prompt, np.asarray(req.generated, np.int32)]))
 
     def _donated_gone(self) -> bool:
         try:
@@ -825,7 +863,7 @@ class DecodeEngine:
         m, c = self.model, self.model.cfg
         params = self.params
         dtype = c.compute_dtype
-        stage_params = jax.tree.map(lambda a: a[0], params["stages"])
+        stage_params = m._stage_params(params)
         pos_h = self._fetch(self._pos)
         toks_h = self._fetch(self._toks)
         ck, cv = self._ck, self._cv
@@ -835,8 +873,7 @@ class DecodeEngine:
             p = jnp.int32(int(pos_h[s]))
             x = params["embed"][int(toks_h[s])].astype(dtype)[None, None, :]
             for l in range(c.n_layers):
-                p_l = m._cast_params(
-                    jax.tree.map(lambda a: a[l], stage_params))
+                p_l = m._cast_params(stage_params, l)
                 a_in = _rmsnorm(x, p_l["ln1"])
                 qkv = jnp.einsum("bsd,dohk->bsohk", a_in, p_l["wqkv"])
                 q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]

@@ -89,8 +89,21 @@ class TestProfiling:
         assert t.seconds is not None and t.seconds > 0
 
     def test_annotate(self):
-        with ht.utils.profiling.annotate("scope"):
-            _ = ht.arange(4).sum()
+        # `annotate` became `span` (PR 29): off it is the shared null object,
+        # on it is a record in the ring around the work it encloses
+        prof = ht.utils.profiling
+        assert prof.span("scope") is prof.span("other")
+        prof.clear()
+        prof.enable()
+        try:
+            with prof.span("scope", n=4):
+                _ = ht.arange(4).sum()
+        finally:
+            prof.disable()
+        rec = [r for r in prof.spans() if r.name == "scope"]
+        assert len(rec) == 1 and rec[0].attrs == {"n": 4}
+        assert rec[0].t1 > rec[0].t0
+        prof.clear()
 
 
 class TestPytreeStructureRoundTrip:
