@@ -194,6 +194,110 @@ def cdist_tile(x, y, sqrt: bool = True, block_m: int = 256,
 # --------------------------------------------------------------------------- #
 
 
+_NT = ((1,), (1,))  # a·bᵀ: contract both operands' minor dim
+_NN = ((1,), (0,))  # a·b
+
+
+def _all_bf16(*refs) -> bool:
+    """The flash kernels hand the MXU bfloat16 exactly when every tensor
+    operand arrives bfloat16; any other dtype (or a mix) keeps float32
+    products at ``HIGHEST``. Fixed at trace time by what the caller passed."""
+    return all(r.dtype == jnp.bfloat16 for r in refs)
+
+
+def _gemm(a, b, contract, acc_dtype, mxu_bf16: bool):
+    """``a·b`` over ``contract``, accumulated in ``acc_dtype``.
+
+    Off the bfloat16 path: both operands in ``acc_dtype`` at ``HIGHEST``
+    (six bfloat16 passes on the MXU for float32). On it ``b`` is an input
+    tile holding bfloat16 VALUES, and a bf16×bf16 product is exact in
+    float32, so an ``a`` that is an input tile too takes ONE pass and loses
+    nothing; an ``a`` the kernel computed in float32 (``p``, ``ds``) goes as
+    two bfloat16 terms ``hi + lo`` — 16 bits of mantissa, two passes."""
+    dims = (contract, ((), ()))
+    if not mxu_bf16:
+        return jax.lax.dot_general(
+            a.astype(acc_dtype), b.astype(acc_dtype), dimension_numbers=dims,
+            preferred_element_type=acc_dtype,
+            precision=jax.lax.Precision.HIGHEST)
+    # DEFAULT said aloud: the package's global default is "high", which
+    # Mosaic refuses and which bfloat16 operands have no use for
+    one_pass = functools.partial(
+        jax.lax.dot_general, dimension_numbers=dims,
+        preferred_element_type=acc_dtype, precision=jax.lax.Precision.DEFAULT)
+    if a.dtype == jnp.bfloat16:
+        return one_pass(a, b)
+    hi = a.astype(jnp.bfloat16)
+    lo = (a - hi.astype(a.dtype)).astype(jnp.bfloat16)
+    return one_pass(hi, b) + one_pass(lo, b)
+
+
+def _scores(q, k, scale: float, acc_dtype, mxu_bf16: bool, transposed=False):
+    """The score tile ``scale·q·kᵀ`` (``transposed``: ``scale·k·qᵀ``). On the
+    bfloat16 path ``scale`` multiplies the float32 tile after the dot, so the
+    operands stay the bfloat16 values they arrived as."""
+    if mxu_bf16:
+        a, b = (k, q) if transposed else (q, k)
+        return _gemm(a, b, _NT, acc_dtype, True) * scale
+    qs = q.astype(acc_dtype) * scale
+    a, b = (k, qs) if transposed else (qs, k)
+    return _gemm(a, b, _NT, acc_dtype, False)
+
+
+def _block_mask(shape, row_axis: int, row0, col0, kv_valid: int,
+                causal_offset: Optional[int]):
+    """Live positions of one score block: keys before the padded tail and,
+    causal, on or below the end-aligned diagonal."""
+    row = jax.lax.broadcasted_iota(jnp.int32, shape, row_axis) + row0
+    col = jax.lax.broadcasted_iota(jnp.int32, shape, 1 - row_axis) + col0
+    mask = col < kv_valid
+    if causal_offset is not None:
+        mask = jnp.logical_and(mask, col <= row + causal_offset)
+    return mask
+
+
+def _run_blocks(step, qi, kb, block_q: int, block_k: int, kv_valid: int,
+                causal_offset: Optional[int], bare_interior: bool = True):
+    """Run ``step(masked)`` for grid cell (q-block ``qi``, k-block ``kb``):
+    not at all for a block wholly above the diagonal, and ``masked=True``
+    where the diagonal or the padded tail crosses it. A block every position
+    of which is live runs ``step(False)`` (no iota, compare or select) when
+    ``bare_interior``; the masks select nothing there either way."""
+    live = True
+    crossed = []  # where a block needs its masks
+    if kv_valid % block_k:  # the last K block holds padded keys
+        crossed.append((kb + 1) * block_k > kv_valid)
+    if causal_offset is not None:
+        # live: the block's first key is visible to its last query row;
+        # crossed: its last key is NOT visible to its first query row
+        live = kb * block_k <= (qi + 1) * block_q - 1 + causal_offset
+        crossed.append((kb + 1) * block_k - 1 > qi * block_q + causal_offset)
+    if not (crossed and bare_interior):  # one copy of the step
+        masked = bool(crossed)
+        if live is True:
+            step(masked)
+        else:
+            pl.when(live)(lambda: step(masked))
+        return
+    crossed = functools.reduce(jnp.logical_or, crossed)
+    pl.when(jnp.logical_and(live, crossed))(lambda: step(True))
+    pl.when(jnp.logical_and(live, jnp.logical_not(crossed)))(lambda: step(False))
+
+
+def _flash_blocks(block_q: Optional[int], block_k: Optional[int], Sq: int,
+                  Sk: int, mxu_bf16: bool) -> Tuple[int, int]:
+    """``(bq, bk)`` of a flash kernel's score block. ``bq`` is the lane dim
+    of the dK/dV kernel's (8, bq) statistics block and ``bk`` the lane dim of
+    the (bq, bk) score block, so a caller's sizes are rounded up to 128
+    rather than trusted, and neither exceeds its padded sequence. With none
+    given: 256, and 512 on the bfloat16 path, where a grid step's fixed cost
+    and not the MXU sets the pace (PERF.md section 6, PR 30)."""
+    default = 512 if mxu_bf16 else 256
+    bq = min(_round_up(block_q or default, 128), _round_up(Sq, 128))
+    bk = min(_round_up(block_k or default, 128), _round_up(Sk, 128))
+    return bq, bk
+
+
 def _flash_kernel(
     q_ref,
     k_ref,
@@ -222,7 +326,6 @@ def _flash_kernel(
     qi = pl.program_id(1)
     kb = pl.program_id(2)
     num_kb = pl.num_programs(2)
-    bq = q_ref.shape[1]
 
     @pl.when(kb == 0)
     def _init():
@@ -230,40 +333,28 @@ def _flash_kernel(
         m_ref[...] = jnp.full_like(m_ref, _NEG_BIG)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    def step():
-        row = jax.lax.broadcasted_iota(jnp.int32, (bq, block_k), 0) + qi * block_q
-        col = jax.lax.broadcasted_iota(jnp.int32, (bq, block_k), 1) + kb * block_k
-        q = q_ref[0].astype(acc_dtype) * scale
-        k = k_ref[0].astype(acc_dtype)
-        v = v_ref[0].astype(acc_dtype)
-        s = jax.lax.dot_general(
-            q, k, dimension_numbers=(((1,), (1,)), ((), ())), preferred_element_type=acc_dtype,
-            precision=jax.lax.Precision.HIGHEST,
-        )  # (bq, block_k)
-        mask = col < kv_valid
-        if causal_offset is not None:
-            mask = jnp.logical_and(mask, col <= row + causal_offset)
-        s = jnp.where(mask, s, jnp.asarray(_NEG_BIG, s.dtype))
+    mxu_bf16 = _all_bf16(q_ref, k_ref, v_ref)
+
+    def step(masked: bool):
+        s = _scores(q_ref[0], k_ref[0], scale, acc_dtype, mxu_bf16)  # (bq, block_k)
+        if masked:
+            mask = _block_mask(s.shape, 0, qi * block_q, kb * block_k,
+                               kv_valid, causal_offset)
+            s = jnp.where(mask, s, jnp.asarray(_NEG_BIG, s.dtype))
         m_prev = m_ref[...]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
-        # mask p explicitly: on a fully-masked row m_new is still _NEG_BIG and
-        # exp(s - m_new) would be 1 at masked positions, silently yielding
-        # mean(V) instead of the dense path's NaN
-        p = jnp.where(mask, jnp.exp(s - m_new), jnp.zeros((), acc_dtype))
-        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-            p, v, dimension_numbers=(((1,), (0,)), ((), ())), preferred_element_type=acc_dtype,
-            precision=jax.lax.Precision.HIGHEST,
-        )
+        p = jnp.exp(s - m_new)
+        if masked:
+            # mask p explicitly: on a fully-masked row m_new is still _NEG_BIG
+            # and exp(s - m_new) would be 1 at masked positions, silently
+            # yielding mean(V) instead of the dense path's NaN
+            p = jnp.where(mask, p, jnp.zeros((), acc_dtype))
+        acc_ref[...] = acc_ref[...] * alpha + _gemm(p, v_ref[0], _NN, acc_dtype, mxu_bf16)
         l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
         m_ref[...] = m_new
 
-    if causal_offset is None:
-        step()
-    else:
-        # skip blocks wholly above the (end-aligned) diagonal
-        live = kb * block_k <= (qi + 1) * block_q - 1 + causal_offset
-        pl.when(live)(step)
+    _run_blocks(step, qi, kb, block_q, block_k, kv_valid, causal_offset)
 
     @pl.when(kb == num_kb - 1)
     def _finalize():
@@ -306,18 +397,14 @@ def _flash_impl(
     v,
     scale: float,
     causal: bool,
-    block_q: int,
-    block_k: int,
+    block_q: Optional[int],
+    block_k: Optional[int],
 ):
     """Raw blockwise (flash) attention forward; returns ``(out, lse)``."""
     B, H, Sq, D = q.shape
     Sk = k.shape[2]
     acc_dtype = jnp.float64 if jnp.promote_types(q.dtype, jnp.float32) == jnp.float64 else jnp.float32
-    # bq must be a multiple of 128 (the (1, bq) lse output block's lane dim),
-    # and bk is the lane dim of the (bq, bk) score block — round user-supplied
-    # block sizes up rather than trusting them
-    bq = min(_round_up(block_q, 128), _round_up(Sq, 128))
-    bk = min(_round_up(block_k, 128), _round_up(Sk, 128))
+    bq, bk = _flash_blocks(block_q, block_k, Sq, Sk, _all_bf16(q, k, v))
     sqp, skp, dp = _round_up(Sq, bq), _round_up(Sk, bk), _round_up(D, 128)
 
     qf = _pad_axis(_pad_axis(q.reshape(B * H, Sq, D), 1, sqp), 2, dp)
@@ -389,51 +476,33 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dmb_ref,
         acc_dk[...] = jnp.zeros_like(acc_dk)
         acc_dv[...] = jnp.zeros_like(acc_dv)
 
-    def step():
-        q = q_ref[0].astype(acc_dtype)
-        k = k_ref[0].astype(acc_dtype)
-        v = v_ref[0].astype(acc_dtype)
-        do = do_ref[0].astype(acc_dtype)
+    mxu_bf16 = _all_bf16(q_ref, k_ref, v_ref, do_ref)
+
+    def step(masked: bool):
+        q, do = q_ref[0], do_ref[0]
         lse_row = lse_ref[0][:1, :]          # (1, bq)
         dmb_row = dmb_ref[0][:1, :]          # (1, bq)
-        s_t = jax.lax.dot_general(
-            k, q * scale, dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=acc_dtype,
-            precision=jax.lax.Precision.HIGHEST,
-        )                                     # (bk, bq)
-        col = jax.lax.broadcasted_iota(jnp.int32, (block_k, block_q), 0) + kb * block_k
-        row = jax.lax.broadcasted_iota(jnp.int32, (block_k, block_q), 1) + qi * block_q
-        mask = col < kv_valid
-        if causal_offset is not None:
-            mask = jnp.logical_and(mask, col <= row + causal_offset)
-        # lse = +inf on padded query rows (p -> 0); -inf on fully-masked real
-        # rows would blow exp() up, so gate on finiteness like the dense path
-        p_t = jnp.where(
-            jnp.logical_and(mask, jnp.isfinite(lse_row)),
-            jnp.exp(s_t - lse_row), jnp.zeros((), acc_dtype))
-        dp_t = jax.lax.dot_general(
-            v, do, dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=acc_dtype,
-            precision=jax.lax.Precision.HIGHEST,
-        )                                     # (bk, bq)
+        s_t = _scores(q, k_ref[0], scale, acc_dtype, mxu_bf16,
+                      transposed=True)       # (bk, bq)
+        # lse = +inf on padded query rows (p -> 0)
+        p_t = jnp.exp(s_t - lse_row)
+        if masked:
+            # -inf on fully-masked real rows would blow exp() up, so gate on
+            # finiteness like the dense path
+            mask = _block_mask(s_t.shape, 1, qi * block_q, kb * block_k,
+                               kv_valid, causal_offset)
+            p_t = jnp.where(jnp.logical_and(mask, jnp.isfinite(lse_row)),
+                            p_t, jnp.zeros((), acc_dtype))
+        dp_t = _gemm(v_ref[0], do, _NT, acc_dtype, mxu_bf16)  # (bk, bq)
         ds_t = p_t * (dp_t + dmb_row)
-        acc_dv[...] += jax.lax.dot_general(
-            p_t, do, dimension_numbers=(((1,), (0,)), ((), ())),
-            preferred_element_type=acc_dtype,
-            precision=jax.lax.Precision.HIGHEST,
-        )
-        acc_dk[...] += jax.lax.dot_general(
-            ds_t, q, dimension_numbers=(((1,), (0,)), ((), ())),
-            preferred_element_type=acc_dtype,
-            precision=jax.lax.Precision.HIGHEST,
-        )
+        acc_dv[...] += _gemm(p_t, do, _NN, acc_dtype, mxu_bf16)
+        acc_dk[...] += _gemm(ds_t, q, _NN, acc_dtype, mxu_bf16)
 
-    if causal_offset is None:
-        step()
-    else:
-        # skip Q blocks wholly above the diagonal for this K block
-        live = kb * block_k <= (qi + 1) * block_q - 1 + causal_offset
-        pl.when(live)(step)
+    # this kernel is the one the MXU still bounds (6 passes a block): the
+    # second, mask-free copy of the step cost it 2% on the chip, so it masks
+    # every live block
+    _run_blocks(step, qi, kb, block_q, block_k, kv_valid, causal_offset,
+                bare_interior=False)
 
     @pl.when(qi == num_qb - 1)
     def _flush():
@@ -456,43 +525,24 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dmb_ref,
     def _init():
         acc_dq[...] = jnp.zeros_like(acc_dq)
 
-    def step():
-        q = q_ref[0].astype(acc_dtype)
-        k = k_ref[0].astype(acc_dtype)
-        v = v_ref[0].astype(acc_dtype)
-        do = do_ref[0].astype(acc_dtype)
+    mxu_bf16 = _all_bf16(q_ref, k_ref, v_ref, do_ref)
+
+    def step(masked: bool):
+        k = k_ref[0]
         lse_col = lse_ref[0][:, :1]          # (bq, 1)
         dmb_col = dmb_ref[0][:, :1]          # (bq, 1)
-        s = jax.lax.dot_general(
-            q * scale, k, dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=acc_dtype,
-            precision=jax.lax.Precision.HIGHEST,
-        )                                     # (bq, bk)
-        row = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0) + qi * block_q
-        col = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1) + kb * block_k
-        mask = col < kv_valid
-        if causal_offset is not None:
-            mask = jnp.logical_and(mask, col <= row + causal_offset)
-        p = jnp.where(
-            jnp.logical_and(mask, jnp.isfinite(lse_col)),
-            jnp.exp(s - lse_col), jnp.zeros((), acc_dtype))
-        dp = jax.lax.dot_general(
-            do, v, dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=acc_dtype,
-            precision=jax.lax.Precision.HIGHEST,
-        )                                     # (bq, bk)
+        s = _scores(q_ref[0], k, scale, acc_dtype, mxu_bf16)  # (bq, bk)
+        p = jnp.exp(s - lse_col)
+        if masked:
+            mask = _block_mask(s.shape, 0, qi * block_q, kb * block_k,
+                               kv_valid, causal_offset)
+            p = jnp.where(jnp.logical_and(mask, jnp.isfinite(lse_col)),
+                          p, jnp.zeros((), acc_dtype))
+        dp = _gemm(do_ref[0], v_ref[0], _NT, acc_dtype, mxu_bf16)  # (bq, bk)
         ds = p * (dp + dmb_col)
-        acc_dq[...] += jax.lax.dot_general(
-            ds, k, dimension_numbers=(((1,), (0,)), ((), ())),
-            preferred_element_type=acc_dtype,
-            precision=jax.lax.Precision.HIGHEST,
-        )
+        acc_dq[...] += _gemm(ds, k, _NN, acc_dtype, mxu_bf16)
 
-    if causal_offset is None:
-        step()
-    else:
-        live = kb * block_k <= (qi + 1) * block_q - 1 + causal_offset
-        pl.when(live)(step)
+    _run_blocks(step, qi, kb, block_q, block_k, kv_valid, causal_offset)
 
     @pl.when(kb == num_kb - 1)
     def _flush():
@@ -503,7 +553,7 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dmb_ref,
     jax.jit, static_argnames=("scale", "causal", "block_q", "block_k")
 )
 def _flash_bwd_impl(q, k, v, out, lse, dout, dlse, scale: float, causal: bool,
-                    block_q: int, block_k: int):
+                    block_q: Optional[int], block_k: Optional[int]):
     """Blockwise (flash) attention backward: O(S·D) memory per (batch, head)
     instead of the dense fallback's O(Sq·Sk) probability matrix — the memory
     profile long-context training needs. Two grid passes: dK/dV (Q-axis
@@ -512,8 +562,7 @@ def _flash_bwd_impl(q, k, v, out, lse, dout, dlse, scale: float, causal: bool,
     B, H, Sq, D = q.shape
     Sk = k.shape[2]
     acc_dtype = jnp.float64 if jnp.promote_types(q.dtype, jnp.float32) == jnp.float64 else jnp.float32
-    bq = min(_round_up(block_q, 128), _round_up(Sq, 128))
-    bk = min(_round_up(block_k, 128), _round_up(Sk, 128))
+    bq, bk = _flash_blocks(block_q, block_k, Sq, Sk, _all_bf16(q, k, v, dout))
     sqp, skp, dp = _round_up(Sq, bq), _round_up(Sk, bk), _round_up(D, 128)
     BH = B * H
 
@@ -654,8 +703,8 @@ def flash_attention(
     scale: Optional[float] = None,
     causal: bool = False,
     return_lse: bool = False,
-    block_q: int = 256,
-    block_k: int = 256,
+    block_q: Optional[int] = None,
+    block_k: Optional[int] = None,
 ):
     """Blockwise (flash) attention with online softmax.
 
@@ -665,10 +714,19 @@ def flash_attention(
     Differentiable: the Pallas forward pairs with a recompute-from-lse
     backward (``_flash_diff_bwd``), so training paths (ring attention, the
     transformer example) work on TPU.
+
+    What the MXU is handed follows the inputs' dtype (``_gemm``): all
+    bfloat16, the exact GEMMs (``q·kᵀ``, ``dout·vᵀ``) take one pass and the
+    float32 tiles the kernels compute (``p``, ``ds``) go as two bfloat16
+    terms; any other dtype, or a mix, keeps float32 products at ``HIGHEST``.
+    Statistics, ``exp`` and accumulators are float32 (float64 for float64
+    inputs) either way. ``block_q``/``block_k`` default by that path
+    (``_flash_blocks``).
     """
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
-    out, lse = _flash_diff(q, k, v, float(scale), bool(causal), int(block_q), int(block_k))
+    bq, bk = (b if b is None else int(b) for b in (block_q, block_k))
+    out, lse = _flash_diff(q, k, v, float(scale), bool(causal), bq, bk)
     if return_lse:
         return out, lse
     return out

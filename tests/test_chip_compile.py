@@ -84,26 +84,34 @@ def test_cdist_tile_compiles(on_chip, one_chip, dtype, n, d):
     _compiled_text(lambda a, b: pk.cdist_tile(a, b, sqrt=True), x, x)
 
 
-def _qkv(sharding, B=8):
-    return [jax.ShapeDtypeStruct((B, 16, 1024, 64), jnp.bfloat16,
-                                 sharding=sharding)] * 3
+# (8, 16, 1024, 64): chip_smoke.py's train phase; (2, 16, 2048, 128): the
+# benchmark's train cell (pythia-1.4b-d8.train-s2048), heads of 128
+_FLASH_SHAPES = [(8, 16, 1024, 64), (2, 16, 2048, 128)]
 
 
-def test_flash_forward_compiles(on_chip, one_chip):
+def _qkv(sharding, shape=_FLASH_SHAPES[0]):
+    return [jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=sharding)] * 3
+
+
+@pytest.mark.parametrize("shape", _FLASH_SHAPES, ids=str)
+def test_flash_forward_compiles(on_chip, one_chip, shape):
     _compiled_text(lambda q, k, v: pk.flash_attention(q, k, v, causal=True),
-                   *_qkv(one_chip))
+                   *_qkv(one_chip, shape))
 
 
-def test_flash_backward_compiles(on_chip, one_chip):
+@pytest.mark.parametrize("shape", _FLASH_SHAPES, ids=str)
+def test_flash_backward_compiles(on_chip, one_chip, shape):
     """``jax.grad`` through the kernel takes the hand-written blockwise
     backward (dK/dV and dQ kernels) — not the dense jnp branch the
-    interpreter's vma hazard routes to off-TPU."""
+    interpreter's vma hazard routes to off-TPU. bfloat16: the block shapes
+    and the MXU operand forms (one-pass exact GEMMs, two-term tiles) are what
+    the chip would be asked to compile."""
     def loss(q, k, v):
         out = pk.flash_attention(q, k, v, causal=True)
         return jnp.sum(out.astype(jnp.float32) ** 2)
 
     text = _compiled_text(jax.grad(loss, argnums=(0, 1, 2)),
-                          *_qkv(one_chip))
+                          *_qkv(one_chip, shape))
     assert text.count("tpu_custom_call") >= 3  # forward + dkv + dq
 
 

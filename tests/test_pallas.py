@@ -396,6 +396,85 @@ class TestFlashBlockwiseBackward:
             rtol=0.1, atol=0.1)
 
 
+def _bf16_close(got, want32, name):
+    """``got`` (bfloat16) against the float32 path's result rounded to
+    bfloat16: every element within one bfloat16 ulp of
+    itself or 2^-14 of the tensor's largest magnitude, whichever is larger —
+    a sum taken in another order may cross one rounding edge, no more."""
+    got = np.asarray(jnp.asarray(got).astype(jnp.bfloat16).astype(jnp.float32))
+    want = np.asarray(jnp.asarray(want32).astype(jnp.bfloat16).astype(jnp.float32))
+    assert np.isfinite(want).all(), name
+    ulp = 2.0 ** (np.floor(np.log2(np.maximum(np.abs(want), 1e-30))) - 7)
+    tol = np.maximum(ulp, 2.0 ** -14 * np.abs(want).max())
+    bad = np.abs(got - want) > tol
+    assert not bad.any(), (
+        f"{name}: {int(bad.sum())} of {bad.size} beyond one bfloat16 ulp; "
+        f"worst {np.abs(got - want).max():.3e} against {tol[bad].min():.3e}")
+
+
+class TestFlashBf16Operands:
+    """bfloat16 ``q``/``k``/``v``/``dout`` go to the MXU as they arrive
+    (exact products: one pass) and the float32 tiles the kernels compute
+    (``p``, ``ds``) as two bfloat16 terms. That must read what the float32
+    path (``HIGHEST`` on the same values upcast) reads, to bfloat16 rounding."""
+
+    @pytest.mark.parametrize("sq,sk,blocks", [
+        (384, 384, 256), (256, 384, 256),    # one and a half blocks of 256:
+        # a padded tail on both axes; sq < sk: the end-aligned diagonal
+        (384, 384, None), (256, 384, None),  # the blocks the path defaults to
+        (1100, 1100, None),                  # three of those, and a tail
+    ])
+    @pytest.mark.parametrize("d", [64, 128])
+    @pytest.mark.parametrize("causal", [True, False])
+    def test_reads_what_the_float32_path_reads(self, causal, d, sq, sk, blocks,
+                                               force_pallas):
+        ks = jax.random.split(jax.random.PRNGKey(sq + d + causal), 5)
+        q, k, v, dout = (
+            jax.random.normal(kk, (1, 2, n, d), jnp.bfloat16)
+            for kk, n in zip(ks, (sq, sk, sk, sq)))
+        dlse = jax.random.normal(ks[4], (1, 2, sq), jnp.float32)
+        scale = 1.0 / math.sqrt(d)
+        up = lambda t: t.astype(jnp.float32)
+
+        (out, lse), vjp = jax.vjp(
+            lambda q, k, v: pk.flash_attention(
+                q, k, v, scale=scale, causal=causal, return_lse=True,
+                block_q=blocks, block_k=blocks),
+            q, k, v)
+        dq, dk, dv = vjp((dout, dlse))
+        assert {t.dtype for t in (out, dq, dk, dv)} == {jnp.dtype(jnp.bfloat16)}
+        assert lse.dtype == jnp.float32
+
+        out32, lse32 = pk._flash_impl(up(q), up(k), up(v), scale, causal, 256, 256)
+        # the backward of the float32 path on the SAME residuals: the
+        # bfloat16 ``out`` and its lse, as the custom_vjp saved them
+        dq32, dk32, dv32 = pk._flash_bwd_impl(
+            up(q), up(k), up(v), up(out), lse, up(dout), dlse, scale, causal,
+            256, 256)
+        for name, got, want in (("out", out, out32), ("dq", dq, dq32),
+                                ("dk", dk, dk32), ("dv", dv, dv32)):
+            _bf16_close(got, want, f"{name} (causal={causal}, d={d}, {sq}x{sk})")
+        # lse is float32 on both paths: held to float32 rounding, which is
+        # well inside a bfloat16 ulp
+        np.testing.assert_allclose(np.asarray(lse), np.asarray(lse32),
+                                   rtol=2e-6, atol=2e-6)
+
+    def test_mixed_dtypes_keep_float32_products(self, force_pallas):
+        """A float32 ``k`` beside bfloat16 ``q``/``v`` promotes: the kernels
+        take the ``HIGHEST`` path and read what all-float32 inputs read."""
+        ks = jax.random.split(jax.random.PRNGKey(5), 3)
+        q, k, v = (jax.random.normal(kk, (1, 1, 256, 64), jnp.bfloat16)
+                   for kk in ks)
+        up = lambda t: t.astype(jnp.float32)
+        out, lse = pk.flash_attention(q, up(k), v, causal=True, return_lse=True)
+        out32, lse32 = pk.flash_attention(up(q), up(k), up(v), causal=True,
+                                          return_lse=True)
+        assert out.dtype == jnp.bfloat16
+        np.testing.assert_array_equal(np.asarray(lse), np.asarray(lse32))
+        np.testing.assert_array_equal(
+            np.asarray(out), np.asarray(out32.astype(jnp.bfloat16)))
+
+
 class TestInterpretVmaHazard:
     """force_pallas + the flagship's check_vma=True shard_map must work on
     the CPU mesh: the interpret-mode Pallas HLO interpreter rejects
