@@ -8,7 +8,13 @@ ONE thread drives the load: a completed `Future` puts itself on a queue (its
 done-callback, in the engine's thread, does nothing else), and the driver's
 loop takes it off, notes the time, and submits that client's next request.
 The loop is started in set-up and runs `warm_completions` requests before the
-window opens, so the window sees steady state.
+window opens, so the window sees steady state. When the window has closed
+nothing more is sent, and the driver waits for the requests that were under
+way: `decode_tokens_per_s` is the tokens callers were DELIVERED inside the
+window (`work.tokens_delivered`: a request's tokens spread over its life from
+submit to last token), so a request that straddles an end of the window needs
+its own end known. `req_ms_per_token_p90` is over the requests that completed
+inside the window.
 
 `correct`: once the window has closed and the engine is gone, a sample of the
 requests it finished (the longest among them, the rest drawn from the seed)
@@ -124,8 +130,7 @@ class Cell:
         pass                  # the engine runs on: the trace cuts where it is
 
     # -- the window -----------------------------------------------------
-    def window(self, probe):
-        t_open = time.perf_counter()
+    def window(self, probe, drain_s=90.0):
         first = len(self.records)
         while not probe.done():
             try:
@@ -135,22 +140,41 @@ class Cell:
                 probe.poll()
             else:
                 probe.unit()
-        elapsed = probe.elapsed()
+        probe.close()
+        elapsed = probe.window_s
+        t_open, t_close = probe.t0, probe.t0 + elapsed
         self.open = False
         done = self.records[first:]
         self.window_done = done
-        self.window_span = (t_open, t_open + elapsed)
+        # the requests under way at the close: each is waited for, `drain_s`
+        # at the most; one that never ends has failed
+        under_way = self.next_req - len(self.records)
+        try:
+            while drain_s and len(self.records) < self.next_req:
+                self._take(timeout=max(
+                    0.001, t_close + drain_s - time.perf_counter()))
+        except queue.Empty:
+            pass
+        late = self.records[first + len(done):]
+        self.window_late = late
+        self.never_ended = (under_way - len(late)) if drain_s else 0
+        lost = self.never_ended + sum(r[3] is None for r in late)
         ok = [r for r in done if r[3] is not None]
-        n_out = [len(r[3]) - len(self.requests[r[0] % len(self.requests)][0])
-                 for r in ok]
-        per_tok = [1e3 * (r[2] - r[1]) / n for r, n in zip(ok, n_out)]
+        per_tok = [1e3 * (r[2] - r[1]) / self._n_out(r) for r in ok]
         worst = 1e3 * elapsed             # a failed request: the worst there is
         per_tok += [worst] * (len(done) - len(ok))
+        delivered = self.ctx.work.tokens_delivered(
+            [(r[1], r[2], self._n_out(r)) for r in done + late
+             if r[3] is not None], t_open, t_close)
         return {"metrics": {
-                    "decode_tokens_per_s": sum(n_out) / elapsed,
-                    "req_ms_per_token_p95": float(np.percentile(per_tok, 95))},
-                "attempted": len(done), "failed": len(done) - len(ok),
-                "output_tokens": sum(n_out)}
+                    "decode_tokens_per_s": delivered / elapsed,
+                    "req_ms_per_token_p90": float(np.percentile(per_tok, 90))},
+                "attempted": len(done) + len(late) + self.never_ended,
+                "failed": len(done) - len(ok) + lost}
+
+    def _n_out(self, record):
+        return len(record[3]) - len(
+            self.requests[record[0] % len(self.requests)][0])
 
     def release(self):
         self.open = False
@@ -171,10 +195,11 @@ class Cell:
             self.mix["check_requests"]) - 1]]
 
     def _wrong_answers(self):
-        """Finished requests whose answer has the wrong length or does not
-        echo its prompt, plus requests that failed or were refused."""
-        bad = 0
-        for i, _t0, _t1, toks in self.window_done:
+        """Finished requests (in the window, or under way at its close and
+        waited for) whose answer has the wrong length or does not echo its
+        prompt, plus requests that failed, were refused or never ended."""
+        bad = self.never_ended
+        for i, _t0, _t1, toks in self.window_done + self.window_late:
             prompt, n_out = self.requests[i % len(self.requests)]
             if (toks is None or len(toks) != len(prompt) + n_out
                     or not np.array_equal(toks[:len(prompt)], prompt)
@@ -202,8 +227,10 @@ class Cell:
                 "wrong_answers": self._wrong_answers(),
                 "tokens_judged": served, "requests_judged": len(gaps)}
 
-    def check(self):
-        got = self.numbers()
+    def check(self, got=None):
+        """The numbers beside their limits; `got` puts other numbers (the
+        control's, a fault's) in the program's place."""
+        got = self.numbers() if got is None else got
         return [(n, got[n], float(self.limits[n])) for n in self.limits]
 
     def readings(self):
@@ -213,7 +240,7 @@ class Cell:
         probe = Probe(float(self.mix.get("readings_seconds", 15.0)), None,
                       self.counters, self.sync)
         probe.start()
-        self.window(probe)
+        self.window(probe, drain_s=0.0)   # the numbers need no rate
         self.release()
         return self.numbers()
 

@@ -7,4 +7,5 @@ has no collective and reports nothing."""
 def read(run):
     if not run.trace or run.chips < 2:
         return None
-    return 100.0 * run.trace["device0_collective_s"] / run.trace["window_s"]
+    return (100.0 * run.trace["collectives"]["0"]["total_s"]
+            / run.trace["window_s"])

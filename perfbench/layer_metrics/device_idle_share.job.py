@@ -1,7 +1,7 @@
 """Device: 1 - (union of the devices' operation intervals) / traced window,
 mean over the chips used, in the analytics cells."""
 
-from perfbench.trace_reduce import idle_share_percent
+from perfbench.trace_scopes import idle_share_percent
 
 
 def read(run):

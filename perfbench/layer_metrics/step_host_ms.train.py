@@ -8,17 +8,7 @@ nothing, and the metric is left out."""
 
 
 def read(run):
-    tr = run.probe.traced
-    if not tr or "t1" not in tr:
-        return None
-    try:
-        from heat_tpu.utils import profiling
-
-        records = profiling.spans()
-    except (ImportError, AttributeError):
-        return None
-    took = [r.t1 - r.t0 for r in records
-            if r.name == "train_step" and tr["t0"] <= r.t0 and r.t1 <= tr["t1"]]
+    took = [r.t1 - r.t0 for r in run.spans_named("train_step")]
     if not took:
         return None
     return 1e3 * sum(took) / len(took)

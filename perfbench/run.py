@@ -17,6 +17,14 @@ reference under `references/`. The last line of stdout is one JSON object:
 `correct, attempted, failed, metrics, device` (+ `breakdown` when traced) and
 last the numbers compared, each beside its limit.
 
+A traced run hands each reader two things: the ONE reduction of the traced
+sub-window's xplane (`run.trace`, `trace_scopes.py`: busy and idle time over
+the chips used, device 0's time by program, scope and direction, idle gaps by
+innermost host span) and the program's own host spans (`run.spans`, the ring
+of `heat_tpu.utils.profiling`, on the probe's clock). The ring records from
+before set-up in every traced run, so that a request that was sent before the
+profiler started still has its spans; `spans_named` cuts them to a window.
+
 Exit codes: 0 a result was printed; 2 no accelerator, too few chips, an
 unknown `device_kind` or an unknown cell; 3 a share read over 100%.
 """
@@ -108,7 +116,7 @@ class Probe:
         self.tracing = False
         self.traced = None
         self._win = None
-        self.t0 = None
+        self.t0 = self.window_s = None
 
     def start(self):
         self.at_start = self._counters()
@@ -180,11 +188,17 @@ class Probe:
         self.tracing = False
         jax.profiler.stop_trace()
 
-    def finish(self):
+    def close(self):
+        """The window ends HERE; what the driver still does before it returns
+        (waiting for answers that were due) is not in it."""
         if self.tracing:
             self._trace_stop()
         self.window_s = self.elapsed()
         self.at_end = self._counters()
+
+    def finish(self):
+        if self.window_s is None:
+            self.close()
 
 
 class Run:
@@ -199,6 +213,47 @@ class Run:
         if name not in a or name not in b:
             return None
         return b[name] - a[name]
+
+    def spans_named(self, name, traced=True):
+        """The program's spans of that name that lie whole inside the traced
+        sub-window (or, `traced=False`, end inside the run's window)."""
+        tr = self.probe.traced
+        if traced:
+            if not tr or "t1" not in tr:
+                return []
+            lo, hi = tr["t0"], tr["t1"]
+            return [r for r in self.spans
+                    if r.name == name and lo <= r.t0 and r.t1 <= hi]
+        lo, hi = self.probe.t0, self.probe.t0 + self.probe.window_s
+        return [r for r in self.spans if r.name == name and lo <= r.t1 <= hi]
+
+
+def program_spans(enable=False) -> list:
+    """The ring of the program's host spans (`heat_tpu.utils.profiling`),
+    oldest first; `enable` makes it record from now on. A program without
+    the module has no spans."""
+    try:
+        from heat_tpu.utils import profiling
+    except ImportError:
+        return []
+    if enable:
+        profiling.enable()
+    return profiling.spans()
+
+
+def breakdown_of(trace: dict) -> dict:
+    """What the ledger keeps of a traced run: the ten heaviest device
+    operations, each under the program's scope (`scope:operation`), and the
+    idle gaps by what the host was doing (the innermost `ht.`/`pb.` span)."""
+    ops = {}
+    for (_prog, scope, _d), row in trace["by_scope"].items():
+        for name, t in row["ops"].items():
+            key = f"{scope}:{name}"
+            ops[key] = ops.get(key, 0.0) + t
+    return {"device_ops": [list(kv) for kv in sorted(
+                ops.items(), key=lambda kv: -kv[1])[:10]],
+            "idle_gaps": [list(kv) for kv in
+                          list(trace["idle_gaps"].items())[:10]]}
 
 
 def device_summary(devices):
@@ -303,6 +358,8 @@ def main(argv=None):
     cell, chips, devices, traffic = ctx.cell, ctx.chips, ctx.devices, ctx.traffic
     seconds = args.seconds if args.seconds is not None else (
         2.0 if args.rehearse else float(bench["run_seconds"]))
+    if args.trace:
+        program_spans(enable=True)
     program = driver.Cell(ctx)
     program.setup()
 
@@ -326,22 +383,19 @@ def main(argv=None):
 
     metrics, breakdown = {}, None
     if args.trace:
-        from perfbench import trace_reduce
+        from perfbench import trace_scopes
 
+        xplane = trace_scopes.find_xplane(TRACE_DIR)
         keep = os.environ.get("PERFBENCH_KEEP_TRACE")   # a builder's look
         if keep:
             os.makedirs(keep, exist_ok=True)
-            xplane = trace_reduce.find_xplane(TRACE_DIR)
             shutil.copy(xplane, os.path.join(keep, cell["name"] + ".xplane.pb"))
-            with open(os.path.join(keep, cell["name"] + ".planes.txt"), "w") as f:
-                f.write(trace_reduce.describe(trace_reduce.load_planes(xplane)))
-        trace = trace_reduce.reduce_trace(TRACE_DIR, chips, args.rehearse)
+        trace = trace_scopes.reduce_file(xplane, chips, args.rehearse)
         shutil.rmtree(TRACE_DIR, ignore_errors=True)
         device["busy_s"], device["window_s"] = trace["busy_s"], trace["window_s"]
-        breakdown = {"device_ops": [list(kv) for kv in trace["device0_ops"][:10]],
-                     "idle_gaps": [list(kv) for kv in
-                                   trace["device0_idle_gaps"][:10]]}
-        run = Run(trace=trace, probe=probe, result=result, **ctx.__dict__)
+        breakdown = breakdown_of(trace)
+        run = Run(trace=trace, spans=program_spans(), probe=probe,
+                  result=result, **ctx.__dict__)
         for m in metrics_of(bench, "per_layer", cell["name"]):
             value = load_by_name("layer_metrics", m["name"]).read(run)
             if value is None:

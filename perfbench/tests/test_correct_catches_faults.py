@@ -4,7 +4,8 @@ Each test skips the harness's look for a chip (`--rehearse`: tiny sizes, the
 CPU) and drives the REST of a run through `perfbench.run.main`, with one
 fault planted in the program: a step that returns its state unchanged; half
 of the batch left out, the mean taken over the rest; the exchange between
-chips left out; a token or an answer altered where it is produced. The
+chips left out; a token or an answer altered where it is produced; for the
+distance matrix a row block left out and operands rounded to bfloat16. The
 limits are the cells' own (`perfbench/limits/`).
 
 The controls are kept here too, at a size a test can hold: the nearest lower
@@ -12,6 +13,7 @@ precision in the program's place has to fail one of the cell's numbers.
 """
 
 import json
+import os
 
 import numpy as np
 import pytest
@@ -22,18 +24,21 @@ import jax.numpy as jnp
 from perfbench import run
 
 KM1, KM4 = "kmeans-w25m.fit30", "kmeans-w25m-x4.fit30"
-TRAIN, DECODE = "pythia-1.4b-d8.train-s2048", "pythia-1.4b-d8.decode-closed48"
+TRAIN = "pythia-1.4b-d8.train-s2048"
+DECODE = "pythia-1.4b-d8.decode-conv-closed48"
+CDIST = "heat-cdist-40k.cdist"
 HELD = "perfbench/HELD.json"     # cells built and held back (PERF.md s. 7)
 
 
 def bench_of(cell):
-    return "BENCHMARK.json" if cell == TRAIN else HELD
+    return HELD if cell in (KM1, KM4) else "BENCHMARK.json"
 
 
-def last_line(capsys, cell, seed=2 ** 31 + 11, seconds="0.5"):
+def last_line(capsys, cell, seed=2 ** 31 + 11, seconds="0.5", trace=0):
     capsys.readouterr()
     assert run.main(["--workload", cell, "--rehearse", "--seed", str(seed),
-                     "--seconds", seconds, "--bench", bench_of(cell)]) == 0
+                     "--seconds", seconds, "--bench", bench_of(cell),
+                     "--trace", str(trace)]) == 0
     return json.loads(capsys.readouterr().out.strip().splitlines()[-1])
 
 
@@ -54,13 +59,93 @@ def kmeans_module(monkeypatch):
     fusion.reset()
 
 
-@pytest.mark.parametrize("cell", [KM1, KM4, TRAIN, DECODE])
+@pytest.fixture
+def distance_module():
+    from heat_tpu.spatial import distance
+
+    distance._RING_CACHE.clear()  # programs built before the fault was planted
+    yield distance
+    distance._RING_CACHE.clear()
+
+
+@pytest.mark.parametrize("cell", [KM1, KM4, TRAIN, DECODE, CDIST])
 def test_sound_program_is_correct(capsys, cell):
     line = last_line(capsys, cell)
     assert line["correct"] is True and failed(line) == []
     assert line["rehearsal"] is True and line["device"]["platform"] == "cpu"
     assert line["attempted"] > 0 and line["failed"] == 0
     assert list(line)[-1] == "checks"
+    bench = run.load_json(os.path.join(run.ROOT, bench_of(cell)))
+    assert set(line["metrics"]) == {
+        m["name"] for m in run.metrics_of(bench, "end_to_end", cell)}
+
+
+@pytest.mark.parametrize("cell,silent", [
+    # what a rehearsal's trace cannot feed stays silent: there is no device
+    # plane, so nothing is read by the program's scopes
+    (DECODE, {"cache_move_share.decode", "weights_cast_share.decode"}),
+    (CDIST, set())])
+def test_traced_rehearsal_of_a_new_cell_reports_its_layer_metrics(
+        capsys, cell, silent):
+    line = last_line(capsys, cell, seconds="1.5", trace=1)
+    bench = run.load_json(os.path.join(run.ROOT, "BENCHMARK.json"))
+    listed = {m["name"] for m in run.metrics_of(bench, "per_layer", cell)}
+    assert set(line["metrics"]) == listed - silent
+    assert line["correct"] is True and line["device"]["busy_s"] > 0
+    assert len(line["breakdown"]["device_ops"]) <= 10
+    assert all(m["value"] <= 100.0 for m in line["metrics"].values()
+               if m["unit"] == "%")
+
+
+def test_cdist_row_block_left_out(capsys, distance_module, monkeypatch):
+    sound = distance_module._euclidean_tile
+
+    def holed(x, y, expand):
+        return sound(x, y, expand).at[64:128].set(0.0)
+
+    monkeypatch.setattr(distance_module, "_euclidean_tile", holed)
+    line = last_line(capsys, CDIST)
+    assert line["correct"] is False and failed(line) == ["dist_err"]
+    assert line["checks"]["dist_err"]["value"] > 0.5    # whole distances gone
+
+
+def test_cdist_operands_rounded_to_bfloat16(capsys, distance_module,
+                                            monkeypatch):
+    sound = distance_module._euclidean_tile
+
+    def rounded(x, y, expand):
+        return sound(x.astype(jnp.bfloat16), y.astype(jnp.bfloat16),
+                     expand).astype(x.dtype)
+
+    monkeypatch.setattr(distance_module, "_euclidean_tile", rounded)
+    line = last_line(capsys, CDIST)
+    assert line["correct"] is False and failed(line) == ["dist_err"]
+
+
+def test_cdist_answer_altered_where_it_is_produced(capsys, distance_module,
+                                                   monkeypatch):
+    sound = distance_module._euclidean_tile
+
+    def altered(x, y, expand):
+        return sound(x, y, expand).at[3, 5].add(0.1)      # ONE entry of n x n
+
+    monkeypatch.setattr(distance_module, "_euclidean_tile", altered)
+    line = last_line(capsys, CDIST)
+    assert line["correct"] is False and failed(line) == ["dist_err"]
+
+
+def test_cdist_result_of_another_shape_is_no_answer(capsys, distance_module,
+                                                    monkeypatch):
+    sound = distance_module.cdist
+
+    def fewer(x, y=None, quadratic_expansion=False):
+        return sound(x, y, quadratic_expansion)[:-1]
+
+    import heat_tpu as ht
+
+    monkeypatch.setattr(ht.spatial, "cdist", fewer)
+    line = last_line(capsys, CDIST)
+    assert line["correct"] is False and failed(line) == ["dist_err"]
 
 
 def test_kmeans_step_returns_its_state_unchanged(capsys, kmeans_module):
@@ -210,6 +295,7 @@ def test_kmeans_control_bfloat16_storage_fails_centroid_err():
     assert got["centroid_err"] <= limits["centroid_err"]
     assert ctl["centroid_err"] > limits["centroid_err"]
     assert ctl["centroid_err"] > 3 * got["centroid_err"]
+    assert run.judge(cell.check(got)) and not run.judge(cell.check(ctl))
 
 
 def test_kmeans_common_shrink_of_the_size_read_on_the_chip_is_not_correct(
@@ -250,6 +336,7 @@ def test_train_control_float8_fails_a_number():
     assert all(got[n] <= limits[n] for n in limits)
     assert any(ctl[n] > limits[n] for n in limits)
     assert ctl["grad_norm_gap"] > 3 * got["grad_norm_gap"]
+    assert run.judge(cell.check(got)) and not run.judge(cell.check(ctl))
 
 
 def test_decode_control_float8_reads_a_gap_where_the_program_reads_none():
@@ -258,6 +345,37 @@ def test_decode_control_float8_reads_a_gap_where_the_program_reads_none():
     ctl = cell.control()
     assert got["tokens_judged"] > 0 and got["wrong_answers"] == 0
     assert ctl["token_gap"] > 3 * got["token_gap"] and ctl["token_gap"] > 0.01
+
+
+def test_cdist_controls_fail_dist_err_and_the_program_does_not():
+    """The reference's expansion with its product at `high` (three bfloat16
+    passes) and with bfloat16 operands, in the answer's place."""
+    cell, limits = _cell(CDIST)
+    got = cell.readings()
+    ctl = cell.control()
+    assert got["dist_err"] <= limits["dist_err"]
+    assert ctl["dist_err.high"] > limits["dist_err"]
+    assert ctl["dist_err.bf16"] > 3 * ctl["dist_err.high"]
+    assert ctl["dist_err"] == ctl["dist_err.high"] > 3 * got["dist_err"]
+    # the control in the program's place, through the run's own judgement
+    assert run.judge(cell.check(got)) and not run.judge(cell.check(ctl))
+
+
+@pytest.mark.parametrize("workload", [CDIST, DECODE])
+def test_readings_tool_judges_the_control_not_correct(workload, capsys):
+    """`tools/readings.py` puts the control's numbers through the driver's
+    `check()` and the harness's `judge()`: the line a chip call prints."""
+    from perfbench.tools import readings
+
+    capsys.readouterr()
+    readings.main(["--workload", workload, "--seeds", str(2 ** 31 + 21),
+                   "--control", "1", "--rehearse"])
+    lines = [json.loads(ln) for ln in capsys.readouterr().out.splitlines()
+             if ln.startswith("{")]
+    by_kind = {ln["kind"]: ln for ln in lines}
+    assert by_kind["program"]["correct"] is True
+    assert by_kind["control"]["correct"] is False
+    assert set(by_kind["control"]["checks"]) == set(by_kind["program"]["checks"])
 
 
 def test_a_share_over_100_percent_fails_the_run(capsys, monkeypatch):

@@ -1,14 +1,12 @@
 """The reduction by name (`perfbench/trace_scopes.py`): on hand-made planes
 where every answer can be worked out, on the two KMeans traces PR 28 recorded
-(programs without scopes: everything `unscoped`, sums equal to
-`trace_reduce`'s), and on one small trace recorded on the TPU v5e by PR 29
+(programs without scopes: everything `unscoped`), and on one small trace recorded on the TPU v5e by PR 29
 (one step of a two-layer width-256 `TransformerLM`), whose table is pinned."""
 
 import os
 
 import pytest
 
-from perfbench import trace_reduce as tr
 from perfbench import trace_scopes as ts
 from perfbench.tools import scopes as tool
 
@@ -120,11 +118,8 @@ def test_idle_gap_goes_to_the_innermost_span_not_the_outermost():
     assert r["idle_gaps"] == pytest.approx({
         "ht.train_step.place": 0.010, "ht.train_step.dispatch": 0.030,
         "pb.window": 0.020})
-    # today's rule (trace_reduce) gives all of it to the outermost spans
-    old = dict(tr.reduce_planes(
-        {k: {ln: [e[:3] for e in evs] for ln, evs in v.items()}
-         for k, v in p.items()}, chips=1)["device0_idle_gaps"])
-    assert old == pytest.approx({"step": 0.040, "wait": 0.020})
+    assert sum(r["idle_gaps"].values()) == pytest.approx(
+        r["window_s"] - r["device0_busy_s"])
     # a gap no span covers by half, window gone too, is nobody's
     assert ts.gap_owner((0.0, 10.0), [("ht.x", 0.0, 4.0, {})]) == "none"
     assert ts.gap_owner((0.0, 10.0), [("ht.x", 0.0, 5.0, {}),
@@ -166,17 +161,27 @@ def test_wire_reader_rejects_what_is_no_xplane(tmp_path):
 
 @pytest.mark.parametrize("name,chips", [("recorded_kmeans_1chip", 1),
                                         ("recorded_kmeans_4chip", 4)])
-def test_recorded_kmeans_traces_agree_with_trace_reduce(name, chips):
+def test_recorded_kmeans_traces_by_name(name, chips):
     path = os.path.join(HERE, name + ".xplane.pb")
-    mine, theirs = ts.load_planes(path), tr.load_planes(path)
-    # the hand reader sees what ProfileData sees: planes, lines, names, times
-    assert {p: sorted(l) for p, l in mine.items()} \
-        == {p: sorted(l) for p, l in theirs.items()}
-    for p, lines in theirs.items():
-        for ln, evs in lines.items():
-            assert [e[:3] for e in mine[p][ln]] == evs, (p, ln)
-    r, old = ts.reduce_planes(mine, chips), tr.reduce_planes(theirs, chips)
-    assert r["window_s"] == old["window_s"]
+    mine = ts.load_planes(path)
+    # the hand reader sees what `jax.profiler.ProfileData` sees: planes,
+    # lines, names, whole nanoseconds
+    from jax.profiler import ProfileData
+    for plane in ProfileData.from_file(path).planes:
+        theirs = {}
+        for line in plane.lines:
+            theirs.setdefault(line.name, []).extend(
+                (ts.op_name(ev.name), float(ev.start_ns), float(ev.duration_ns))
+                for ev in line.events)
+        assert sorted(theirs) == sorted(mine[plane.name])
+        for ln, evs in theirs.items():
+            assert [e[:3] for e in mine[plane.name][ln]] == evs, (plane.name, ln)
+    r = ts.reduce_planes(mine, chips)
+    old = {1: {"window_s": 2.116613435, "device0_busy_s": 2.003013582,
+               "device0_collective_s": 0.0},
+           4: {"window_s": 2.147488868, "device0_busy_s": 2.004102558,
+               "device0_collective_s": 7.9638e-05}}[chips]
+    assert r["window_s"] == pytest.approx(old["window_s"], rel=1e-9)
     assert abs(r["device0_busy_s"] - old["device0_busy_s"]) < 1e-9
     rows = r["by_scope"]
     # PR 28's programs carry no scope: three programs, all unscoped
@@ -220,10 +225,9 @@ def test_recorded_train_step_by_scope_is_pinned():
     assert os.path.getsize(path) <= 512 * 1024
     planes_ = ts.load_planes(path)
     r = ts.reduce_planes(planes_)
-    old = tr.reduce_planes(tr.load_planes(path), chips=1)
-    assert r["window_s"] == old["window_s"] == pytest.approx(0.002761929)
-    assert r["device0_busy_s"] == pytest.approx(0.000645116, abs=1e-9)
-    assert abs(r["device0_busy_s"] - old["device0_busy_s"]) < 1e-9
+    assert r["window_s"] == pytest.approx(0.002761929)
+    assert r["busy_s"] == r["device0_busy_s"] == pytest.approx(
+        0.000645116, abs=1e-9)
     # the module line: one program, named by its family
     assert r["programs"] == {"jit_train_step": {
         "runs": 1, "device_s": pytest.approx(0.000650705, abs=1e-9)}}
@@ -265,8 +269,6 @@ def test_recorded_train_step_by_scope_is_pinned():
     # and dispatch, neither half of it), then the wait for the device
     assert r["idle_gaps"] == pytest.approx({
         "ht.train_step": 0.001544692, "pb.wait": 0.000572121}, abs=1e-9)
-    assert dict(old["device0_idle_gaps"]) == pytest.approx(
-        {"step": 0.001544692, "wait": 0.000572121}, abs=1e-9)
     # the program's span lies inside the benchmark's on the host plane
     spans = {e[0]: e for e in ts.host_spans(planes_)}
     step, inner = spans["pb.step"], spans["ht.train_step"]

@@ -30,7 +30,7 @@ def test_token_batches_pure_and_in_vocab():
 
 
 def test_request_sizes_keep_to_clips_and_capacity():
-    m = mix("decode-closed48")
+    m = mix("decode-conv-closed48")
     s = traffic.request_sizes(m)
     assert s.shape == (m["n_sizes"], 2)
     assert np.array_equal(s, traffic.request_sizes(m))
@@ -40,11 +40,18 @@ def test_request_sizes_keep_to_clips_and_capacity():
     for pi, oi in s:
         assert traffic.prompt_bucket(pi, m["prompt_bucket_min"]) + oi \
             <= m["max_seq_len"]
-    assert 200 < np.median(p) < 320 and 100 < np.median(o) < 160
+    # prompts longer than answers, the tails INSIDE the ranges the engine's
+    # buckets leave (a clip that binds half the prompts is no tail): the mix
+    # states what binds, names no source and says so
+    assert 200 < np.median(p) < 300 and 100 < np.median(o) < 160
+    assert (p == m["prompt"]["max"]).sum() == 3 <= 0.05 * len(p)
+    assert (o == m["output"]["max"]).sum() == 4 <= 0.05 * len(o)
+    assert "3 of the 256 prompts" in m["lengths"] and "source" not in m
+    assert "NOT from a trace" in m["lengths"] and m["assumed"]
 
 
 def test_requests_same_sizes_in_the_same_order_other_tokens():
-    m = mix("decode-closed48")
+    m = mix("decode-conv-closed48")
     a = traffic.requests(m, 7, 50304)
     b = traffic.requests(m, 7, 50304)
     c = traffic.requests(m, 2 ** 31 + 5, 50304)

@@ -8,7 +8,10 @@ For each seed: the driver's set-up at the cell's own size, then
 `readings()` (the program against the plain reference, the numbers that
 `correct` compares); for the first `--control` seeds also `control()` (the
 nearest lower precision in the program's place) and for the first `--faults`
-seeds `faults()` where the driver has them. One JSON line per reading on
+seeds `faults()` where the driver has them. Every reading goes through the
+driver's own `check()` and the harness's `judge()`, as a run's numbers do, and
+its line says `correct`: true for the program, FALSE for the control and for
+each fault, or the limit separates nothing. One JSON line per reading on
 stdout and appended to `chiprun_out/readings.jsonl`. `--set key=value` lays a
 number over the configuration (a look at how a reading grows with the size,
 never a cell); `--dump` adds what the driver keeps of the answers compared
@@ -24,7 +27,7 @@ import time
 from perfbench import run as harness
 
 
-def main():
+def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seeds", required=True)
@@ -35,7 +38,7 @@ def main():
     ap.add_argument("--set", action="append", default=[],
                     metavar="KEY=NUMBER")
     ap.add_argument("--dump", action="store_true")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
     seeds = [int(s) for s in args.seeds.split(",")]
     out_dir = os.path.join(harness.ROOT, "chiprun_out")
     os.makedirs(out_dir, exist_ok=True)
@@ -47,6 +50,9 @@ def main():
 
     with open(os.path.join(out_dir, "readings.jsonl"), "a") as log:
         def emit(rec):
+            checks = cell.check(rec["numbers"])
+            rec.update(correct=harness.judge(checks), checks={
+                n: {"value": v, "limit": lim} for n, v, lim in checks})
             rec.update(workload=args.workload, rehearsal=args.rehearse,
                        device=jax.devices()[0].device_kind, **laid)
             line = json.dumps(rec)

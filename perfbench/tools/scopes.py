@@ -7,8 +7,8 @@ Prints `perfbench/trace_scopes.py`'s reduction of one kept trace
 device 0's self time inside `pb.window` by (program, scope, direction) with
 the compiler's flops and bytes beside it, the `unscoped` rest by operation,
 the program runs, the idle gaps by innermost host span, and collective time
-exposed against hidden. Needs no JAX and no chip. Never part of a benchmark
-run: the next `benchmark` issue wires the reduction into `run.py`.
+exposed against hidden. Needs no JAX and no chip. It is the reduction that
+`run.py` hands the per-layer readers as `run.trace`, printed for a human.
 """
 
 import argparse

@@ -1,5 +1,6 @@
-"""From the profiler's `.xplane.pb` to device time BY NAME, with nothing but
-the standard library (`trace_reduce.py` stays as it is; its helpers are used).
+"""From the profiler's `.xplane.pb` to numbers, with nothing but the standard
+library: ONE reader of the file and ONE reduction, which every per-layer
+reader is handed as `run.trace` (busy and idle time, and device time BY NAME).
 
 `jax.profiler.ProfileData` hands out each event's name, start and duration,
 and the event's own stats. What the program's names ride in is the plane's
@@ -22,10 +23,15 @@ dot_general`), `flops` and `bytes_accessed` (XLA's cost analysis of that
 operation: the COMPILER'S count of what it emitted, never the work the
 algorithm needs), `program_id` and `hlo_category`. A time is
 `line.timestamp_ns + offset_ps // 1000` and a duration `duration_ps // 1000`,
-whole nanoseconds as `ProfileData` gives them, so that both reductions agree.
+whole nanoseconds as `jax.profiler.ProfileData` gives them.
 
-The reduction (`reduce_planes`), all on device 0 inside the `pb.window` span:
+The reduction (`reduce_planes`), inside the `pb.window` span (the traced
+sub-window, which `run.py` opens and closes on whole units):
 
+* `busy_s`: the union of the intervals in which an operation ran on a device,
+  mean over the chips used (`busy_s_by_device`, `device0_busy_s`); the idle
+  share is 1 - busy / window (`idle_share_percent`). A traced run in which no
+  operation ran on a device is not a run: it raises.
 * `by_scope`: SELF time by (program, scope, direction). `scope` is the
   innermost of `SCOPES` in `tf_op` (a fusion carries its root's `op_name`, so
   it counts under its root's scope), `unscoped` where there is none;
@@ -39,16 +45,23 @@ The reduction (`reduce_planes`), all on device 0 inside the `pb.window` span:
   only when nothing inside it qualifies; `none` when not even that.
 * `collectives`: time of collective operations, split into exposed (no other
   operation of that device running) and hidden.
+
+A rehearsal on the CPU has no device plane; there the XLA:CPU worker threads
+of the host plane stand in as device 0 (no metadata: all `unscoped`), so that
+the code path is exercised. Its numbers are never a device metric (`run.py`
+marks the whole line a rehearsal).
 """
 
 from __future__ import annotations
 
+import glob
+import os
 import re
 import struct
 
-from perfbench import trace_reduce as tr
-
+WINDOW_SPAN = "pb.window"
 SPAN_PREFIXES = ("ht.", "pb.")
+OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
 ASYNC_LINE = "Async XLA Ops"
 UNSCOPED = "unscoped"
@@ -61,8 +74,53 @@ SCOPES = frozenset((
     "lloyd.norms", "lloyd.dist", "lloyd.argmin", "lloyd.sums", "lloyd.psum",
     "lloyd.update", "lloyd.inertia"))
 META_KEPT = ("tf_op", "flops", "bytes_accessed", "program_id", "hlo_category")
+COLLECTIVES = ("all-reduce", "all-gather", "reduce-scatter", "all-to-all",
+               "collective-permute")
 _WRAPPED = re.compile(r"^\w+\((.*)\)$")
 _PROGRAM = re.compile(r"^(.*)\((\d+)\)$")
+_DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
+_CPU_WORKER = ("tf_XLAPjRtCpuClient", "tf_XLAEigen", "tf_XLATfrtCpuClient")
+
+
+def find_xplane(trace_dir: str) -> str:
+    found = sorted(glob.glob(os.path.join(
+        trace_dir, "plugins", "profile", "*", "*.xplane.pb")))
+    if not found:
+        raise FileNotFoundError(f"no .xplane.pb under {trace_dir}")
+    return found[-1]
+
+
+# ---------------------------------------------------------------------- #
+# intervals and names                                                    #
+# ---------------------------------------------------------------------- #
+def op_name(name: str) -> str:
+    """The TPU's device lines name an operation by its whole HLO text
+    (`fusion.3 = f32[...] fusion(...)`): keep what stands before ` = `."""
+    return name.split(" = ", 1)[0].lstrip("%")
+
+
+def is_collective(name: str) -> bool:
+    return any(c in name for c in COLLECTIVES)
+
+
+def union(intervals) -> list:
+    """Sorted, disjoint [start, end) covering the same points."""
+    out = []
+    for s, e in sorted(i for i in intervals if i[1] > i[0]):
+        if out and s <= out[-1][1]:
+            out[-1][1] = max(out[-1][1], e)
+        else:
+            out.append([s, e])
+    return out
+
+
+def length(disjoint) -> float:
+    return sum(e - s for s, e in disjoint)
+
+
+def clip(intervals, lo, hi):
+    return [(max(s, lo), min(e, hi)) for s, e in intervals
+            if min(e, hi) > max(s, lo)]
 
 
 # ---------------------------------------------------------------------- #
@@ -176,9 +234,8 @@ def _line(buf):
 
 def load_planes(path: str) -> dict:
     """{plane name: {line name: [(operation name, start_ns, duration_ns,
-    meta)]}}: `trace_reduce.load_planes`' form with the event metadata's kept
-    stats (`META_KEPT`, one dict per metadata entry, shared by its events) as
-    a fourth member."""
+    meta)]}}; `meta` holds the event metadata's kept stats (`META_KEPT`, one
+    dict per metadata entry, shared by its events)."""
     with open(path, "rb") as f:
         space = memoryview(f.read())
     planes = {}
@@ -205,7 +262,7 @@ def load_planes(path: str) -> dict:
                 sname = smeta.get(sk, "")
                 if sname in META_KEPT:
                     kept[sname] = smeta.get(value, "") if ref else value
-            metas[k] = (tr.op_name(m["name"]), kept)
+            metas[k] = (op_name(m["name"]), kept)
         out = planes.setdefault(pname, {})
         for lbuf in lines:
             lname, t0, events = _line(lbuf)
@@ -288,7 +345,7 @@ def gap_owner(gap, spans):
         name, s, d = ev[0], ev[1], ev[2]
         if min(gap[1], s + d) - max(gap[0], s) < need:
             continue
-        rank = (name == tr.WINDOW_SPAN, not name.startswith("ht."), d)
+        rank = (name == WINDOW_SPAN, not name.startswith("ht."), d)
         if best_rank is None or rank < best_rank:
             best, best_rank = name, rank
     return best
@@ -299,23 +356,40 @@ def _clip_events(events, lo, hi):
             for e in events if min(e[1] + e[2], hi) > max(e[1], lo)]
 
 
-def reduce_planes(planes: dict, chips: int = 1, scopes=SCOPES) -> dict:
-    """Device 0 inside the `pb.window` span, by name. Times in seconds."""
+def device_lines(planes, chips, rehearse=False):
+    """[(device id, {line name: events})] of the first `chips` devices. A
+    rehearsal's trace has no device plane: the XLA:CPU worker threads of the
+    host plane stand in as device 0."""
+    found = sorted((int(m.group(1)), lines) for m, lines in
+                   ((_DEVICE_PLANE.match(p), ln) for p, ln in planes.items())
+                   if m and OPS_LINE in lines)
+    if not found and rehearse:
+        cpu = [ev for lname, evs in planes.get("/host:CPU", {}).items()
+               if lname.startswith(_CPU_WORKER) for ev in evs if ev[2] > 0]
+        if cpu:
+            found = [(0, {OPS_LINE: cpu})]
+    if len(found) < (1 if rehearse else chips):
+        raise ValueError(f"the trace holds {len(found)} device planes with an "
+                         f"{OPS_LINE!r} line, the cell runs on {chips}: "
+                         f"{sorted(planes)}")
+    return found[:chips]
+
+
+def reduce_planes(planes: dict, chips: int = 1, scopes=SCOPES,
+                  rehearse: bool = False) -> dict:
+    """Inside the `pb.window` span: busy time over the chips used, and device
+    0 by name. Times in seconds. Raises where the trace has no window span,
+    too few device planes or no operation on a device inside the window."""
     spans = host_spans(planes)
-    win = [ev for ev in spans if ev[0] == tr.WINDOW_SPAN]
+    win = [ev for ev in spans if ev[0] == WINDOW_SPAN]
     if not win:
-        raise ValueError(f"the trace holds no {tr.WINDOW_SPAN!r} span")
+        raise ValueError(f"the trace holds no {WINDOW_SPAN!r} span")
     w0 = min(ev[1] for ev in win)
     w1 = max(ev[1] + ev[2] for ev in win)
-    found = sorted((int(m.group(1)), lines) for m, lines in
-                   ((tr._DEVICE_PLANE.match(p), l) for p, l in planes.items())
-                   if m and tr.OPS_LINE in lines)
-    if len(found) < chips:
-        raise ValueError(f"the trace holds {len(found)} device planes with an "
-                         f"{tr.OPS_LINE!r} line, asked for {chips}")
+    found = device_lines(planes, chips, rehearse)
     _dev, lines0 = found[0]
     names = program_names(lines0.get(MODULES_LINE, ()))
-    ops = _clip_events(lines0[tr.OPS_LINE], w0, w1)
+    ops = _clip_events(lines0[OPS_LINE], w0, w1)
     ns = 1e-9
 
     by_scope = {}
@@ -339,9 +413,9 @@ def reduce_planes(planes: dict, chips: int = 1, scopes=SCOPES) -> dict:
         row["runs"] += 1
         row["device_s"] += ev[2] * ns
 
-    busy = tr.union([(e[1], e[1] + e[2]) for e in ops])
+    busy0 = union([(e[1], e[1] + e[2]) for e in ops])
     gaps, prev = [], w0
-    for s, e in busy:
+    for s, e in busy0:
         if s > prev:
             gaps.append((prev, s))
         prev = e
@@ -352,28 +426,39 @@ def reduce_planes(planes: dict, chips: int = 1, scopes=SCOPES) -> dict:
         owner = gap_owner(g, spans)
         idle[owner] = idle.get(owner, 0.0) + (g[1] - g[0]) * ns
 
-    collectives = {}
-    for dev, lines in found[:chips]:
-        dops = _clip_events(lines[tr.OPS_LINE], w0, w1)
-        coll = [e for e in dops if tr.is_collective(e[0])]
+    busy, collectives = {}, {}
+    for dev, lines in found:
+        dops = _clip_events(lines[OPS_LINE], w0, w1)
+        busy[str(dev)] = length(union([(e[1], e[1] + e[2])
+                                       for e in dops])) * ns
+        coll = [e for e in dops if is_collective(e[0])]
         coll += [e for e in _clip_events(lines.get(ASYNC_LINE, ()), w0, w1)
-                 if tr.is_collective(e[0])]
-        c = tr.union([(e[1], e[1] + e[2]) for e in coll])
-        other = tr.union([(e[1], e[1] + e[2]) for e, _own, encloses
-                          in self_time_per_event(dops)
-                          if not encloses and not tr.is_collective(e[0])])
-        hidden = sum(tr.length(tr.clip(other, s, e)) for s, e in c)
-        collectives[str(dev)] = {"total_s": tr.length(c) * ns,
+                 if is_collective(e[0])]
+        c = union([(e[1], e[1] + e[2]) for e in coll])
+        other = union([(e[1], e[1] + e[2]) for e, _own, encloses
+                       in self_time_per_event(dops)
+                       if not encloses and not is_collective(e[0])])
+        hidden = sum(length(clip(other, s, e)) for s, e in c)
+        collectives[str(dev)] = {"total_s": length(c) * ns,
                                  "hidden_s": hidden * ns,
-                                 "exposed_s": (tr.length(c) - hidden) * ns}
+                                 "exposed_s": (length(c) - hidden) * ns}
+    if not any(busy.values()):
+        raise ValueError("no operation ran on a device inside the window")
     return {
         "window_s": (w1 - w0) * ns,
-        "device0_busy_s": tr.length(busy) * ns,
+        "busy_s": sum(busy.values()) / len(busy),
+        "busy_s_by_device": busy,
+        "device0_busy_s": length(busy0) * ns,
         "by_scope": by_scope,
         "programs": programs,
         "idle_gaps": dict(sorted(idle.items(), key=lambda kv: -kv[1])),
         "collectives": collectives,
     }
+
+
+def idle_share_percent(reduction: dict) -> float:
+    """1 - busy / window of a reduction, mean over the chips used, in %."""
+    return 100.0 * (1.0 - reduction["busy_s"] / reduction["window_s"])
 
 
 def scope_shares(reduction: dict) -> dict:
@@ -386,5 +471,19 @@ def scope_shares(reduction: dict) -> dict:
     return dict(sorted(out.items(), key=lambda kv: -kv[1]))
 
 
-def reduce_file(path: str, chips: int = 1) -> dict:
-    return reduce_planes(load_planes(path), chips)
+def scope_seconds(reduction: dict, scope: str, program: str = None,
+                  op_prefix: str = "") -> float:
+    """Device 0's self time under `scope` (every direction; one program or
+    all), optionally only of operations whose name starts with `op_prefix`."""
+    total = 0.0
+    for (prog, sc, _d), row in reduction["by_scope"].items():
+        if sc != scope or (program is not None and prog != program):
+            continue
+        total += (sum(t for n, t in row["ops"].items()
+                      if n.startswith(op_prefix))
+                  if op_prefix else row["self_s"])
+    return total
+
+
+def reduce_file(path: str, chips: int = 1, rehearse: bool = False) -> dict:
+    return reduce_planes(load_planes(path), chips, rehearse=rehearse)
