@@ -11,6 +11,14 @@ train loss, the prefill logits and the decode engine's token choices can
 all be judged against it (``chip_smoke.py``, ``tests/test_reference.py``).
 
 Dense-MLP models only (the decode grid's own restriction).
+
+:func:`pattern_logits` is the same kind of oracle for a model with a
+per-layer PATTERN (``TransformerLMConfig.pattern``: Mamba-1, window, full
+and cross differential attention, gated memory units; pre-norm LayerNorm, a
+gated SiLU MLP, no positional encoding, the head tied to the embedding): one
+full forward over a whole sequence, a ``lax.scan`` over positions for the
+state-space layers, every head pair written out. It shares nothing with
+``heat_tpu.nn.mixers`` but the parameter tree.
 """
 
 from __future__ import annotations
@@ -23,13 +31,24 @@ import jax
 import jax.numpy as jnp
 
 __all__ = ["host_params", "reference_logits", "reference_loss",
-           "greedy_gaps", "prefill_logits"]
+           "greedy_gaps", "prefill_logits", "pattern_logits"]
 
 
 def host_params(params):
     """The model's parameter tree as host float32 arrays with the
-    ``(pp, Ls, ...)`` stage axes flattened to one layer axis."""
+    ``(pp, Ls, ...)`` stage axes flattened to one layer axis (a pattern
+    model's ``segments``, stacked by repeat, come apart into ``layers``, a
+    list with one dict a layer; a tree that has ``layers`` stays)."""
     host = jax.tree.map(lambda a: np.asarray(a, np.float32), params)
+    if "layers" in host:
+        return host
+    if "segments" in host:
+        host["layers"] = [
+            {name: a[i] for name, a in place.items()}
+            for run in host.pop("segments")
+            for i in range(len(next(iter(run[0].values()))))
+            for place in run]
+        return host
     host["stages"] = {k: v.reshape((-1,) + v.shape[2:])
                       for k, v in host["stages"].items()}
     if "router" in host["stages"]:
@@ -115,7 +134,7 @@ def greedy_gaps(logits, seq, s0):
 def prefill_logits(model, params, prompt):
     """The code UNDER TEST, exposed for judging: the last-position logits
     the decode engine's prefill program computes for ``prompt`` — the same
-    ``_prompt_kv_logits`` body over the same power-of-two prompt bucket
+    ``prefill`` body over the same power-of-two prompt bucket
     (pad rows included), on the model's own grid and compute dtype. The
     engine itself keeps only the argmax of these."""
     from jax.sharding import PartitionSpec as P
@@ -123,14 +142,14 @@ def prefill_logits(model, params, prompt):
     from ..core._compat import shard_map
 
     prompt = np.asarray(prompt, np.int32)
-    padded = np.zeros(model.prompt_bucket(len(prompt)), np.int32)
+    padded = np.zeros(model.serving_bucket(len(prompt)), np.int32)
     padded[:len(prompt)] = prompt
 
     key = ("reference.prefill_logits", len(padded))
     fn = model._step_cache.get(key)  # one compile per prompt bucket
     if fn is None:
         def body(params, toks, n_valid):
-            return model._prompt_kv_logits(params, toks[None], n_valid)[2][0]
+            return model.prefill(params, toks[None], n_valid)[1][0]
 
         fn = model._step_cache[key] = jax.jit(shard_map(
             body, mesh=model.grid.mesh,
@@ -138,3 +157,122 @@ def prefill_logits(model, params, prompt):
             check_vma=False))
     return np.asarray(fn(params, jnp.asarray(padded),
                          jnp.int32(len(prompt))))
+
+
+# ---------------------------------------------------------------------- #
+# a per-layer pattern (serving only)                                     #
+# ---------------------------------------------------------------------- #
+F8, F8_MAX = jnp.float8_e4m3fn, 448.0
+
+
+def _mul(a, b, fp8):
+    """``a @ b``; ``fp8`` rounds both operands to float8 e4m3 (per-tensor
+    scale) first: the control, the nearest precision below bfloat16."""
+    if fp8:
+        def q8(x):
+            s = jnp.maximum(jnp.max(jnp.abs(x)), 1e-30) / F8_MAX
+            return (x / s).astype(F8).astype(jnp.float32) * s
+        a, b = q8(a), q8(b)
+    return a @ b
+
+
+def _ln(x, g, b, eps):
+    mu = jnp.mean(x, axis=-1, keepdims=True)
+    var = jnp.mean((x - mu) ** 2, axis=-1, keepdims=True)
+    return (x - mu) / jnp.sqrt(var + eps) * g + b
+
+
+def _silu(x):
+    return x / (1.0 + jnp.exp(-x))
+
+
+def _mamba(p, u, cfg, fp8):
+    """Mamba-1 over ``u`` (S, D); returns (out, y before the gate)."""
+    di, N, K = cfg.d_inner, cfg.d_state, cfg.d_conv
+    S = u.shape[0]
+    xz = _mul(u, p["w_in"], fp8)
+    x, z = xz[:, :di], xz[:, di:]
+    xp = jnp.concatenate([jnp.zeros((K - 1, di)), x])
+    x = _silu(sum(xp[j:j + S] * p["conv_w"][j] for j in range(K))
+              + p["conv_b"])
+    dbc = _mul(x, p["w_x"], fp8)
+    R = dbc.shape[1] - 2 * N
+    delta = jax.nn.softplus(_mul(dbc[:, :R], p["w_dt"], fp8) + p["b_dt"])
+    Bm, Cm = dbc[:, R:R + N], dbc[:, R + N:]
+    A = -jnp.exp(p["A_log"].T)                               # (d_inner, N)
+
+    def step(s, inp):
+        d_t, x_t, b_t, c_t = inp
+        s = jnp.exp(d_t[:, None] * A) * s + (d_t * x_t)[:, None] * b_t[None]
+        return s, s @ c_t + p["D_skip"] * x_t
+
+    _, y = jax.lax.scan(step, jnp.zeros((di, N)), (delta, x, Bm, Cm))
+    return _mul(y * _silu(z), p["w_out"], fp8), y
+
+
+def _diff_attention(p, q, k, v, mask, layer, cfg, fp8):
+    """Differential attention of query heads ``q`` (S, H, d) over keys and
+    values (S, Hkv, d), pair by pair; ``mask`` (S, S) True where seen."""
+    d = q.shape[-1]
+    lam_init = 0.8 - 0.6 * math.exp(-0.3 * layer)
+    lq1, lk1, lq2, lk2 = p["lam"]
+    lam = jnp.exp(lq1 @ lk1) - jnp.exp(lq2 @ lk2) + lam_init
+    bias = jnp.where(mask, 0.0, -jnp.inf)
+    pairs = []
+    for j in range(q.shape[1] // 2):
+        g = j // 2
+        vbar = jnp.concatenate([v[:, 2 * g], v[:, 2 * g + 1]], axis=-1)
+        a1 = _mul(jax.nn.softmax(_mul(q[:, 2 * j], k[:, 2 * g].T, fp8)
+                                 / math.sqrt(d) + bias, axis=-1), vbar, fp8)
+        a2 = _mul(jax.nn.softmax(_mul(q[:, 2 * j + 1], k[:, 2 * g + 1].T, fp8)
+                                 / math.sqrt(d) + bias, axis=-1), vbar, fp8)
+        o = a1 - lam * a2
+        o = o / jnp.sqrt(jnp.mean(o * o, axis=-1, keepdims=True)
+                         + cfg.norm_eps) * p["subln"]
+        pairs.append(o * (1.0 - lam_init))
+    return _mul(jnp.concatenate(pairs, axis=-1), p["wo"], fp8) + p["bo"]
+
+
+def _pattern_forward(hp, toks, cfg, fp8):
+    S = toks.shape[0]
+    H, Hkv, dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    t, s_ = jnp.arange(S)[:, None], jnp.arange(S)[None, :]
+    causal = s_ <= t
+    h = hp["embed"][toks]
+    memory = full_k = full_v = None
+    for l, (kind, p) in enumerate(zip(cfg.pattern, hp["layers"])):
+        u = _ln(h, p["ln1"], p["ln1_b"], cfg.norm_eps)
+        if kind == "mamba":
+            mixed, memory = _mamba(p, u, cfg, fp8)
+        elif kind == "gmu":
+            mixed = _mul(memory * _silu(_mul(u, p["w1"], fp8)), p["w2"], fp8)
+        elif kind == "cross":
+            q = _mul(u, p["wq"], fp8) + p["bq"]
+            mixed = _diff_attention(p, q.reshape(S, H, dh), full_k, full_v,
+                                    causal, l, cfg, fp8)
+        else:
+            qkv = (_mul(u, p["wqkv"], fp8) + p["bqkv"]).reshape(
+                S, H + 2 * Hkv, dh)
+            q, k, v = qkv[:, :H], qkv[:, H:H + Hkv], qkv[:, H + Hkv:]
+            if kind == "full":
+                full_k, full_v, mask = k, v, causal
+            else:
+                mask = causal & (s_ > t - cfg.window)
+            mixed = _diff_attention(p, q, k, v, mask, l, cfg, fp8)
+        h = h + mixed
+        gu = _mul(_ln(h, p["ln2"], p["ln2_b"], cfg.norm_eps),
+                  p["w_gate_up"], fp8)
+        F = gu.shape[1] // 2
+        h = h + _mul(_silu(gu[:, :F]) * gu[:, F:], p["w_down"], fp8)
+    return _mul(_ln(h, hp["final_ln"], hp["final_ln_b"], cfg.norm_eps),
+                hp["embed"].T, fp8)
+
+
+def pattern_logits(hp, toks, cfg, fp8=False):
+    """``(S,)`` int tokens of ONE sequence -> ``(S, vocab)`` float32 logits
+    of a pattern model; ``hp`` is :func:`host_params` of its tree.
+    ``fp8=True`` is the control: every matrix product's operands rounded to
+    float8."""
+    fn = jax.jit(lambda hp, toks: _pattern_forward(hp, toks, cfg, fp8))
+    with jax.default_matmul_precision("highest"):
+        return fn(hp, jnp.asarray(toks, jnp.int32))

@@ -35,7 +35,7 @@ from __future__ import annotations
 import math
 from dataclasses import dataclass, field
 from functools import partial
-from typing import Any, Dict, Optional
+from typing import Any, Dict, Optional, Tuple
 
 import numpy as np
 
@@ -48,11 +48,54 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..core.communication import MeshGrid
 from ..utils.profiling import scope, span
+from . import mixers
 from .attention import (_ring_body, _ulysses_core, _zigzag_core,
                         local_attention, zigzag_layout, zigzag_unlayout)
 from .parallel import pipeline_apply, switch_moe
 
-__all__ = ["TransformerLM", "TransformerLMConfig"]
+__all__ = ["TransformerLM", "TransformerLMConfig", "MIXER_KINDS",
+           "sambay_pattern"]
+
+# what a layer's token mixer may be (``TransformerLMConfig.pattern``)
+MIXER_KINDS = ("attn", "mamba", "window", "full", "cross", "gmu")
+
+
+def sambay_pattern(n_layers: int) -> Tuple[str, ...]:
+    """The decoder-hybrid-decoder's placement rule (arXiv:2507.06607, as
+    Phi-4-mini-flash's modeling file applies it) for ``n_layers`` layers, a
+    multiple of four. Self-decoder, layers 0..n/2+1: Mamba on the even
+    layers, window attention on the odd ones, and ONE full-attention layer
+    last (n/2+1), whose keys and values are the only full-length ones.
+    Cross-decoder, the rest: gated memory units (even; they read the last
+    Mamba layer's output) and cross-attention to the full layer's keys and
+    values (odd)."""
+    if n_layers < 4 or n_layers % 4:
+        raise ValueError(
+            f"the pattern needs a multiple of four layers, got {n_layers}")
+    half = n_layers // 2
+    return tuple(
+        ("mamba" if l % 2 == 0 else "window") if l <= half
+        else "full" if l == half + 1
+        else ("gmu" if l % 2 == 0 else "cross")
+        for l in range(n_layers))
+
+
+def _segments(kinds) -> Tuple[Tuple[int, int, int], ...]:
+    """``kinds`` cut into runs ``(first layer, period, repeats)``: from each
+    layer on, the period of at most four kinds that repeats over the most
+    layers; a layer that opens no repeat is a run of its own, (l, 1, 1)."""
+    out, l = [], 0
+    while l < len(kinds):
+        period, reps = 1, 1
+        for p in range(1, 5):
+            r = 1
+            while kinds[l + r * p:l + (r + 1) * p] == kinds[l:l + p]:
+                r += 1
+            if r > 1 and p * r > period * reps:
+                period, reps = p, r
+        out.append((l, period, reps))
+        l += period * reps
+    return tuple(out)
 
 
 @dataclass
@@ -74,12 +117,36 @@ class TransformerLMConfig:
     rope_theta: float = 10000.0
     remat: bool = False                 # jax.checkpoint each layer: trade
                                         # recompute FLOPs for activation HBM
+    # -- the per-layer pattern (None: every layer plain causal attention, a
+    # GELU MLP and RMSNorm, what training and ``generate`` support). A
+    # pattern is SERVED (``serve_transformer(..., decode=True)``): one mixer
+    # kind a layer out of MIXER_KINDS, pre-norm LayerNorm with bias, a gated
+    # SiLU MLP, differential attention over grouped key/value heads, no
+    # positional encoding, the head tied to the embedding.
+    pattern: Optional[Tuple[str, ...]] = None
+    n_kv_heads: Optional[int] = None    # default n_heads
+    window: int = 0                     # rows a "window" layer sees and keeps
+    d_inner: Optional[int] = None       # state-space width, default 2 d_model
+    d_state: int = 16
+    d_conv: int = 4
+    dt_rank: Optional[int] = None       # default ceil(d_model / 16)
+    norm_eps: float = 1e-5              # LayerNorm and the pairs' RMSNorm
+    param_dtype: Any = jnp.float32      # what the matrices are HELD in
 
     def __post_init__(self):
         if self.d_ff is None:
             self.d_ff = 4 * self.d_model
         if self.d_model % self.n_heads:
             raise ValueError("d_model must be divisible by n_heads")
+        if self.n_kv_heads is None:
+            self.n_kv_heads = self.n_heads
+        if self.d_inner is None:
+            self.d_inner = 2 * self.d_model
+        if self.dt_rank is None:
+            self.dt_rank = -(-self.d_model // 16)
+        if self.pattern is not None:
+            self.pattern = tuple(self.pattern)
+            self._check_pattern()
         if self.attn_schedule not in ("ring", "zigzag", "ulysses"):
             raise ValueError(
                 f"attn_schedule must be 'ring', 'zigzag' or 'ulysses', got "
@@ -91,6 +158,38 @@ class TransformerLMConfig:
     @property
     def head_dim(self) -> int:
         return self.d_model // self.n_heads
+
+    def _check_pattern(self):
+        kinds = self.pattern
+        if len(kinds) != self.n_layers:
+            raise ValueError(
+                f"pattern names {len(kinds)} layers, n_layers is "
+                f"{self.n_layers}")
+        unknown = sorted(set(kinds) - set(MIXER_KINDS[1:]))
+        if unknown:
+            raise ValueError(
+                f"pattern kinds must be of {MIXER_KINDS[1:]}, got {unknown}")
+        if self.moe_experts or self.rope:
+            raise ValueError(
+                "a pattern has a gated dense MLP and no positional "
+                "encoding: moe_experts=0, rope=False")
+        if self.n_heads % 4 or self.n_heads != 2 * self.n_kv_heads:
+            raise ValueError(
+                "differential attention pairs adjacent heads and each pair "
+                "of pairs shares two key/value heads: n_heads must be a "
+                f"multiple of 4 and twice n_kv_heads, got {self.n_heads} and "
+                f"{self.n_kv_heads}")
+        if "window" in kinds and self.window < 1:
+            raise ValueError("a 'window' layer needs window >= 1")
+        for l, kind in enumerate(kinds):
+            if kind == "cross" and "full" not in kinds[:l]:
+                raise ValueError(
+                    f"layer {l} is 'cross' with no 'full' layer before it "
+                    "whose keys and values it could read")
+            if kind == "gmu" and "mamba" not in kinds[:l]:
+                raise ValueError(
+                    f"layer {l} is 'gmu' with no 'mamba' layer before it "
+                    "whose output it could gate")
 
 
 def _rmsnorm(x, scale):
@@ -171,6 +270,18 @@ class TransformerLM:
                 f"={c.n_heads // self.tp}) divisible by sp ({self.sp})")
         self.layers_per_stage = c.n_layers // self.pp
         self.mesh_size = self.dcn * self.dp * self.pp * self.tp * self.sp
+        # one mixer kind a layer; "attn" everywhere is the dense model
+        self.kinds = c.pattern if c.pattern else ("attn",) * c.n_layers
+        # a pattern's layers by (first layer, period, repeats): its
+        # parameters are stacked by repeat and a prompt's forward scans them
+        # (the dense model's are stacked by stage: a layer a segment here)
+        self.segments = (_segments(self.kinds) if c.pattern else
+                         tuple((l, 1, 1) for l in range(c.n_layers)))
+        if c.pattern and (self.pp, self.tp, self.sp) != (1, 1, 1):
+            raise ValueError(
+                f"pattern {self._pattern_name()} is served on dp-only grids "
+                f"(pp = tp = sp = 1), got pp={self.pp} tp={self.tp} "
+                f"sp={self.sp}")
         self._step_cache: Dict = {}
 
     @property
@@ -183,8 +294,71 @@ class TransformerLM:
     # parameters                                                    #
     # ------------------------------------------------------------- #
 
+    def _pattern_name(self) -> str:
+        """The pattern, short enough for a message: runs of one kind."""
+        runs = []
+        for kind in self.kinds:
+            if runs and runs[-1][0] == kind:
+                runs[-1][1] += 1
+            else:
+                runs.append([kind, 1])
+        return "(" + ", ".join(k if n == 1 else f"{k} x{n}"
+                               for k, n in runs) + ")"
+
+    def _needs_dense(self, what: str) -> None:
+        if self.cfg.pattern:
+            raise NotImplementedError(
+                f"{what} supports the dense pattern only; the pattern "
+                f"{self._pattern_name()} is served: serve_transformer("
+                f"model, params, max_seq_len, decode=True)")
+
+    def pattern_param_shapes(self) -> Dict[str, Any]:
+        """The parameter tree of a PATTERN model as ``ShapeDtypeStruct``s:
+        ``embed`` (the head is tied to it), the final LayerNorm, and
+        ``segments``: for each run ``(first, period, repeats)`` of
+        ``self.segments`` a list of ``period`` dicts, each holding what its
+        layer's mixer kind has with a leading axis of ``repeats`` (entry i
+        of dict j is layer ``first + i * period + j``;
+        :meth:`layer_params` picks one, :meth:`stack_layers` builds them).
+        Matrices and their biases in ``param_dtype``; norm scales, the
+        state-space layer's ``A_log``, ``D_skip``, ``b_dt`` and the
+        attention layers' lambda vectors and pair-norm scale in float32."""
+        c = self.cfg
+        D, F, H, Hkv, d = (c.d_model, c.d_ff, c.n_heads, c.n_kv_heads,
+                           c.head_dim)
+        di, N, K, R = c.d_inner, c.d_state, c.d_conv, c.dt_rank
+        w, f = jnp.dtype(c.param_dtype), jnp.dtype(jnp.float32)
+        common = {"ln1": ((D,), f), "ln1_b": ((D,), f), "ln2": ((D,), f),
+                  "ln2_b": ((D,), f), "w_gate_up": ((D, 2 * F), w),
+                  "w_down": ((F, D), w)}
+        attn = {"wo": ((H * d, D), w), "bo": ((D,), w), "lam": ((4, d), f),
+                "subln": ((2 * d,), f)}
+        own = {
+            "mamba": {"w_in": ((D, 2 * di), w), "conv_w": ((K, di), w),
+                      "conv_b": ((di,), w), "w_x": ((di, R + 2 * N), w),
+                      "w_dt": ((R, di), w), "b_dt": ((di,), f),
+                      "A_log": ((N, di), f), "D_skip": ((di,), f),
+                      "w_out": ((di, D), w)},
+            "gmu": {"w1": ((D, di), w), "w2": ((di, D), w)},
+            "cross": dict(attn, wq=((D, H * d), w), bq=((H * d,), w)),
+        }
+        own["window"] = own["full"] = dict(
+            attn, wqkv=((D, (H + 2 * Hkv) * d), w),
+            bqkv=(((H + 2 * Hkv) * d,), w))
+        tree = {"embed": ((c.vocab, D), w), "final_ln": ((D,), f),
+                "final_ln_b": ((D,), f),
+                "segments": [
+                    [{n: ((reps,) + shape, dt) for n, (shape, dt) in
+                      dict(common, **own[self.kinds[first + j]]).items()}
+                     for j in range(period)]
+                    for first, period, reps in self.segments]}
+        return jax.tree.map(lambda sd: jax.ShapeDtypeStruct(*sd), tree,
+                            is_leaf=lambda sd: isinstance(sd, tuple))
+
     def param_specs(self) -> Dict[str, Any]:
         c, Ls = self.cfg, self.layers_per_stage
+        if c.pattern:       # dp-only grids: every leaf whole on every device
+            return jax.tree.map(lambda _: P(), self.pattern_param_shapes())
         stages = {
             "ln1": P("pp", None, None),
             # (pp, Ls, D, 3, H, Dh): heads sharded over tp
@@ -214,6 +388,48 @@ class TransformerLM:
             "stages": stages,
         }
 
+    def serving_params(self, params) -> Dict[str, Any]:
+        """``params`` as a decode engine HOLDS them: the matrices in the
+        configuration's ``param_dtype``, so that a model stated in bfloat16
+        takes half the memory and its step casts nothing. Leaves that are
+        in that dtype already come back as they are (the float32 default:
+        the tree itself); any other floating leaf is cast, UP as well as
+        down: a dense model handed bfloat16 parameters under the float32
+        default is held in float32."""
+        if self.cfg.pattern:
+            want = jax.tree.map(lambda sd: sd.dtype,
+                                self.pattern_param_shapes())
+        else:
+            dt = jnp.dtype(self.cfg.param_dtype)
+            want = jax.tree.map(
+                lambda a: dt if jnp.issubdtype(a.dtype, jnp.floating)
+                else a.dtype, params)
+        if all(a.dtype == d for a, d in zip(jax.tree.leaves(params),
+                                            jax.tree.leaves(want))):
+            return params
+        return jax.tree.map(lambda a, d: a.astype(d), params, want)
+
+    def stack_layers(self, layer_of) -> list:
+        """The ``segments`` of a pattern's parameter tree from
+        ``layer_of(l)``, layer ``l``'s own dict of arrays. One place of one
+        run's period at a time, so that no more than ``repeats`` layers
+        exist twice while a model that fills the chip is built."""
+        # one program a place, not one a leaf op by op
+        stack = jax.jit(lambda layers: jax.tree.map(
+            lambda *a: jnp.stack(a), *layers))
+        return [[stack([layer_of(first + i * period + j)
+                        for i in range(reps)])
+                 for j in range(period)]
+                for first, period, reps in self.segments]
+
+    def layer_params(self, params, l: int) -> Dict[str, Any]:
+        """Layer ``l``'s own parameters out of a pattern's ``segments``."""
+        for s, (first, period, reps) in enumerate(self.segments):
+            if l < first + period * reps:
+                i, j = divmod(l - first, period)
+                return jax.tree.map(lambda a: a[i], params["segments"][s][j])
+        raise IndexError(l)
+
     def shard_params(self, params) -> Dict[str, Any]:
         """Place a (host or differently-placed) parameter tree onto this
         grid's shardings — e.g. after ``load_checkpoint``, whose restored
@@ -225,8 +441,43 @@ class TransformerLM:
         # batches the transfers (one placement, not one per leaf)
         return jax.device_put(params, shardings)
 
+    def _init_pattern(self, seed: int) -> Dict[str, Any]:
+        """Matrices N(0, init_scale) rounded ONCE to ``param_dtype``; norm
+        scales 1, biases 0; Mamba's own init for what a normal draw would
+        make unstable: ``A_log`` = log(1..N) a channel, ``D_skip`` 1,
+        ``b_dt`` the inverse softplus of a log-uniform step in [1e-3, 1e-1],
+        the convolution's filter uniform within 1/sqrt(K); the lambda
+        vectors N(0, 0.1)."""
+        c = self.cfg
+        rng = np.random.default_rng(seed)
+
+        def leaf(path, sd):
+            name = path[-1].key
+            if name in ("ln1", "ln2", "final_ln", "subln", "D_skip"):
+                a = np.ones(sd.shape)
+            elif name.endswith("_b") or name in ("bo", "bq", "bqkv"):
+                a = np.zeros(sd.shape)
+            elif name == "A_log":
+                a = np.broadcast_to(
+                    np.log(np.arange(1, c.d_state + 1))[:, None], sd.shape)
+            elif name == "b_dt":
+                dt = np.exp(rng.uniform(np.log(1e-3), np.log(1e-1), sd.shape))
+                a = dt + np.log(-np.expm1(-dt))
+            elif name == "conv_w":
+                a = rng.uniform(-1, 1, sd.shape) / math.sqrt(c.d_conv)
+            elif name == "lam":
+                a = 0.1 * rng.standard_normal(sd.shape)
+            else:
+                a = c.init_scale * rng.standard_normal(sd.shape)
+            return jnp.asarray(a, jnp.float32).astype(sd.dtype)
+
+        return self.shard_params(jax.tree_util.tree_map_with_path(
+            leaf, self.pattern_param_shapes()))
+
     def init(self, seed: int = 0) -> Dict[str, Any]:
         c, Ls, pp = self.cfg, self.layers_per_stage, self.pp
+        if c.pattern:
+            return self._init_pattern(seed)
         H, Dh, D, F, V = c.n_heads, c.head_dim, c.d_model, c.d_ff, c.vocab
         rng = np.random.default_rng(seed)
         s = c.init_scale
@@ -383,6 +634,12 @@ class TransformerLM:
         """Final norm + unembed; logits upcast to f32 only after the GEMM —
         an f32 norm scale would push the largest matmul off the bf16 path."""
         c = self.cfg
+        if c.pattern:       # LayerNorm, the head tied to the embedding
+            h = mixers.layernorm(h, params["final_ln"], params["final_ln_b"],
+                                 c.norm_eps)
+            return jnp.einsum("bsd,vd->bsv", h,
+                              params["embed"].astype(c.compute_dtype),
+                              preferred_element_type=jnp.float32)
         h = _rmsnorm(h, params["final_ln"].astype(c.compute_dtype))
         return (h @ params["unembed"].astype(c.compute_dtype)).astype(jnp.float32)
 
@@ -582,6 +839,7 @@ class TransformerLM:
         collective transpose exact for pipeline/tensor parallelism)."""
         from ..core import fusion
 
+        self._needs_dense("loss_and_grad_fn")
         packed = self.packed_step_supported and fusion.step_enabled()
         # the quant codec changes the packed program's collective wire
         # format, the chunk count its leg structure and the hier config
@@ -645,6 +903,7 @@ class TransformerLM:
         (:meth:`_forward_device`), compiled once and cached; runs with
         ``check_vma=False`` (inference needs no replication-type tracking,
         and the forward then traces on every supported jax)."""
+        self._needs_dense("logits_fn")
         key = "logits"
         fn = self._step_cache.get(key)
         if fn is None:
@@ -678,6 +937,7 @@ class TransformerLM:
 
         from ..core import fusion
 
+        self._needs_dense("make_train_step")
         if self.packed_step_supported and fusion.step_enabled():
             specs = self.param_specs()
             qinfo = {}
@@ -776,6 +1036,23 @@ class TransformerLM:
             raise ValueError(f"prompt length must be >= 1, got {s0}")
         return max(cls.PROMPT_BUCKET_MIN, 1 << (s0 - 1).bit_length())
 
+    def serving_bucket(self, s0: int) -> int:
+        """The rung a served prompt of ``s0`` tokens pads onto:
+        :meth:`prompt_bucket`, and for a pattern with window layers no rung
+        under the window's. NOT for correctness: a prompt shorter than the
+        window fills its ring rows from a program of any length
+        (``mixers.ring_rows``; ``tests/test_pattern_lm.py`` runs prompts
+        unpadded under the window). It is the engine's count of programs:
+        every rung is one more prefill program of every layer to compile and
+        hold (20 s each at 2,560 wide on a v5e's compiler, whatever the
+        rung: ``PERF.md`` section 6, PR 33), and a prompt under the window
+        is the cheapest to pad. It overrides ``PROMPT_BUCKET_MIN`` upward
+        for such a model, whatever a caller set it to."""
+        rung = self.prompt_bucket(s0)
+        if "window" in self.kinds:
+            rung = max(rung, self.prompt_bucket(self.cfg.window))
+        return rung
+
     def check_decode_grid(self) -> None:
         """Decode is token-recurrent: a pipelined or sequence-sharded
         layout would idle on the single live token, and MoE routing at
@@ -785,7 +1062,9 @@ class TransformerLM:
                 "generate requires a pp=1, sp=1 grid (token-recurrent "
                 "decode); use dp x tp for inference")
         if self.cfg.moe_experts:
-            raise NotImplementedError("generate supports the dense MLP only")
+            raise NotImplementedError(
+                "decode supports a dense MLP (GELU, or gated SiLU under a "
+                "pattern), not Switch-MoE routing")
 
     @scope("attn.core")
     def _attn_from_cache(self, q, ck, cv, upto):
@@ -820,35 +1099,332 @@ class TransformerLM:
         return x, ck, cv
 
     def _prompt_kv_logits(self, params, toks, n_valid, wire=None):
-        """Padded-prompt prefill forward: ``toks`` (Bl, Sp) int32 with
-        rows >= ``n_valid`` (a traced scalar) being pad. Returns per-layer
-        K/V lists (each (Bl, Sp, Hs, Dh), post-RoPE — each row rotated by
-        its absolute position exactly as in training) and the f32 logits
-        at position ``n_valid - 1``. Causal attention never reads a later
-        column, so valid rows are exactly the unpadded forward's; padded
-        rows carry garbage the caller must keep masked (col < upto) until
-        its own decode writes overwrite them."""
+        """The dense model's padded-prompt prefill as ``generate`` and the
+        reference's ``prefill_logits`` take it: per-layer K/V lists (each
+        (Bl, Sp, Hs, Dh), post-RoPE — each row rotated by its absolute
+        position exactly as in training) and the f32 logits at position
+        ``n_valid - 1``. See :meth:`prefill`."""
+        kept, logits = self.prefill(params, toks, n_valid, wire=wire)
+        return [k["k"] for k in kept], [k["v"] for k in kept], logits
+
+    # ------------------------------------------------------------- #
+    # serving: ONE layer function over (mixer kind, cache view)     #
+    # ------------------------------------------------------------- #
+    # `prefill` and `decode_step_logits` are what the decode engine
+    # compiles. Each walks the layers once and hands every layer to the
+    # function of its kind; the dense model is the one-kind case ("attn":
+    # fused-QKV causal attention with rotary, a GELU MLP, RMSNorm). What a
+    # layer keeps between tokens is the model's to say (`cache_layout`):
+    # the engine holds that tree, donates it, and never looks inside.
+
+    def _layer_picker(self, params):
+        """``pick(l)``: layer ``l``'s parameters in the dtype it computes
+        in. Out of a pattern's stacked ``segments`` that is a static slice,
+        which the products read in place."""
+        if self.cfg.pattern:
+            return partial(self.layer_params, params)
+        return partial(self._cast_params, self._stage_params(params))
+
+    def _pre_norm(self, p, name, x):
+        return mixers.layernorm(x, p[name], p[name + "_b"], self.cfg.norm_eps)
+
+    @scope("mlp")
+    def _gated_mlp_residual(self, p, x):
+        """Pre-norm gated SiLU MLP + residual: (silu(g) * u) W_down,
+        [g, u] = LN(x) W_gate_up."""
+        F = p["w_down"].shape[0]
+        gu = mixers.mm(self._pre_norm(p, "ln2", x), p["w_gate_up"])
+        h = (jax.nn.silu(gu[..., :F].astype(jnp.float32))
+             * gu[..., F:].astype(jnp.float32)).astype(x.dtype)
+        return x + mixers.mm(h, p["w_down"])
+
+    def _diff_qkv(self, p, u, kind):
+        """Query heads (and, but for a cross layer, this layer's own key and
+        value heads) of ``u`` (B, S, D)."""
         c = self.cfg
-        dtype = c.compute_dtype
-        stage_params = self._stage_params(params)
-        Sp = toks.shape[1]
-        with scope("embed"):
-            x = params["embed"][toks].astype(dtype)
-        pos0 = jnp.arange(Sp)
-        ks, vs = [], []
-        for l in range(c.n_layers):
-            p_l = self._cast_params(stage_params, l)
+        H, Hkv = c.n_heads, c.n_kv_heads
+        with scope("attn.qkv"):
+            w, b = ("wq", "bq") if kind == "cross" else ("wqkv", "bqkv")
+            qkv = (jnp.dot(u, p[w].astype(u.dtype),
+                           preferred_element_type=jnp.float32)
+                   + p[b].astype(jnp.float32)).astype(u.dtype)
+            # the product stays a plain matrix product that reads its
+            # weights where they lie in the segment's stack: seen together
+            # with the split into heads, XLA re-lays the weights a step
+            qkv = lax.optimization_barrier(qkv)
+            qkv = qkv.reshape(*u.shape[:2], -1, c.head_dim)
+            if kind == "cross":
+                return qkv, None, None
+            return qkv[:, :, :H], qkv[:, :, H:H + Hkv], qkv[:, :, H + Hkv:]
+
+    def _diff_out(self, p, a, l, dtype):
+        """The maps' outputs ``a`` (B, S, H, 2 Dh) -> the layer's (B, S, D)."""
+        o = mixers.diff_finish(a, p["lam"], p["subln"], l, self.cfg.norm_eps,
+                               dtype)
+        with scope("attn.proj"):
+            return mixers.mm(o, p["wo"]) + p["bo"].astype(dtype)
+
+    def _prompt_layer(self, kind, l, p_l, x, pos0, n_valid, carry, wire):
+        """Layer ``l`` (of ``kind``; the index may be traced: a scanned
+        segment's) over a padded prompt ``x`` (Bl, Sp, D) whose rows
+        >= ``n_valid`` are pad. Returns (x, what the cache keeps of it,
+        carry); ``carry`` hands later layers the last state-space output
+        (``m``) and the full layer's keys and values (``k``, ``v``)."""
+        c, dtype = self.cfg, self.cfg.compute_dtype
+        if kind == "attn":
             q, k, v = self._qkv(p_l, x, pos0)
-            ks.append(k.astype(dtype))
-            vs.append(v.astype(dtype))
+            kept = {"k": k.astype(dtype), "v": v.astype(dtype)}
             with scope("attn.core"):
                 attn = jnp.moveaxis(local_attention(
                     jnp.moveaxis(q, 2, 1), jnp.moveaxis(k, 2, 1),
                     jnp.moveaxis(v, 2, 1), causal=True), 1, 2)
             x = self._attn_residual(p_l, x, attn, wire=wire)
-            x = self._dense_mlp_residual(p_l, x, wire=wire)
+            return self._dense_mlp_residual(p_l, x, wire=wire), kept, carry
+        with scope("attn.qkv"):
+            u = self._pre_norm(p_l, "ln1", x)
+        kept = {}
+        if kind == "mamba":
+            mixed, mem, s_end, tail = mixers.mamba_prompt(
+                p_l, u, n_valid, c.d_state)
+            kept = {"s": s_end, "conv": tail}
+            carry = dict(carry, m=mem)
+        elif kind == "gmu":
+            mixed = mixers.gmu(p_l, u, carry["m"])
+        else:
+            q, k, v = self._diff_qkv(p_l, u, kind)
+            if kind == "window":
+                with scope("attn.core"), scope("attn.window"):
+                    a = mixers.window_attention_prompt(q, k, v, c.window)
+                kept = {"k": mixers.ring_rows(mixers.lanes(k), n_valid,
+                                              c.window),
+                        "v": mixers.ring_rows(mixers.lanes(v), n_valid,
+                                              c.window)}
+            else:
+                if kind == "full":
+                    kept = {"k": mixers.lanes(k), "v": mixers.lanes(v)}
+                    carry = dict(carry, k=k, v=v)
+                # causal flash attention, one plain head a (query head,
+                # value half): the kernel the dense prompt runs
+                with scope("attn.core"), scope("attn." + kind):
+                    kk, vv = mixers.diff_heads(carry["k"], carry["v"],
+                                               c.n_heads)
+                    a = jnp.moveaxis(local_attention(
+                        jnp.moveaxis(jnp.repeat(q, 2, axis=2), 2, 1),
+                        jnp.moveaxis(kk, 2, 1), jnp.moveaxis(vv, 2, 1),
+                        causal=True), 1, 2).astype(jnp.float32)
+                    a = a.reshape(a.shape[0], a.shape[1], c.n_heads, -1)
+            mixed = self._diff_out(p_l, a, l, dtype)
+        return self._gated_mlp_residual(p_l, x + mixed), kept, carry
+
+    def _prompt_segment(self, segment, stacked, x, pos0, n_valid, carry,
+                        wire):
+        """A run of ``repeats`` periods over the padded prompt as ONE scan
+        over its parameters' leading axis: the period's layers are traced
+        (and compiled) once, not once a repeat. Returns what
+        :meth:`_prompt_layer` does, ``kept`` a list in layer order."""
+        first, period, reps = segment
+        kinds = self.kinds[first:first + period]
+        c, (B, S, _) = self.cfg, x.shape
+        # a scan's carry keeps one structure: what the period's layers hand
+        # on is there from the start
+        if "mamba" in kinds:
+            carry = dict({"m": jnp.zeros((B, S, c.d_inner), jnp.float32)},
+                         **carry)
+        if "full" in kinds:
+            z = jnp.zeros((B, S, c.n_kv_heads, c.head_dim), x.dtype)
+            carry = dict({"k": z, "v": z}, **carry)
+
+        def period_of(xc, inp):
+            x, carry = xc
+            i, p_i = inp
+            kept = []
+            for j, kind in enumerate(kinds):
+                x, kept_j, carry = self._prompt_layer(
+                    kind, first + i * period + j, p_i[j], x, pos0, n_valid,
+                    carry, wire)
+                kept.append(kept_j)
+            return (x, carry), kept
+
+        (x, carry), kept = lax.scan(period_of, (x, carry),
+                                    (jnp.arange(reps), stacked))
+        return x, [jax.tree.map(lambda a: a[i], kept[j])
+                   for i in range(reps) for j in range(period)], carry
+
+    def prefill(self, params, toks, n_valid, wire=None):
+        """Padded-prompt prefill forward: ``toks`` (Bl, Sp) int32 with
+        rows >= ``n_valid`` (a traced scalar) being pad. Returns what each
+        layer's cache keeps of the prompt (a list, one dict a layer, leaves
+        (Bl, rows, ...); see :meth:`cache_layout`) and the f32 logits at
+        position ``n_valid - 1``. Causal attention never reads a later
+        column and the state-space scan stops at ``n_valid``, so valid rows
+        are exactly the unpadded forward's; padded K/V rows carry garbage
+        the caller must keep masked (col < upto) until its own decode
+        writes overwrite them."""
+        c = self.cfg
+        pick = self._layer_picker(params)
+        Sp = toks.shape[1]
+        with scope("embed"):
+            x = params["embed"][toks].astype(c.compute_dtype)
+        pos0 = jnp.arange(Sp)
+        kept, carry = [], {}
+        for s, (first, period, reps) in enumerate(self.segments):
+            if reps == 1:
+                x, kept_l, carry = self._prompt_layer(
+                    self.kinds[first], first, pick(first), x, pos0, n_valid,
+                    carry, wire)
+                kept.append(kept_l)
+            else:
+                x, kept_s, carry = self._prompt_segment(
+                    (first, period, reps), params["segments"][s], x, pos0,
+                    n_valid, carry, wire)
+                kept.extend(kept_s)
         h_last = lax.dynamic_slice_in_dim(x, n_valid - 1, 1, axis=1)
-        return ks, vs, self._head(params, h_last)[:, 0]
+        return kept, self._head(params, h_last)[:, 0]
+
+    def _step_layer(self, l, p_l, x, cache, pos, carry, wire):
+        """Layer ``l`` on a single-token batch ``x`` (Bl, 1, D), every row
+        at its own position ``pos`` (Bl,). Reads and writes only what its
+        kind keeps: the dense layer its lane of the arena; a window layer
+        ring row ``pos mod window``; the full layer lane row ``pos``; a
+        state-space layer its state and convolution tail, in place; cross
+        and gated-memory layers nothing of their own."""
+        c, kind, dtype = self.cfg, self.kinds[l], self.cfg.compute_dtype
+        if kind == "attn":
+            new_k, new_v = cache
+            with scope("cache.read"):
+                ck_l, cv_l = new_k[l], new_v[l]
+            x, ckl, cvl = self._cache_layer_step(p_l, x, ck_l, cv_l, pos,
+                                                 wire=wire)
+            with scope("cache.write"):
+                new_k = new_k.at[l].set(ckl)
+                new_v = new_v.at[l].set(cvl)
+            return x, (new_k, new_v), carry
+        with scope("attn.qkv"):
+            u = self._pre_norm(p_l, "ln1", x)
+        (per_layer,) = cache
+        mine = per_layer[l]
+        if kind == "mamba":
+            mixed, mem, s, tail = mixers.mamba_step(
+                p_l, u, mine["s"], mine["conv"], c.d_state)
+            # the donated state IS the update's output: no copy to name
+            mine = {"s": s, "conv": tail.astype(mine["conv"].dtype)}
+            carry = dict(carry, m=mem)
+        elif kind == "gmu":
+            mixed = mixers.gmu(p_l, u, carry["m"])
+        else:
+            q, k, v = self._diff_qkv(p_l, u, kind)
+            if kind == "cross":
+                ck, cv, seen = carry["k"], carry["v"], pos + 1
+            else:
+                ring = kind == "window"
+                rows = jnp.arange(x.shape[0])
+                at = pos % c.window if ring else pos
+                with scope("cache.write"), scope("ring" if ring else "lane"):
+                    mine = {"k": mine["k"].at[rows, at].set(
+                                mixers.lanes(k)[:, 0].astype(mine["k"].dtype)),
+                            "v": mine["v"].at[rows, at].set(
+                                mixers.lanes(v)[:, 0].astype(mine["v"].dtype))}
+                # read in place by the maps below: no copy, so no op of
+                # its own that a `cache.read` scope could name
+                ck, cv = mine["k"], mine["v"]
+                # a ring holds the last `window` positions in any order (no
+                # positional encoding): before it wraps, rows <= pos
+                seen = jnp.minimum(pos + 1, c.window) if ring else pos + 1
+                if kind == "full":
+                    carry = dict(carry, k=ck, v=cv)
+            with scope("attn.core"), scope("attn." + kind):
+                a = mixers.diff_attention_lanes(q, ck, cv, seen)
+            mixed = self._diff_out(p_l, a, l, dtype)
+        cache = (per_layer[:l] + [mine] + per_layer[l + 1:],)
+        return self._gated_mlp_residual(p_l, x + mixed), cache, carry
+
+    def decode_step_logits(self, params, cache, toks, pos, wire=None):
+        """One token a row: ``toks`` (Bl,) at positions ``pos`` (Bl,) against
+        ``cache`` (:meth:`cache_layout`'s tuple of trees). Returns (f32 logits
+        (Bl, vocab), the cache with this token written)."""
+        c = self.cfg
+        pick = self._layer_picker(params)
+        with scope("embed"):
+            x = params["embed"][toks].astype(c.compute_dtype)[:, None, :]
+        carry = {}
+        for l in range(c.n_layers):
+            x, cache, carry = self._step_layer(
+                l, pick(l), x, cache, pos, carry, wire)
+        return self._head(params, x)[:, 0], cache
+
+    # what the cache holds, by the name `DecodeEngine.stats()` reports it
+    # under: the dense model's uniform arena, a window layer's ring, the
+    # full layer's lane, a state-space layer's state and convolution tail
+    CACHE_KINDS = {"attn": "arena", "window": "ring", "full": "lane",
+                   "mamba": "state"}
+
+    def cache_layout(self, slots: int, s_cap: int, dp_axes="dp"):
+        """What a decode engine of ``slots`` lanes and ``s_cap`` positions
+        keeps between tokens, as the MODEL describes it: (a tuple of trees of
+        ``ShapeDtypeStruct``s, each an argument the engine's programs take
+        and donate; the matching ``PartitionSpec``s: slots over the
+        data-parallel axes, heads over tp; bytes by kind). Dense: ONE arena
+        ``(n_layers, slots, s_cap, H, Dh)`` for K and one for V. A pattern:
+        one list, a dict a layer: a window layer a ring of
+        ``window`` rows, the full layer a lane of ``s_cap`` rows (the cross
+        layers read it and keep nothing), a state-space layer its float32
+        state ``(slots, d_state, d_inner)`` and convolution tail ``(slots,
+        d_conv - 1, d_inner)``, a gated memory unit nothing."""
+        c, dtype = self.cfg, jnp.dtype(self.cfg.compute_dtype)
+        sds = jax.ShapeDtypeStruct
+        if not c.pattern:
+            arena = sds((c.n_layers, slots, s_cap, c.n_heads, c.head_dim),
+                        dtype)
+            spec = P(None, dp_axes, None, "tp", None)
+            shapes, specs = (arena, arena), (spec, spec)
+        else:
+            def kv(n_rows):     # a position a row: mixers.lanes
+                return {n: sds((slots, n_rows, c.n_kv_heads * c.head_dim),
+                               dtype) for n in ("k", "v")}
+
+            per_kind = {
+                "window": kv(c.window), "full": kv(s_cap),
+                "mamba": {"s": sds((slots, c.d_state, c.d_inner),
+                                   jnp.float32),
+                          "conv": sds((slots, c.d_conv - 1, c.d_inner),
+                                      dtype)}}
+            shapes = ([per_kind.get(kind, {}) for kind in self.kinds],)
+            specs = jax.tree.map(lambda _: P(dp_axes), shapes)
+        nbytes = {}
+        for kind, tree in (zip(self.kinds, shapes[0]) if c.pattern
+                           else [("attn", shapes)]):
+            for leaf in jax.tree.leaves(tree):
+                name = self.CACHE_KINDS[kind]
+                nbytes[name] = nbytes.get(name, 0) + math.prod(
+                    leaf.shape) * leaf.dtype.itemsize
+        return shapes, specs, nbytes
+
+    @scope("cache.write")
+    def cache_store(self, cache, kept, slot, ok):
+        """Write one prompt's ``kept`` (:meth:`prefill`'s, batch of 1) into
+        lane ``slot`` of this device's ``cache``; ``ok`` false (the slot
+        lives on another dp shard) writes the lane's OWN current rows back:
+        the select is block-sized, never a full-cache copy. A state-space
+        layer's state and tail are written WHOLE: whatever the lane's last
+        tenant left there is gone."""
+        def put(buf, new, idx):
+            new = new[(None,) * (buf.ndim - new.ndim)].astype(buf.dtype)
+            cur = lax.dynamic_slice(buf, idx, new.shape)
+            return lax.dynamic_update_slice(
+                buf, jnp.where(ok, new, cur), idx)
+
+        zero = jnp.int32(0)
+        if not self.cfg.pattern:
+            ck, cv = cache
+            for l, kept_l in enumerate(kept):
+                idx = (jnp.int32(l), slot, zero, zero, zero)
+                ck = put(ck, kept_l["k"], idx)
+                cv = put(cv, kept_l["v"], idx)
+            return ck, cv
+        return ([{name: put(buf, kept_l[name],
+                            (slot,) + (zero,) * (buf.ndim - 1))
+                  for name, buf in mine.items()}
+                 for mine, kept_l in zip(cache[0], kept)],)
 
     def generate(self, params, prompts, max_new_tokens: int,
                  temperature: float = 0.0, seed: int = 0):
@@ -875,6 +1451,7 @@ class TransformerLM:
         absolute position exactly as in the training forward.
         """
         c = self.cfg
+        self._needs_dense("generate")
         self.check_decode_grid()
         prompts = jnp.asarray(prompts, jnp.int32)
         B, S0 = prompts.shape

@@ -1,4 +1,4 @@
-"""Continuous-batching decode engine: slot-based device-resident KV cache
+"""Continuous-batching decode engine: slot-based device-resident cache
 with in-flight request join/leave (ROADMAP item 2(d) — the LLM-serving
 traffic shape).
 
@@ -8,15 +8,26 @@ whole batch to drain (the convoy effect). The :class:`DecodeEngine`
 replaces that for serving traffic with SLOTS independent lanes over a
 persistent, device-resident KV cache:
 
-* **State.** ``(n_layers, SLOTS, S_cap, H, Dh)`` K/V lanes sharded over
-  the model's dp×tp grid (slots over dp, heads over tp), plus per-slot
-  position and last-token vectors — all device-resident for the engine's
-  lifetime. ``S_cap`` is a rung of the power-of-two sequence ladder
-  (``TransformerLM.prompt_bucket``), and every prompt pads onto the same
-  ladder, so the compiled-program set is finite by construction.
+* **State.** What each layer keeps between tokens is the MODEL's to say
+  (``TransformerLM.cache_layout``); the engine holds that tree, hands it to
+  its two programs, donates it, and never looks inside. The dense model: a
+  uniform ``(n_layers, SLOTS, S_cap, H, Dh)`` K and V arena (slots over dp,
+  heads over tp). A per-layer pattern: a ring of ``window`` rows for a
+  window-attention layer, ONE lane of ``S_cap`` rows for the full-attention
+  layer (the cross layers read it), a float32 recurrent state and a
+  convolution tail for a state-space layer, nothing for a gated memory
+  unit. Plus per-slot position and last-token vectors — all device-resident
+  for the engine's lifetime. ``S_cap`` is a rung of the power-of-two
+  sequence ladder (``TransformerLM.prompt_bucket``), and every prompt pads
+  onto the same ladder, so the compiled-program set is finite by
+  construction. A granted slot's recurrent state is RESET by its prefill:
+  the prompt's scan starts from zero and overwrites the state and the tail
+  whole (a freed slot's state is live garbage: it is not masked by a
+  position the way stale K/V rows are).
 * **Exactly TWO executables per (bucket, codec) signature.** A bucketed
-  PREFILL program (runs the padded prompt forward, writes its K/V into a
-  free slot, samples the first token) and ONE donated-carry DECODE-STEP
+  PREFILL program (runs the padded prompt forward, writes what the cache
+  keeps of it into a free slot, samples the first token) and ONE
+  donated-carry DECODE-STEP
   program (cache, positions, live-mask, tokens in; cache donated back)
   dispatched repeatedly. Steady-state decoding compiles nothing, and the
   only per-step device→host transfer is the sampled-token vector
@@ -37,8 +48,8 @@ persistent, device-resident KV cache:
   priority), tenant ``slo_ms`` is the default deadline, and per-tenant
   admitted/completed/shed counters fold into ``runtime_stats()``.
 * **Fault containment.** A failed decode-step dispatch degrades that
-  step to the eager per-slot path (plain global-array jnp ops, one slot
-  at a time) with every future intact — ``serve.decode_fallbacks`` ticks
+  step to the eager path (plain global-array jnp ops, no compiled step
+  executable) with every future intact — ``serve.decode_fallbacks`` ticks
   and the chaos matrix pins fault-free-equal tokens
   (``serve.decode.step`` in ``doc/robustness.md``).
 
@@ -144,10 +155,13 @@ class DecodeEngine:
     Parameters
     ----------
     model : TransformerLM
-        A pp=1, sp=1 dense-MLP model (``check_decode_grid``) — any dp×tp
-        grid, optionally with the leading dcn tier axis.
+        A pp=1, sp=1 model without Switch-MoE (``check_decode_grid``): the
+        dense model on any dp×tp grid, optionally with the leading dcn tier
+        axis, or a per-layer pattern of state-space, window, full, cross
+        and gated-memory mixers on a dp-only grid.
     params : pytree
-        The model's sharded parameters (``model.init`` / ``shard_params``).
+        The model's sharded parameters (``model.init`` / ``shard_params``);
+        held in the configuration's ``param_dtype``.
     config : DecodeConfig, optional
     program_cache : ProgramCache, optional
         Counters aggregate under ``serve.program_*`` like every serving
@@ -162,7 +176,7 @@ class DecodeEngine:
                  program_cache: Optional[ProgramCache] = None):
         model.check_decode_grid()
         self.model = model
-        self.params = params
+        self.params = model.serving_params(params)
         self.config = config if config is not None else DecodeConfig()
         self.name = name
         self.program_cache = (program_cache if program_cache is not None
@@ -178,17 +192,18 @@ class DecodeEngine:
         if c.vocab < 2:
             raise ValueError("decode needs vocab >= 2")
         self._dp_axes = (("dcn", "dp") if model._has_dcn else "dp")
-        mesh = model.grid.mesh
-        self._cache_spec = P(None, self._dp_axes, None, "tp", None)
         self._vec_spec = P(self._dp_axes)
-        cache_sh = NamedSharding(mesh, self._cache_spec)
-        vec_sh = NamedSharding(mesh, self._vec_spec)
-        Hs = c.n_heads  # global head axis; tp shards it via the sharding
-        shape = (c.n_layers, self.slots, self.S_cap, Hs, c.head_dim)
-        self._ck = jax.device_put(jnp.zeros(shape, c.compute_dtype), cache_sh)
-        self._cv = jax.device_put(jnp.zeros(shape, c.compute_dtype), cache_sh)
-        self._pos = jax.device_put(jnp.zeros(self.slots, jnp.int32), vec_sh)
-        self._toks = jax.device_put(jnp.zeros(self.slots, jnp.int32), vec_sh)
+        # what matters is the one-device mesh; the dense model is kept on
+        # `shard_map` there only because ISSUE 33 leaves its programs as
+        # they are (its bodies name no mesh axis either once tp = 1).
+        # PERF.md section 7.12f: the next `perf_opt` drops `bool(c.pattern)`
+        # and the dense decode cell measures what the boundary's copies cost
+        self._one_device = model.mesh_size == 1 and bool(c.pattern)
+        # the cache is the model's: a tuple of trees, each an argument of
+        # the two programs (dense: the K arena and the V arena)
+        self._cache_shapes, self._cache_specs, self._cache_bytes = \
+            model.cache_layout(self.slots, self.S_cap, self._dp_axes)
+        self._fresh_lanes()
         self._base_key = jax.random.key(self.config.seed)
         # host mirrors: which request owns each slot (None = free) and the
         # live mask uploaded to the step program every dispatch
@@ -209,6 +224,8 @@ class DecodeEngine:
         self._prefill_seq = 0
         # per-engine figures (process-wide serve.decode_* counters mirror)
         self._prefills = 0
+        self._prefill_tokens = 0
+        self._state_resets = 0
         self._steps = 0
         self._tokens_out = 0
         self._fallbacks = 0
@@ -238,13 +255,16 @@ class DecodeEngine:
         max_new = int(max_new_tokens)
         if max_new < 1:
             raise ValueError(f"max_new_tokens must be >= 1, got {max_new}")
-        need = self.model.prompt_bucket(prompt.size) + max_new
+        need = self.model.serving_bucket(prompt.size) + max_new
         if need > self.S_cap:
+            # rings and recurrent states never fill up: what runs out of
+            # room is the kind that keeps a row a position
+            kind = "lane" if "lane" in self._cache_bytes else "arena"
             raise ValueError(
                 f"request needs {need} cache rows (prompt bucket "
-                f"{self.model.prompt_bucket(prompt.size)} + {max_new} new) "
-                f"but the engine's sequence bucket is {self.S_cap}; raise "
-                f"DecodeConfig.max_seq_len")
+                f"{self.model.serving_bucket(prompt.size)} + {max_new} new) "
+                f"but a slot's {kind} (the engine's sequence bucket) holds "
+                f"{self.S_cap}; raise DecodeConfig.max_seq_len")
         adm = self._admission
         if adm is not None:
             tname = adm.resolve(tenant)
@@ -397,6 +417,11 @@ class DecodeEngine:
                 pass  # the worker's final step resolved it first
         if threading.current_thread() is not self._worker:
             self._worker.join(timeout)
+            if not self._worker.is_alive():
+                # the programs' closures hold the engine, so nothing frees
+                # its gigabytes until the cycle collector runs: let go here
+                self.params = self._cache = None
+                self._pos = self._toks = self._live_dev = None
 
     @property
     def closed(self) -> bool:
@@ -420,6 +445,9 @@ class DecodeEngine:
             "seq_bucket": self.S_cap,
             "occupancy": (sum(occ) / len(occ)) if occ else 0.0,
             "prefills": self._prefills,
+            "prefill_tokens": self._prefill_tokens,
+            "state_resets": self._state_resets,
+            "cache_bytes": dict(self._cache_bytes),
             "decode_steps": self._steps,
             "tokens_out": self._tokens_out,
             "decode_fallbacks": self._fallbacks,
@@ -448,7 +476,7 @@ class DecodeEngine:
             prompt_lens = rungs
         seen = set()
         for s0 in prompt_lens:
-            sp = self.model.prompt_bucket(int(s0))
+            sp = self.model.serving_bucket(int(s0))
             if sp in seen or sp >= self.S_cap:
                 continue
             seen.add(sp)
@@ -469,41 +497,67 @@ class DecodeEngine:
 
         return (fusion.quant_key(), fusion.chunk_key(), fusion.hier_key())
 
+    def _program(self, body, in_specs, out_specs, donate):
+        """``body`` compiled over the model's mesh. A pattern model on ONE
+        device is a plain ``jit``: a ``shard_map`` of one shard computes the
+        same and copies every donated lane at its boundary, which is what
+        the cache per kind is there to avoid."""
+        if self._one_device:
+            return jax.jit(body, donate_argnums=donate)
+        return jax.jit(shard_map(
+            body, mesh=self.model.grid.mesh, in_specs=in_specs,
+            out_specs=out_specs, check_vma=False), donate_argnums=donate)
+
     def _dp_index(self):
         m = self.model
+        if self._one_device:
+            return jnp.int32(0)
         idx = lax.axis_index("dp")
         if m._has_dcn:
             idx = lax.axis_index("dcn") * m.dp + idx
         return idx
 
+    # ------------------------------------------------------------------ #
+    # the cache: the model's tuple of trees                              #
+    # ------------------------------------------------------------------ #
+    def _fresh_lanes(self) -> None:
+        """Zeroed device state: the model's cache, positions, last tokens."""
+        mesh = self.model.grid.mesh
+        shardings = jax.tree.map(lambda s: NamedSharding(mesh, s),
+                                 self._cache_specs,
+                                 is_leaf=lambda s: isinstance(s, P))
+        self._cache = jax.tree.map(
+            lambda sd, sh: jax.device_put(jnp.zeros(sd.shape, sd.dtype), sh),
+            self._cache_shapes, shardings)
+        vec_sh = NamedSharding(mesh, self._vec_spec)
+        self._pos = jax.device_put(jnp.zeros(self.slots, jnp.int32), vec_sh)
+        self._toks = jax.device_put(jnp.zeros(self.slots, jnp.int32), vec_sh)
+
+    @property
+    def _ck(self):
+        """The dense model's K arena (its cache is the pair K, V)."""
+        return self._cache[0]
+
+    @property
+    def _cv(self):
+        return self._cache[1]
+
     def _step_prog(self):
-        """THE decode-step executable: (params, ck, cv, pos, live, toks,
-        key) -> (ck, cv, pos', toks'), carries donated. One per
-        (S_cap, slots, temperature, codec-keys) signature."""
+        """THE decode-step executable: (params, *cache, pos, live, toks,
+        key) -> (*cache, pos', toks'), carries donated (dense: cache = ck,
+        cv). One per (S_cap, slots, temperature, codec-keys) signature."""
         wire = self._wire()
         temp = float(self.config.temperature)
         key = ("decode_step", self.S_cap, self.slots, temp) + wire
 
         def build():
-            m, c = self.model, self.model.cfg
+            m = self.model
 
-            def decode_step(params, ck, cv, pos, live, toks, skey):
+            def decode_step(params, *rest):
+                *cache, pos, live, toks, skey = rest
                 Bl = toks.shape[0]
-                dtype = c.compute_dtype
-                stage_params = m._stage_params(params)
-                with scope("embed"):
-                    x = params["embed"][toks].astype(dtype)[:, None, :]
-                new_k, new_v = ck, cv
-                for l in range(c.n_layers):
-                    p_l = m._cast_params(stage_params, l)
-                    with scope("cache.read"):
-                        ck_l, cv_l = new_k[l], new_v[l]
-                    x, ckl, cvl = m._cache_layer_step(
-                        p_l, x, ck_l, cv_l, pos, wire=wire)
-                    with scope("cache.write"):
-                        new_k = new_k.at[l].set(ckl)
-                        new_v = new_v.at[l].set(cvl)
-                logits = m._head(params, x)[:, 0]
+                logits, cache = m.decode_step_logits(
+                    params, tuple(cache), toks, pos, wire=wire)
                 with scope("sample"):
                     if temp == 0.0:
                         nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
@@ -518,22 +572,21 @@ class DecodeEngine:
                 # on the same already-masked row every step)
                 toks2 = jnp.where(live, nxt, toks)
                 pos2 = pos + live.astype(jnp.int32)
-                return new_k, new_v, pos2, toks2
+                return (*cache, pos2, toks2)
 
-            cs, vs = self._cache_spec, self._vec_spec
-            sm = shard_map(
-                decode_step, mesh=self.model.grid.mesh,
-                in_specs=(self.model.param_specs(), cs, cs, vs, vs, vs,
-                          P()),
-                out_specs=(cs, cs, vs, vs), check_vma=False)
-            return jax.jit(sm, donate_argnums=(1, 2, 3, 5))
+            cs, vs = self._cache_specs, self._vec_spec
+            n = len(cs)
+            return self._program(
+                decode_step,
+                (self.model.param_specs(), *cs, vs, vs, vs, P()),
+                (*cs, vs, vs), (*range(1, n + 2), n + 3))
 
         return self.program_cache.get_custom(key, build)
 
     def _prefill_prog(self, Sp: int):
         """The bucketed prefill executable for prompt bucket ``Sp``:
-        (params, ck, cv, pos, toks, prompt, n_valid, slot, key) ->
-        (ck, cv, pos', toks', first_token); carries donated.
+        (params, *cache, pos, toks, prompt, n_valid, slot, key) ->
+        (*cache, pos', toks', first_token); carries donated.
 
         The prompt rides replicated (every dp shard runs the forward,
         only the owning shard keeps the K/V write) and joins dispatch
@@ -541,7 +594,12 @@ class DecodeEngine:
         serialized dispatches on a k-request join. Acceptable while
         prefill is a small fraction of decode wall (the benched shape);
         the batched form (one prompt row per dp shard, one dispatch per
-        wave of grants) is the known follow-up when prefill-bound."""
+        wave of grants) is the known follow-up when prefill-bound.
+
+        The slot's lanes are written WHOLE from a forward that starts
+        from nothing (a state-space scan from the zero state, stopped at
+        ``n_valid``): that is the reset a granted slot's recurrent state
+        needs."""
         wire = self._wire()
         temp = float(self.config.temperature)
         key = ("decode_prefill", Sp, self.S_cap, self.slots, temp) + wire
@@ -549,9 +607,9 @@ class DecodeEngine:
         def build():
             m = self.model
 
-            def decode_prefill(params, ck, cv, pos, toks, prompt, n_valid,
-                               slot, skey):
-                ks, vs, logits = m._prompt_kv_logits(
+            def decode_prefill(params, *rest):
+                *cache, pos, toks, prompt, n_valid, slot, skey = rest
+                kept, logits = m.prefill(
                     params, prompt[None], n_valid, wire=wire)
                 with scope("sample"):
                     if temp == 0.0:
@@ -560,7 +618,7 @@ class DecodeEngine:
                         first = jax.random.categorical(
                             jax.random.fold_in(skey, slot),
                             logits[0] / temp).astype(jnp.int32)
-                ls = ck.shape[1]  # local slots on this dp shard
+                ls = pos.shape[0]  # local slots on this dp shard
                 local = slot - self._dp_index() * ls
                 ok = (local >= 0) & (local < ls)
                 lc = jnp.clip(local, 0, ls - 1)
@@ -568,33 +626,17 @@ class DecodeEngine:
                 # back (a no-op): the select is block-sized, never a
                 # full-cache copy — prefill cost stays O(prompt), not
                 # O(cache)
-                for l in range(m.cfg.n_layers):
-                    idx = (jnp.int32(l), lc, jnp.int32(0), jnp.int32(0),
-                           jnp.int32(0))
-                    for buf_i, new in ((0, ks[l]), (1, vs[l])):
-                        buf = (ck, cv)[buf_i]
-                        with scope("cache.write"):
-                            cur = lax.dynamic_slice(
-                                buf, idx, (1, 1) + new.shape[1:])
-                            upd = jnp.where(
-                                ok, new[None].astype(buf.dtype), cur)
-                            buf = lax.dynamic_update_slice(buf, upd, idx)
-                        if buf_i == 0:
-                            ck = buf
-                        else:
-                            cv = buf
+                cache = m.cache_store(tuple(cache), kept, lc, ok)
                 hit = ok & (jnp.arange(ls) == lc)
                 pos = jnp.where(hit, n_valid, pos)
                 toks = jnp.where(hit, first, toks)
-                return ck, cv, pos, toks, first
+                return (*cache, pos, toks, first)
 
-            cs, vs = self._cache_spec, self._vec_spec
-            sm = shard_map(
-                decode_prefill, mesh=self.model.grid.mesh,
-                in_specs=(self.model.param_specs(), cs, cs, vs, vs, P(),
-                          P(), P(), P()),
-                out_specs=(cs, cs, vs, vs, P()), check_vma=False)
-            return jax.jit(sm, donate_argnums=(1, 2, 3, 4))
+            cs, vs = self._cache_specs, self._vec_spec
+            return self._program(
+                decode_prefill,
+                (self.model.param_specs(), *cs, vs, vs, P(), P(), P(), P()),
+                (*cs, vs, vs, P()), tuple(range(1, len(cs) + 3)))
 
         return self.program_cache.get_custom(key, build)
 
@@ -696,19 +738,22 @@ class DecodeEngine:
 
         m = self.model
         S0 = int(prompt.size)
-        Sp = m.prompt_bucket(S0)
+        Sp = m.serving_bucket(S0)
         prog = self._prefill_prog(Sp)
         padded = np.zeros(Sp, np.int32)
         padded[:S0] = prompt
         self._prefill_seq += 1
         with span("decode.prefill.dispatch", slot=slot, bucket=Sp):
-            out = prog(self.params, self._ck, self._cv, self._pos,
+            out = prog(self.params, *self._cache, self._pos,
                        self._toks, jnp.asarray(padded), jnp.int32(S0),
                        jnp.int32(slot),
                        self._next_key(2 * self._prefill_seq + 1))
-        self._ck, self._cv, self._pos, self._toks, first = out
+        *cache, self._pos, self._toks, first = out
+        self._cache = tuple(cache)
         if record:
             self._prefills += 1
+            self._prefill_tokens += S0
+            self._state_resets += "state" in self._cache_bytes
             _pm.inc("serve.decode_prefills")
         return int(self._fetch(first))
 
@@ -758,7 +803,7 @@ class DecodeEngine:
         try:
             _faults.check("serve.decode.step")
             with span("decode.step.dispatch"):
-                out = prog(self.params, self._ck, self._cv, self._pos,
+                out = prog(self.params, *self._cache, self._pos,
                            self._live_dev, self._toks, skey)
         except Exception:
             if self._donated_gone():
@@ -768,7 +813,8 @@ class DecodeEngine:
             _pm.inc("serve.decode_fallbacks")
             self._fallbacks += 1
             out = self._step_eager(live, skey)
-        self._ck, self._cv, self._pos, toks2 = out
+        *cache, self._pos, toks2 = out
+        self._cache = tuple(cache)
         self._toks = toks2
         if record:
             self._steps += 1
@@ -813,7 +859,7 @@ class DecodeEngine:
 
     def _donated_gone(self) -> bool:
         try:
-            return bool(self._ck.is_deleted())
+            return bool(jax.tree.leaves(self._cache)[0].is_deleted())
         except Exception:
             return False
 
@@ -821,18 +867,7 @@ class DecodeEngine:
         """Backstop recovery: fail every in-flight future typed, free all
         slots, rebuild fresh device lanes (the donated ones may be
         invalid)."""
-        c = self.model.cfg
-        mesh = self.model.grid.mesh
-        cache_sh = NamedSharding(mesh, self._cache_spec)
-        vec_sh = NamedSharding(mesh, self._vec_spec)
-        shape = (c.n_layers, self.slots, self.S_cap, c.n_heads, c.head_dim)
-        self._ck = jax.device_put(jnp.zeros(shape, c.compute_dtype),
-                                  cache_sh)
-        self._cv = jax.device_put(jnp.zeros(shape, c.compute_dtype),
-                                  cache_sh)
-        self._pos = jax.device_put(jnp.zeros(self.slots, jnp.int32), vec_sh)
-        self._toks = jax.device_put(jnp.zeros(self.slots, jnp.int32),
-                                    vec_sh)
+        self._fresh_lanes()
         failed = []
         with self._cv_lock:
             for s, req in enumerate(self._slot_req):
@@ -857,10 +892,14 @@ class DecodeEngine:
         GSPMD per-op dispatch) but it keeps every future intact when the
         step dispatch fails; values match the compiled step (same masked
         attention over the same cache rows). Host-known per-slot
-        positions/tokens drive it, so shapes stay static."""
+        positions/tokens drive it, so shapes stay static. A pattern
+        model runs the step's own body op by op instead, every slot at
+        once (:meth:`_step_eager_pattern`)."""
         from ..nn.transformer import _rmsnorm, rope_apply
 
         m, c = self.model, self.model.cfg
+        if c.pattern:
+            return self._step_eager_pattern(live, skey)
         params = self.params
         dtype = c.compute_dtype
         stage_params = m._stage_params(params)
@@ -902,3 +941,29 @@ class DecodeEngine:
             new_toks,
             NamedSharding(self.model.grid.mesh, self._vec_spec))
         return ck, cv, pos2, toks2
+
+    def _step_eager_pattern(self, live: np.ndarray, skey):
+        """The degraded step of a pattern model: the compiled step's body
+        (``decode_step_logits``) as plain global-array ops, all slots
+        together; dead slots keep their token and position."""
+        m = self.model
+        mesh = m.grid.mesh
+        logits, cache = m.decode_step_logits(
+            self.params, self._cache, self._toks, self._pos,
+            wire=self._wire())
+        temp = float(self.config.temperature)
+        if temp == 0.0:
+            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        else:
+            keys = jax.vmap(lambda i: jax.random.fold_in(skey, i))(
+                jnp.arange(self.slots))
+            nxt = jax.vmap(lambda k, lg: jax.random.categorical(
+                k, lg / temp))(keys, logits).astype(jnp.int32)
+        live_d = jnp.asarray(live)
+        vec_sh = NamedSharding(mesh, self._vec_spec)
+        toks2 = jax.device_put(jnp.where(live_d, nxt, self._toks), vec_sh)
+        pos2 = jax.device_put(self._pos + live_d.astype(jnp.int32), vec_sh)
+        cache = jax.tree.map(
+            lambda a, spec: jax.device_put(a, NamedSharding(mesh, spec)),
+            cache, self._cache_specs)
+        return (*cache, pos2, toks2)

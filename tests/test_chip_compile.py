@@ -151,3 +151,97 @@ def test_flash_inside_check_vma_shard_map_over_four_chips(on_chip, topo):
                    out_specs=(P(), (P("dp"),) * 3), check_vma=True)
     text = _compiled_text(fn, *_qkv(sh))
     assert "all-reduce" in text
+
+
+def _copy_sizes(compiled):
+    import re
+
+    return [int(np.prod([int(d) for d in dims.split(",")]))
+            for dims in re.findall(r"= \w+\[([\d,]+)\][^ ]* copy\(",
+                                   compiled.as_text())]
+
+
+def _described_pattern_engine(topo):
+    """A never-started decode engine of the served pattern at
+    Phi-4-mini-flash's widths (32 layers, 64 slots of 4,096 positions,
+    bfloat16) on one described v5e, and its programs' arguments as
+    ``ShapeDtypeStruct``s: (engine, params, cache, slot vector, live mask,
+    ``on``)."""
+    import heat_tpu as ht
+    from heat_tpu.nn.transformer import (TransformerLM, TransformerLMConfig,
+                                         sambay_pattern)
+    from heat_tpu.serve.decode import DecodeConfig, DecodeEngine
+    from heat_tpu.serve.program_cache import ProgramCache
+
+    grid = ht.MeshGrid((1, 1, 1, 1), ("dp", "pp", "tp", "sp"),
+                       devices=topo.devices[:1])
+    model = TransformerLM(grid, TransformerLMConfig(
+        vocab=200064, d_model=2560, n_heads=40, n_kv_heads=20, n_layers=32,
+        d_ff=10240, rope=False, pattern=sambay_pattern(32), window=512,
+        d_inner=5120, d_state=16, d_conv=4, dt_rank=160,
+        compute_dtype=jnp.bfloat16, param_dtype=jnp.bfloat16))
+    slots, s_cap = 64, 4096
+    # the engine's constructor places its lanes; a described device holds
+    # nothing, so the programs are built on an engine that was never started
+    eng = DecodeEngine.__new__(DecodeEngine)
+    eng.model, eng.slots, eng.S_cap = model, slots, s_cap
+    eng.config = DecodeConfig(slots=slots, max_seq_len=s_cap)
+    eng._dp_axes, eng._vec_spec, eng._one_device = "dp", P("dp"), True
+    eng._cache_shapes, eng._cache_specs, nbytes = model.cache_layout(
+        slots, s_cap, "dp")
+    eng.program_cache = ProgramCache(name="described")
+    assert sum(nbytes.values()) == 2890792960           # 2.89 GB, per kind
+    assert nbytes["lane"] == nbytes["ring"] == 2 * 64 * 4096 * 1280 * 2
+
+    def on(sd, spec=P()):
+        return jax.ShapeDtypeStruct(
+            sd.shape, sd.dtype, sharding=NamedSharding(grid.mesh, spec))
+
+    params = jax.tree.map(on, model.pattern_param_shapes())
+    cache = jax.tree.map(on, eng._cache_shapes, eng._cache_specs)
+    vec = on(jax.ShapeDtypeStruct((slots,), jnp.int32), P("dp"))
+    live = on(jax.ShapeDtypeStruct((slots,), jnp.bool_), P("dp"))
+    return eng, params, cache, vec, live, on
+
+
+def test_pattern_decode_step_at_published_widths_copies_no_lane(topo):
+    """The served pattern's step program at Phi-4-mini-flash's widths
+    compiles for one v5e, fits beside its 10.6 GB of parameters and cache,
+    and its optimised HLO holds no copy as large as a ring: a scatter on a
+    lane's minor axis, a product batched over heads, or a `shard_map`
+    boundary would each make XLA re-lay or copy the whole cache every step
+    (PERF.md section 6, PR 33). Nor one as large as the smallest weight
+    matrix: the step reads static slices of the runs' stacked parameters
+    where they lie (seen together with the split into heads, XLA re-laid
+    every QKV weight twice a step)."""
+    eng, params, cache, vec, live, _on = _described_pattern_engine(topo)
+    compiled = eng._step_prog().lower(
+        params, *cache, vec, live, vec,
+        jax.eval_shape(lambda: jax.random.key(0))).compile()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes < 10.7e9          # 7.71 GB + 2.89 GB
+    assert mem.alias_size_in_bytes > 2.89e9             # the cache, in place
+    assert mem.temp_size_in_bytes < 0.5e9
+    copies = _copy_sizes(compiled)
+    assert copies and max(copies) < min(64 * 512 * 1280, 2560 * 2560), \
+        max(copies)
+
+
+def test_pattern_prefill_at_published_widths_scans_its_runs(topo, on_chip):
+    """The 512-token prefill program at the same widths: the pattern's two
+    runs are `while` loops over the stacked parameters (the flash kernel of
+    the cross layers inside one), the cache is updated in place, and nothing
+    as large as a lane is copied: a prefill is O(prompt), not O(cache)."""
+    eng, params, cache, vec, _live, on = _described_pattern_engine(topo)
+    assert eng.model.segments == ((0, 2, 8), (16, 1, 1), (17, 1, 1),
+                                  (18, 2, 7))
+    i32 = on(jax.ShapeDtypeStruct((), jnp.int32))
+    compiled = eng._prefill_prog(512).lower(
+        params, *cache, vec, vec, on(jax.ShapeDtypeStruct((512,), jnp.int32)),
+        i32, i32, jax.eval_shape(lambda: jax.random.key(0))).compile()
+    text = compiled.as_text()
+    assert text.count(" while(") >= 2 and "tpu_custom_call" in text
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes > 2.89e9
+    assert mem.temp_size_in_bytes < 1.0e9
+    assert max(_copy_sizes(compiled)) < 64 * 4096 * 1280

@@ -273,6 +273,56 @@ def test_decode_programs_carry_their_names_and_scopes():
         assert {"attn.core", "cache.write", "sample"} <= parts
 
 
+def test_pattern_decode_programs_nest_their_new_scopes_under_known_ones():
+    """A per-layer pattern's programs keep the two program names, and every
+    new scope sits INSIDE one the trace reduction knows (`attn.qkv/ssm.in`,
+    `attn.core/ssm.scan`, `cache.write/ring`, ...), so a reader that knows
+    only the outer name still charges the time to the right layer."""
+    from heat_tpu.nn.transformer import sambay_pattern
+    from heat_tpu.serve.decode import DecodeConfig, DecodeEngine
+
+    grid = ht.MeshGrid((1, 1, 1, 1), AXES, devices=jax.devices()[:1])
+    model = TransformerLM(grid, TransformerLMConfig(
+        vocab=64, d_model=32, n_heads=4, n_kv_heads=2, n_layers=8, d_ff=64,
+        rope=False, pattern=sambay_pattern(8), window=8, d_inner=64,
+        d_state=4, dt_rank=2))
+    params = model.init(0)
+
+    def nested(lowered):
+        txt = lowered.as_text(debug_info=True)
+        return {m for m in re.findall(
+            r"((?:attn\.qkv|attn\.core|cache\.read|cache\.write)/[\w.]+)",
+            txt)}
+
+    with DecodeEngine(model, params,
+                      DecodeConfig(slots=2, max_seq_len=32)) as eng:
+        step = eng._step_prog().lower(
+            params, *eng._cache, eng._pos, jnp.zeros(2, bool), eng._toks,
+            jax.random.key(0))
+        module, parts = scopes_of(step)
+        assert module == "jit_decode_step"
+        assert {"attn.qkv", "attn.core", "attn.proj", "mlp", "head", "sample",
+                "embed", "cache.write"} <= parts
+        # rings, the lane and the states are read IN PLACE by the layer's
+        # own operations and a state is its update's (donated) output:
+        # nothing is copied, so `cache.read` names no operation
+        assert "cache.read" not in parts
+        assert {"attn.qkv/ssm.in", "attn.core/ssm.step",
+                "attn.core/attn.window", "attn.core/attn.full",
+                "attn.core/attn.cross", "attn.core/gmu", "cache.write/ring",
+                "cache.write/lane"} <= nested(step)
+        prefill = eng._prefill_prog(16).lower(
+            params, *eng._cache, eng._pos, eng._toks,
+            jnp.zeros(16, jnp.int32), jnp.int32(3), jnp.int32(0),
+            jax.random.key(0))
+        module, parts = scopes_of(prefill)
+        assert module == "jit_decode_prefill"
+        assert {"attn.qkv/ssm.in", "attn.core/ssm.scan",
+                "attn.core/attn.window", "attn.core/attn.full",
+                "attn.core/attn.cross", "attn.core/gmu"} <= nested(prefill)
+        assert {"cache.write", "sample", "head"} <= parts
+
+
 def test_lloyd_programs_carry_their_names_and_scopes():
     from heat_tpu.cluster import kmeans as km
 
