@@ -1284,25 +1284,23 @@ class TransformerLM:
     def _step_layer(self, l, p_l, x, cache, pos, carry, wire):
         """Layer ``l`` on a single-token batch ``x`` (Bl, 1, D), every row
         at its own position ``pos`` (Bl,). Reads and writes only what its
-        kind keeps: the dense layer its lane of the arena; a window layer
-        ring row ``pos mod window``; the full layer lane row ``pos``; a
+        kind keeps: the dense layer row ``pos`` of its own lanes; a window
+        layer ring row ``pos mod window``; the full layer lane row ``pos``; a
         state-space layer its state and convolution tail, in place; cross
         and gated-memory layers nothing of their own."""
         c, kind, dtype = self.cfg, self.kinds[l], self.cfg.compute_dtype
-        if kind == "attn":
-            new_k, new_v = cache
-            with scope("cache.read"):
-                ck_l, cv_l = new_k[l], new_v[l]
-            x, ckl, cvl = self._cache_layer_step(p_l, x, ck_l, cv_l, pos,
-                                                 wire=wire)
-            with scope("cache.write"):
-                new_k = new_k.at[l].set(ckl)
-                new_v = new_v.at[l].set(cvl)
-            return x, (new_k, new_v), carry
-        with scope("attn.qkv"):
-            u = self._pre_norm(p_l, "ln1", x)
         (per_layer,) = cache
         mine = per_layer[l]
+        if kind == "attn":
+            # the layer's own leaves in, the same leaves out: the row
+            # scatter inside is the only write and the attention reads the
+            # leaf where it lies (no lane is copied out of or into an arena)
+            x, ck, cv = self._cache_layer_step(p_l, x, mine["k"], mine["v"],
+                                               pos, wire=wire)
+            mine = {"k": ck, "v": cv}
+            return x, (per_layer[:l] + [mine] + per_layer[l + 1:],), carry
+        with scope("attn.qkv"):
+            u = self._pre_norm(p_l, "ln1", x)
         if kind == "mamba":
             mixed, mem, s, tail = mixers.mamba_step(
                 p_l, u, mine["s"], mine["conv"], c.d_state)
@@ -1353,8 +1351,9 @@ class TransformerLM:
         return self._head(params, x)[:, 0], cache
 
     # what the cache holds, by the name `DecodeEngine.stats()` reports it
-    # under: the dense model's uniform arena, a window layer's ring, the
-    # full layer's lane, a state-space layer's state and convolution tail
+    # under: the dense layers' lanes (together the arena), a window layer's
+    # ring, the full layer's lane, a state-space layer's state and
+    # convolution tail
     CACHE_KINDS = {"attn": "arena", "window": "ring", "full": "lane",
                    "mamba": "state"}
 
@@ -1363,37 +1362,37 @@ class TransformerLM:
         keeps between tokens, as the MODEL describes it: (a tuple of trees of
         ``ShapeDtypeStruct``s, each an argument the engine's programs take
         and donate; the matching ``PartitionSpec``s: slots over the
-        data-parallel axes, heads over tp; bytes by kind). Dense: ONE arena
-        ``(n_layers, slots, s_cap, H, Dh)`` for K and one for V. A pattern:
-        one list, a dict a layer: a window layer a ring of
-        ``window`` rows, the full layer a lane of ``s_cap`` rows (the cross
-        layers read it and keep nothing), a state-space layer its float32
-        state ``(slots, d_state, d_inner)`` and convolution tail ``(slots,
-        d_conv - 1, d_inner)``, a gated memory unit nothing."""
+        data-parallel axes, heads over tp; bytes by kind). One list, a dict
+        a layer, whatever the model: a dense layer its K and V lanes
+        ``(slots, s_cap, H, Dh)`` (together the "arena"), a window layer a
+        ring of ``window`` rows, the full layer a lane of ``s_cap`` rows (the
+        cross layers read it and keep nothing), a state-space layer its
+        float32 state ``(slots, d_state, d_inner)`` and convolution tail
+        ``(slots, d_conv - 1, d_inner)``, a gated memory unit nothing. A leaf
+        a layer is what lets a program write a row and read a lane where
+        they lie: a layer's lane inside one arena of all layers had to be
+        sliced out and written back whole, every layer of every step."""
         c, dtype = self.cfg, jnp.dtype(self.cfg.compute_dtype)
         sds = jax.ShapeDtypeStruct
-        if not c.pattern:
-            arena = sds((c.n_layers, slots, s_cap, c.n_heads, c.head_dim),
-                        dtype)
-            spec = P(None, dp_axes, None, "tp", None)
-            shapes, specs = (arena, arena), (spec, spec)
-        else:
-            def kv(n_rows):     # a position a row: mixers.lanes
-                return {n: sds((slots, n_rows, c.n_kv_heads * c.head_dim),
-                               dtype) for n in ("k", "v")}
 
-            per_kind = {
-                "window": kv(c.window), "full": kv(s_cap),
-                "mamba": {"s": sds((slots, c.d_state, c.d_inner),
-                                   jnp.float32),
-                          "conv": sds((slots, c.d_conv - 1, c.d_inner),
-                                      dtype)}}
-            shapes = ([per_kind.get(kind, {}) for kind in self.kinds],)
-            specs = jax.tree.map(lambda _: P(dp_axes), shapes)
+        def kv(n_rows):     # a position a row: mixers.lanes
+            return {n: sds((slots, n_rows, c.n_kv_heads * c.head_dim),
+                           dtype) for n in ("k", "v")}
+
+        lane = sds((slots, s_cap, c.n_heads, c.head_dim), dtype)
+        per_kind = {
+            "attn": {"k": lane, "v": lane},
+            "window": kv(c.window), "full": kv(s_cap),
+            "mamba": {"s": sds((slots, c.d_state, c.d_inner), jnp.float32),
+                      "conv": sds((slots, c.d_conv - 1, c.d_inner), dtype)}}
+        shapes = ([per_kind.get(kind, {}) for kind in self.kinds],)
+        # a dense lane's heads go over tp; a pattern runs on dp-only grids
+        spec_of = {"attn": P(dp_axes, None, "tp", None)}
+        specs = ([{n: spec_of.get(kind, P(dp_axes)) for n in tree}
+                  for kind, tree in zip(self.kinds, shapes[0])],)
         nbytes = {}
-        for kind, tree in (zip(self.kinds, shapes[0]) if c.pattern
-                           else [("attn", shapes)]):
-            for leaf in jax.tree.leaves(tree):
+        for kind, tree in zip(self.kinds, shapes[0]):
+            for leaf in tree.values():
                 name = self.CACHE_KINDS[kind]
                 nbytes[name] = nbytes.get(name, 0) + math.prod(
                     leaf.shape) * leaf.dtype.itemsize
@@ -1408,19 +1407,12 @@ class TransformerLM:
         layer's state and tail are written WHOLE: whatever the lane's last
         tenant left there is gone."""
         def put(buf, new, idx):
-            new = new[(None,) * (buf.ndim - new.ndim)].astype(buf.dtype)
+            new = new.astype(buf.dtype)
             cur = lax.dynamic_slice(buf, idx, new.shape)
             return lax.dynamic_update_slice(
                 buf, jnp.where(ok, new, cur), idx)
 
         zero = jnp.int32(0)
-        if not self.cfg.pattern:
-            ck, cv = cache
-            for l, kept_l in enumerate(kept):
-                idx = (jnp.int32(l), slot, zero, zero, zero)
-                ck = put(ck, kept_l["k"], idx)
-                cv = put(cv, kept_l["v"], idx)
-            return ck, cv
         return ([{name: put(buf, kept_l[name],
                             (slot,) + (zero,) * (buf.ndim - 1))
                   for name, buf in mine.items()}

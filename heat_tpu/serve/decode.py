@@ -10,13 +10,17 @@ persistent, device-resident KV cache:
 
 * **State.** What each layer keeps between tokens is the MODEL's to say
   (``TransformerLM.cache_layout``); the engine holds that tree, hands it to
-  its two programs, donates it, and never looks inside. The dense model: a
-  uniform ``(n_layers, SLOTS, S_cap, H, Dh)`` K and V arena (slots over dp,
-  heads over tp). A per-layer pattern: a ring of ``window`` rows for a
+  its two programs, donates it, and never looks inside. It is a leaf a
+  layer: a dense layer's K and V lanes, each ``(SLOTS, S_cap, H, Dh)``
+  (slots over dp, heads over tp; all layers' together are the "arena" of
+  ``stats()``); in a per-layer pattern a ring of ``window`` rows for a
   window-attention layer, ONE lane of ``S_cap`` rows for the full-attention
   layer (the cross layers read it), a float32 recurrent state and a
   convolution tail for a state-space layer, nothing for a gated memory
-  unit. Plus per-slot position and last-token vectors — all device-resident
+  unit. A step writes one row a slot into each leaf and reads each leaf
+  once, where it lies; a prefill writes one slot's rows: on one device no
+  lane is copied, sliced out or written back. Plus per-slot position and
+  last-token vectors — all device-resident
   for the engine's lifetime. ``S_cap`` is a rung of the power-of-two
   sequence ladder (``TransformerLM.prompt_bucket``), and every prompt pads
   onto the same ladder, so the compiled-program set is finite by
@@ -193,14 +197,10 @@ class DecodeEngine:
             raise ValueError("decode needs vocab >= 2")
         self._dp_axes = (("dcn", "dp") if model._has_dcn else "dp")
         self._vec_spec = P(self._dp_axes)
-        # what matters is the one-device mesh; the dense model is kept on
-        # `shard_map` there only because ISSUE 33 leaves its programs as
-        # they are (its bodies name no mesh axis either once tp = 1).
-        # PERF.md section 7.12f: the next `perf_opt` drops `bool(c.pattern)`
-        # and the dense decode cell measures what the boundary's copies cost
-        self._one_device = model.mesh_size == 1 and bool(c.pattern)
+        # on a mesh of one device the programs are plain `jit`s (`_program`)
+        self._one_device = model.mesh_size == 1
         # the cache is the model's: a tuple of trees, each an argument of
-        # the two programs (dense: the K arena and the V arena)
+        # the two programs
         self._cache_shapes, self._cache_specs, self._cache_bytes = \
             model.cache_layout(self.slots, self.S_cap, self._dp_axes)
         self._fresh_lanes()
@@ -498,10 +498,11 @@ class DecodeEngine:
         return (fusion.quant_key(), fusion.chunk_key(), fusion.hier_key())
 
     def _program(self, body, in_specs, out_specs, donate):
-        """``body`` compiled over the model's mesh. A pattern model on ONE
-        device is a plain ``jit``: a ``shard_map`` of one shard computes the
-        same and copies every donated lane at its boundary, which is what
-        the cache per kind is there to avoid."""
+        """``body`` compiled over the model's mesh. On ONE device that is a
+        plain ``jit`` (the bodies name no mesh axis there): a ``shard_map``
+        of one shard computes the same, and its boundary copied every
+        donated lane of a pattern's cache (PR 33). On dp x tp the bodies
+        name the mesh's axes and the ``shard_map`` stays."""
         if self._one_device:
             return jax.jit(body, donate_argnums=donate)
         return jax.jit(shard_map(
@@ -533,19 +534,10 @@ class DecodeEngine:
         self._pos = jax.device_put(jnp.zeros(self.slots, jnp.int32), vec_sh)
         self._toks = jax.device_put(jnp.zeros(self.slots, jnp.int32), vec_sh)
 
-    @property
-    def _ck(self):
-        """The dense model's K arena (its cache is the pair K, V)."""
-        return self._cache[0]
-
-    @property
-    def _cv(self):
-        return self._cache[1]
-
     def _step_prog(self):
         """THE decode-step executable: (params, *cache, pos, live, toks,
-        key) -> (*cache, pos', toks'), carries donated (dense: cache = ck,
-        cv). One per (S_cap, slots, temperature, codec-keys) signature."""
+        key) -> (*cache, pos', toks'), carries donated. One per (S_cap,
+        slots, temperature, codec-keys) signature."""
         wire = self._wire()
         temp = float(self.config.temperature)
         key = ("decode_step", self.S_cap, self.slots, temp) + wire
@@ -622,10 +614,12 @@ class DecodeEngine:
                 local = slot - self._dp_index() * ls
                 ok = (local >= 0) & (local < ls)
                 lc = jnp.clip(local, 0, ls - 1)
-                # non-owning dp shards write the slot's OWN current rows
-                # back (a no-op): the select is block-sized, never a
-                # full-cache copy — prefill cost stays O(prompt), not
-                # O(cache)
+                # one slot's `Sp` rows a layer, written where they lie (a
+                # leaf a layer: nothing else of the cache is touched, so a
+                # prefill is O(prompt), not O(cache);
+                # tests/test_chip_compile.py holds that at the decode
+                # cell's size). Non-owning dp shards write the slot's OWN
+                # current rows back (a no-op): the select is block-sized
                 cache = m.cache_store(tuple(cache), kept, lc, ok)
                 hit = ok & (jnp.arange(ls) == lc)
                 pos = jnp.where(hit, n_valid, pos)
@@ -905,7 +899,7 @@ class DecodeEngine:
         stage_params = m._stage_params(params)
         pos_h = self._fetch(self._pos)
         toks_h = self._fetch(self._toks)
-        ck, cv = self._ck, self._cv
+        lanes = list(self._cache[0])   # a layer's {"k", "v"} each
         new_toks = toks_h.copy()
         for s in np.nonzero(live)[0]:
             s = int(s)
@@ -919,10 +913,11 @@ class DecodeEngine:
                 if c.rope:
                     q = rope_apply(q, p[None], c.rope_theta)
                     k = rope_apply(k, p[None], c.rope_theta)
-                ck = ck.at[l, s, p].set(k[0, 0].astype(ck.dtype))
-                cv = cv.at[l, s, p].set(v[0, 0].astype(cv.dtype))
-                attn = m._attn_from_cache(q, ck[l, s][None], cv[l, s][None],
-                                          p + 1)
+                ck, cv = lanes[l]["k"], lanes[l]["v"]
+                ck = ck.at[s, p].set(k[0, 0].astype(ck.dtype))
+                cv = cv.at[s, p].set(v[0, 0].astype(cv.dtype))
+                lanes[l] = {"k": ck, "v": cv}
+                attn = m._attn_from_cache(q, ck[s][None], cv[s][None], p + 1)
                 x = x + jnp.einsum("bshk,hkd->bsd", attn, p_l["wproj"])
                 m_in = _rmsnorm(x, p_l["ln2"])
                 x = x + jax.nn.gelu(m_in @ p_l["w_up"]) @ p_l["w_down"]
@@ -940,7 +935,7 @@ class DecodeEngine:
         toks2 = jax.device_put(
             new_toks,
             NamedSharding(self.model.grid.mesh, self._vec_spec))
-        return ck, cv, pos2, toks2
+        return lanes, pos2, toks2
 
     def _step_eager_pattern(self, live: np.ndarray, skey):
         """The degraded step of a pattern model: the compiled step's body

@@ -161,6 +161,50 @@ def _copy_sizes(compiled):
                                    compiled.as_text())]
 
 
+def _slice_fusions(compiled):
+    """The ENTRY computation's fusions with ``slice`` in their name (XLA
+    names a fusion after what it holds: ``slice_bitcast_fusion``,
+    ``bitcast_dynamic-update-slice_fusion``, ...): (name, elements of the
+    output, elements of each operand, largest first)."""
+    import re
+
+    text = compiled.as_text()
+    entry = text[text.index("\nENTRY"):]
+    elems = {}
+    for name, dims in re.findall(r"(%[\w.\-]+) = \w+\[([\d,]*)\]", entry):
+        elems[name] = int(np.prod([int(d) for d in dims.split(",") if d]))
+    out = []
+    for name, args in re.findall(
+            r"(%[\w.\-]*slice[\w.\-]*) = \S+ fusion\(([^)]*)\)", entry):
+        ops = sorted((elems[a] for a in re.findall(r"%[\w.\-]+", args)),
+                     reverse=True)
+        out.append((name, elems[name], ops))
+    return out
+
+
+def _described_engine(model, slots, s_cap):
+    """A never-started decode engine of ``model`` on one described v5e (the
+    constructor places its lanes; a described device holds nothing) and
+    ``on``, which puts a ``ShapeDtypeStruct`` on the model's mesh."""
+    from heat_tpu.serve.decode import DecodeConfig, DecodeEngine
+    from heat_tpu.serve.program_cache import ProgramCache
+
+    eng = DecodeEngine.__new__(DecodeEngine)
+    eng.model, eng.slots, eng.S_cap = model, slots, s_cap
+    eng.config = DecodeConfig(slots=slots, max_seq_len=s_cap)
+    eng._dp_axes, eng._vec_spec = "dp", P("dp")
+    eng._one_device = model.mesh_size == 1
+    eng._cache_shapes, eng._cache_specs, eng._cache_bytes = \
+        model.cache_layout(slots, s_cap, "dp")
+    eng.program_cache = ProgramCache(name="described")
+
+    def on(sd, spec=P()):
+        return jax.ShapeDtypeStruct(
+            sd.shape, sd.dtype, sharding=NamedSharding(model.grid.mesh, spec))
+
+    return eng, on
+
+
 def _described_pattern_engine(topo):
     """A never-started decode engine of the served pattern at
     Phi-4-mini-flash's widths (32 layers, 64 slots of 4,096 positions,
@@ -170,8 +214,6 @@ def _described_pattern_engine(topo):
     import heat_tpu as ht
     from heat_tpu.nn.transformer import (TransformerLM, TransformerLMConfig,
                                          sambay_pattern)
-    from heat_tpu.serve.decode import DecodeConfig, DecodeEngine
-    from heat_tpu.serve.program_cache import ProgramCache
 
     grid = ht.MeshGrid((1, 1, 1, 1), ("dp", "pp", "tp", "sp"),
                        devices=topo.devices[:1])
@@ -181,21 +223,10 @@ def _described_pattern_engine(topo):
         d_inner=5120, d_state=16, d_conv=4, dt_rank=160,
         compute_dtype=jnp.bfloat16, param_dtype=jnp.bfloat16))
     slots, s_cap = 64, 4096
-    # the engine's constructor places its lanes; a described device holds
-    # nothing, so the programs are built on an engine that was never started
-    eng = DecodeEngine.__new__(DecodeEngine)
-    eng.model, eng.slots, eng.S_cap = model, slots, s_cap
-    eng.config = DecodeConfig(slots=slots, max_seq_len=s_cap)
-    eng._dp_axes, eng._vec_spec, eng._one_device = "dp", P("dp"), True
-    eng._cache_shapes, eng._cache_specs, nbytes = model.cache_layout(
-        slots, s_cap, "dp")
-    eng.program_cache = ProgramCache(name="described")
+    eng, on = _described_engine(model, slots, s_cap)
+    nbytes = eng._cache_bytes
     assert sum(nbytes.values()) == 2890792960           # 2.89 GB, per kind
     assert nbytes["lane"] == nbytes["ring"] == 2 * 64 * 4096 * 1280 * 2
-
-    def on(sd, spec=P()):
-        return jax.ShapeDtypeStruct(
-            sd.shape, sd.dtype, sharding=NamedSharding(grid.mesh, spec))
 
     params = jax.tree.map(on, model.pattern_param_shapes())
     cache = jax.tree.map(on, eng._cache_shapes, eng._cache_specs)
@@ -245,3 +276,95 @@ def test_pattern_prefill_at_published_widths_scans_its_runs(topo, on_chip):
     assert mem.alias_size_in_bytes > 2.89e9
     assert mem.temp_size_in_bytes < 1.0e9
     assert max(_copy_sizes(compiled)) < 64 * 4096 * 1280
+
+
+# the benchmark's dense decode cell (pythia-1.4b-d8.decode-conv-closed48):
+# Pythia-1.4b's widths, 8 layers, 32 slots of 2,048 positions, a bfloat16
+# cache under float32 parameters
+_DENSE = dict(vocab=50304, d_model=2048, n_heads=16, n_layers=8, d_ff=8192)
+_DENSE_SLOTS, _DENSE_S_CAP = 32, 2048
+_SLOT_LANE = _DENSE_S_CAP * 16 * 128            # one slot's rows of a layer
+_LANE = _DENSE_SLOTS * _SLOT_LANE               # one layer's K (or V) lane
+
+
+def _described_dense_engine(topo):
+    """The dense engine at the decode cell's own size on one described v5e:
+    (engine, params, cache, slot vector, live mask, ``on``)."""
+    import heat_tpu as ht
+    from heat_tpu.nn.transformer import TransformerLM, TransformerLMConfig
+
+    grid = ht.MeshGrid((1, 1, 1, 1), ("dp", "pp", "tp", "sp"),
+                       devices=topo.devices[:1])
+    model = TransformerLM(grid, TransformerLMConfig(
+        compute_dtype=jnp.bfloat16, **_DENSE))
+    eng, on = _described_engine(model, _DENSE_SLOTS, _DENSE_S_CAP)
+    assert eng._one_device
+    assert eng._cache_bytes == {"arena": 4294967296}    # 16 lanes of 268 MB
+    # the dense `init`'s shapes (it draws on the host: too slow to trace)
+    D, F, V, H, L = 2048, 8192, 50304, 16, 8
+    shapes = {"embed": (V, D), "final_ln": (D,), "unembed": (D, V),
+              "stages": {"ln1": (1, L, D), "wqkv": (1, L, D, 3, H, D // H),
+                         "wproj": (1, L, H, D // H, D), "ln2": (1, L, D),
+                         "w_up": (1, L, D, F), "w_down": (1, L, F, D)}}
+    params = jax.tree.map(
+        lambda shape, spec: on(jax.ShapeDtypeStruct(shape, jnp.float32), spec),
+        shapes, model.param_specs(), is_leaf=lambda s: isinstance(s, tuple))
+    cache = jax.tree.map(on, eng._cache_shapes, eng._cache_specs)
+    vec = on(jax.ShapeDtypeStruct((_DENSE_SLOTS,), jnp.int32), P("dp"))
+    live = on(jax.ShapeDtypeStruct((_DENSE_SLOTS,), jnp.bool_), P("dp"))
+    return eng, params, cache, vec, live, on
+
+
+def _assert_touches_no_lane(compiled):
+    """What "written and read where it lies" means in the optimised HLO:
+    the 16 donated lanes come back in place, nothing as large as a lane is
+    copied, no fusion slices a lane out, and a ``dynamic-update-slice``
+    fusion that returns a lane takes that lane and, besides it, nothing
+    larger than a slot's rows (it updates in place)."""
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 4.29e9
+    assert mem.temp_size_in_bytes < 1.0e9
+    copies = _copy_sizes(compiled)
+    assert copies and max(copies) < _LANE, max(copies)
+    for name, out, ops in _slice_fusions(compiled):
+        if "update-slice" in name and ops[:1] == [out]:
+            assert max(ops[1:], default=0) <= _SLOT_LANE, (name, ops)
+        else:
+            assert out < _LANE, (name, out)
+
+
+def test_dense_decode_step_at_the_cell_size_touches_no_lane(topo):
+    """The dense step at the decode cell's size compiles for one v5e and
+    writes ``slots`` rows a layer where they lie: before a layer's lanes were
+    leaves of their own the step sliced every layer's lane out of a
+    ``(layers, slots, S_cap, H, Dh)`` arena and wrote it back whole (32
+    fusions of 0.8 ms, half the step: PERF.md section 6, PR 34)."""
+    eng, params, cache, vec, live, _on = _described_dense_engine(topo)
+    compiled = eng._step_prog().lower(
+        params, *cache, vec, live, vec,
+        jax.eval_shape(lambda: jax.random.key(0))).compile()
+    assert compiled.memory_analysis().argument_size_in_bytes < 6.8e9
+    _assert_touches_no_lane(compiled)
+    # the row scatter is the only thing that returns a lane
+    assert not [name for name, out, _ops in _slice_fusions(compiled)
+                if out >= _LANE]
+
+
+@pytest.mark.parametrize("bucket", [1024, 32])
+def test_dense_prefill_at_the_cell_size_is_o_prompt(topo, on_chip, bucket):
+    """The dense prefill programs of the cell's largest and smallest
+    buckets: one slot's ``bucket`` rows a layer are written in place, and
+    but for the QKV weights (the per-step cast re-lays them: not the
+    cache's) no copy is larger than the prompt's own rows: a prefill is
+    O(prompt), not O(cache) (it took 36.9 ms whatever the prompt while each
+    of the two arenas was copied twice: PERF.md section 5, PR 32)."""
+    eng, params, cache, vec, _live, on = _described_dense_engine(topo)
+    i32 = on(jax.ShapeDtypeStruct((), jnp.int32))
+    compiled = eng._prefill_prog(bucket).lower(
+        params, *cache, vec, vec,
+        on(jax.ShapeDtypeStruct((bucket,), jnp.int32)), i32, i32,
+        jax.eval_shape(lambda: jax.random.key(0))).compile()
+    _assert_touches_no_lane(compiled)
+    wqkv = math.prod(params["stages"]["wqkv"].shape)
+    rest = [n for n in _copy_sizes(compiled) if n != wqkv]
+    assert max(rest) <= 2 * bucket * 16 * 128 <= 2 * _SLOT_LANE, max(rest)

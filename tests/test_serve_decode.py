@@ -43,21 +43,34 @@ from heat_tpu.utils import metrics as _pm
 
 _MEMO: dict = {}
 
+# the meshes the engine's programs differ over: every device (dp x tp 2, the
+# module's default), ONE device (the programs are plain `jit`s there) and
+# dp 2 x tp 2 (`shard_map`, slots over dp and heads over tp)
+MESHES = ("one", "dp2tp2")
 
-def _fx():
-    """Module-shared model/params/program-cache (§2b: one compile set)."""
-    if not _MEMO:
+
+def _fx(mesh="all"):
+    """Module-shared model/params/program-cache (§2b: one compile set a
+    mesh)."""
+    if mesh not in _MEMO:
         n = ht.get_comm().size
-        tp = 2 if n % 2 == 0 else 1
-        dp = n // tp
-        grid = ht.MeshGrid((dp, 1, tp, 1), ("dp", "pp", "tp", "sp"))
+        if mesh == "all":
+            tp = 2 if n % 2 == 0 else 1
+            dp, devices = n // tp, None
+        else:
+            dp, tp = {"one": (1, 1), "dp2tp2": (2, 2)}[mesh]
+            if dp * tp > n:
+                pytest.skip(f"mesh {mesh} needs {dp * tp} devices")
+            devices = jax.devices()[:dp * tp]
+        grid = ht.MeshGrid((dp, 1, tp, 1), ("dp", "pp", "tp", "sp"),
+                           devices=devices)
         cfg = TransformerLMConfig(vocab=29, d_model=32, n_heads=4,
                                   n_layers=2, d_ff=64)
         model = TransformerLM(grid, cfg)
-        _MEMO.update(model=model, params=model.init(11),
-                     cache=ProgramCache(name="decode-test"),
-                     refs={})
-    return _MEMO
+        _MEMO[mesh] = dict(model=model, params=model.init(11),
+                           cache=ProgramCache(name=f"decode-test-{mesh}"),
+                           refs={})
+    return _MEMO[mesh]
 
 
 @pytest.fixture(scope="module", autouse=True)
@@ -68,8 +81,8 @@ def _drop_compiled_state():
     gc.collect()
 
 
-def _engine(**over):
-    fx = _fx()
+def _engine(mesh="all", **over):
+    fx = _fx(mesh)
     kw = dict(slots=2 * fx["model"].dp_world, max_seq_len=64)
     kw.update(over)
     return DecodeEngine(fx["model"], fx["params"], DecodeConfig(**kw),
@@ -81,10 +94,10 @@ def _prompt(seed, s0):
     return rng.integers(0, _fx()["model"].cfg.vocab, (s0,)).astype(np.int32)
 
 
-def _ref(prompt, max_new):
+def _ref(prompt, max_new, mesh="all"):
     """generate()'s tokens for one request (memoized — the reference
     programs are the module's biggest compiles)."""
-    fx = _fx()
+    fx = _fx(mesh)
     key = (prompt.tobytes(), int(max_new))
     if key not in fx["refs"]:
         B = fx["model"].dp_world
@@ -178,14 +191,69 @@ def test_slot_reuse_after_finish():
             out, _ref(_prompt(100 + i, 3 + (i % 5)), 2 + (i % 3)))
 
 
-def test_donation_invalidates_old_cache():
-    """The decode-step carry is donated: after a request runs, the cache
-    buffers the engine started with are deleted (device memory stays
-    ONE cache, not one per step)."""
-    with _engine() as eng:
-        ck0, cv0 = eng._ck, eng._cv
+@pytest.mark.parametrize("mesh", ("all",) + MESHES)
+def test_donation_invalidates_old_cache(mesh):
+    """The decode-step carry is donated: after a request runs, EVERY leaf
+    of the cache the engine started with (a K and a V lane a layer) is
+    deleted (device memory stays ONE cache, not one per step)."""
+    with _engine(mesh) as eng:
+        leaves0 = jax.tree.leaves(eng._cache)
+        assert len(leaves0) == 2 * eng.model.cfg.n_layers
         eng.generate(_prompt(40, 3), 4, timeout=120)
-        assert ck0.is_deleted() and cv0.is_deleted()
+        assert all(leaf.is_deleted() for leaf in leaves0)
+        assert not any(leaf.is_deleted()
+                       for leaf in jax.tree.leaves(eng._cache))
+
+
+# the three ways a lane's rows can be wrong without a wrong shape: a short
+# prompt in the slot a long tenant just left (its rows beyond the prompt are
+# the old tenant's, masked by position alone), a prompt padded up to its
+# bucket (the pad's rows are garbage until decode overwrites them), and a
+# prompt that fills the largest bucket an engine of 64 positions has
+SLOT_CASES = {
+    "reused_slot": ((20, 8), (3, 6)),
+    "padded_prompt": ((5, 6),),
+    "largest_bucket": ((32, 8),),
+}
+
+
+@pytest.mark.parametrize("case", SLOT_CASES)
+@pytest.mark.parametrize("mesh", MESHES)
+def test_engine_matches_generate_on_one_device_and_on_dp_tp(mesh, case):
+    """Tokens served through the engine equal ``generate()``'s token for
+    token where the programs are plain ``jit``s (one device) and where
+    they are ``shard_map``s (dp 2 x tp 2), with the cache a leaf a layer
+    in both. One request at a time into an engine of ``dp_world`` slots,
+    so every request of a case is served from slot 0."""
+    with _engine(mesh, slots=_fx(mesh)["model"].dp_world) as eng:
+        for i, (s0, mn) in enumerate(SLOT_CASES[case]):
+            prompt = _prompt(300 + i, s0)
+            out = eng.generate(prompt, mn, timeout=120)
+            np.testing.assert_array_equal(out, _ref(prompt, mn, mesh))
+        assert eng.stats()["prefills"] == len(SLOT_CASES[case])
+        assert eng.stats()["decode_fallbacks"] == 0
+
+
+@pytest.mark.parametrize("mesh", MESHES)
+def test_programs_are_plain_jits_on_one_device_only(mesh):
+    """On a mesh of ONE device the step and the prefill are plain ``jit``s
+    (a ``shard_map`` of one shard computes the same and may copy donated
+    lanes at its boundary); on dp 2 x tp 2 the bodies name mesh axes and
+    the ``shard_map`` stays. The program names are the same either way."""
+    with _engine(mesh) as eng:
+        assert eng._one_device == (mesh == "one")
+        n = eng.slots
+        step = eng._step_prog().trace(
+            eng.params, *eng._cache, eng._pos, np.zeros(n, bool), eng._toks,
+            jax.random.key(0))
+        prefill = eng._prefill_prog(8).trace(
+            eng.params, *eng._cache, eng._pos, eng._toks,
+            np.zeros(8, np.int32), np.int32(3), np.int32(0),
+            jax.random.key(0))
+        for traced, name in ((step, "jit_decode_step"),
+                             (prefill, "jit_decode_prefill")):
+            assert ("shard_map" in str(traced.jaxpr)) == (mesh != "one")
+            assert f"module @{name}" in traced.lower().as_text()
 
 
 # --------------------------------------------------------------------- #

@@ -251,26 +251,35 @@ def test_moe_block_carries_its_scope():
     assert "moe" in parts and "mlp" not in parts
 
 
-def test_decode_programs_carry_their_names_and_scopes():
+@pytest.mark.parametrize("shape", [(1, 1, 1, 1), (2, 1, 2, 1)], ids=str)
+def test_decode_programs_carry_their_names_and_scopes(shape):
+    """The dense engine's two programs, as plain ``jit``s (one device) and
+    as ``shard_map``s (dp 2 x tp 2): the same names and scopes."""
     from heat_tpu.serve.decode import DecodeConfig, DecodeEngine
 
-    model, params = small_lm()
+    model, params = small_lm(shape)
+    n = 2 * model.dp_world
     with DecodeEngine(model, params,
-                      DecodeConfig(slots=2, max_seq_len=32)) as eng:
+                      DecodeConfig(slots=n, max_seq_len=32)) as eng:
         step = eng._step_prog()
         module, parts = scopes_of(step.lower(
-            params, eng._ck, eng._cv, eng._pos, jnp.zeros(2, bool),
+            params, *eng._cache, eng._pos, jnp.zeros(n, bool),
             eng._toks, jax.random.key(0)))
         assert module == "jit_decode_step"
-        assert {"attn.core", "cache.read", "cache.write", "attn.qkv", "mlp",
-                "head", "sample", "embed"} <= parts
+        assert {"attn.core", "cache.write", "attn.qkv", "mlp", "head",
+                "sample", "embed"} <= parts
+        # a layer's lanes are leaves of their own: the row scatter is the
+        # only write and the attention reads the leaf where it lies, so
+        # nothing is copied out for a `cache.read` to name
+        assert "cache.read" not in parts
         prefill = eng._prefill_prog(8)
         module, parts = scopes_of(prefill.lower(
-            params, eng._ck, eng._cv, eng._pos, eng._toks,
+            params, *eng._cache, eng._pos, eng._toks,
             jnp.zeros(8, jnp.int32), jnp.int32(3), jnp.int32(0),
             jax.random.key(0)))
         assert module == "jit_decode_prefill"
         assert {"attn.core", "cache.write", "sample"} <= parts
+        assert "cache.read" not in parts
 
 
 def test_pattern_decode_programs_nest_their_new_scopes_under_known_ones():
