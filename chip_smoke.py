@@ -157,7 +157,7 @@ def _lm_config(n_layers):
     if R:
         return TransformerLMConfig(vocab=128, d_model=64, n_heads=4,
                                    n_layers=2)
-    # the widest shape the repo carries (bench.py transformer_train_metrics)
+    # a mid-size shape: wide enough for the MXU path, quick to compile
     return TransformerLMConfig(vocab=32768, d_model=1024, n_heads=16,
                                n_layers=n_layers, compute_dtype=jnp.bfloat16)
 

@@ -233,8 +233,8 @@ def _lloyd_fused_gspmd_fn(phys_shape, jdt, k, n_valid, comm, qk, ck, hk):
 
 def _lloyd_eager_step(phys_shape, jdt, k, n_valid):
     """The SAME Lloyd mathematics dispatched op-by-op (unjitted jnp with
-    GSPMD-placed collectives): the ``fit.step.dispatch`` degrade path
-    and the analytics bench's eager leg. Returns the fit-step tuple
+    GSPMD-placed collectives): the ``fit.step.dispatch`` degrade path.
+    Returns the fit-step tuple
     ``(new_centroids, shift, inertia)``."""
     acc = _acc_dtype(jdt)
 
@@ -360,9 +360,8 @@ def _lloyd_fori_fn(phys_shape, jdt, k, n_valid, comm):
 
     The whole hot loop is one XLA program compiled once and reused for any
     iteration count (the compiled-epoch discipline SURVEY.md §7 calls for,
-    hard part 5). Used by the benchmark driver, which times two different
-    trip counts with the same executable and differences them to cancel
-    constant dispatch/transfer overhead."""
+    hard part 5). Audited by ``scripts/collective_audit.py``: one packed
+    all-reduce an iteration at every device count."""
     sums_mode = _use_pallas_step(jdt) and _kmeans_sums_mode()
     block_rows = _kmeans_block_rows() if sums_mode else None
     key = ("fori", phys_shape, str(jdt), k, n_valid, comm.cache_key,

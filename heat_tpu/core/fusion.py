@@ -503,7 +503,7 @@ def set_enabled(flag: bool) -> bool:
 @contextlib.contextmanager
 def override(flag: bool):
     """Context manager form of :func:`set_enabled` (used by the eager-vs-
-    fused property tests and the bench A/B)."""
+    fused property tests)."""
     prev = set_enabled(flag)
     try:
         yield
@@ -530,7 +530,7 @@ def set_step_enabled(flag: bool) -> bool:
 @contextlib.contextmanager
 def step_override(flag: bool):
     """Context manager form of :func:`set_step_enabled` (the traced-vs-
-    eager property tests and the train-step bench A/B)."""
+    eager property tests)."""
     prev = set_step_enabled(flag)
     try:
         yield
@@ -559,7 +559,7 @@ def set_fit_enabled(flag: bool) -> bool:
 @contextlib.contextmanager
 def fit_override(flag: bool):
     """Context manager form of :func:`set_fit_enabled` (the fused-vs-
-    legacy estimator parity tests and the analytics bench A/B)."""
+    legacy estimator parity tests)."""
     prev = set_fit_enabled(flag)
     try:
         yield

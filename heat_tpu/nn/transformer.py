@@ -1016,11 +1016,11 @@ class TransformerLM:
     # ------------------------------------------------------------- #
     # generation (KV-cached autoregressive decode)                  #
     # ------------------------------------------------------------- #
-    # the cache-attention bodies below are shared by generate()'s
-    # compiled batch program AND the serving continuous-batching engine
-    # (heat_tpu.serve.decode.DecodeEngine) — an architecture change
-    # lands in both decoders at once, like _block/_forward_device for
-    # training and serving forwards
+    # what a layer does with one token and what it keeps between tokens is
+    # said ONCE, in `prefill`, `decode_step_logits`, `cache_layout` and
+    # `cache_store` below: generate()'s batch program and the serving
+    # engine (heat_tpu.serve.decode.DecodeEngine) are both clients of those
+    # four, so an architecture change lands in every decoder at once
 
     PROMPT_BUCKET_MIN = 8
 
@@ -1079,33 +1079,6 @@ class TransformerLM:
         w = jax.nn.softmax(s, axis=-1)
         out = jnp.einsum("bhqs,bshd->bqhd", w, cv.astype(jnp.float32))
         return out.astype(q.dtype)
-
-    def _cache_layer_step(self, p_l, x, ck, cv, pos, wire=None):
-        """One block on a single-token batch (Bl, 1, D): write this
-        token's K/V at per-row cache position ``pos`` ((Bl,) int32) and
-        attend rows < pos+1. ``generate`` passes a uniform ``pos`` (the
-        whole batch at step t); the DecodeEngine passes each slot's own
-        position. Rows whose position the caller does not advance (dead
-        slots) just overwrite the same masked row — harmless by the
-        col < upto discipline."""
-        Bl = x.shape[0]
-        q, k, v = self._qkv(p_l, x, pos[:, None])
-        with scope("cache.write"):
-            ck = ck.at[jnp.arange(Bl), pos].set(k[:, 0].astype(ck.dtype))
-            cv = cv.at[jnp.arange(Bl), pos].set(v[:, 0].astype(cv.dtype))
-        x = self._attn_residual(
-            p_l, x, self._attn_from_cache(q, ck, cv, pos + 1), wire=wire)
-        x = self._dense_mlp_residual(p_l, x, wire=wire)
-        return x, ck, cv
-
-    def _prompt_kv_logits(self, params, toks, n_valid, wire=None):
-        """The dense model's padded-prompt prefill as ``generate`` and the
-        reference's ``prefill_logits`` take it: per-layer K/V lists (each
-        (Bl, Sp, Hs, Dh), post-RoPE — each row rotated by its absolute
-        position exactly as in training) and the f32 logits at position
-        ``n_valid - 1``. See :meth:`prefill`."""
-        kept, logits = self.prefill(params, toks, n_valid, wire=wire)
-        return [k["k"] for k in kept], [k["v"] for k in kept], logits
 
     # ------------------------------------------------------------- #
     # serving: ONE layer function over (mixer kind, cache view)     #
@@ -1293,10 +1266,20 @@ class TransformerLM:
         mine = per_layer[l]
         if kind == "attn":
             # the layer's own leaves in, the same leaves out: the row
-            # scatter inside is the only write and the attention reads the
-            # leaf where it lies (no lane is copied out of or into an arena)
-            x, ck, cv = self._cache_layer_step(p_l, x, mine["k"], mine["v"],
-                                               pos, wire=wire)
+            # scatter is the only write and the attention reads the leaf
+            # where it lies (no lane is copied out of or into an arena). A
+            # row whose position the caller does not advance (a dead slot)
+            # overwrites the same masked row: harmless by col < pos + 1
+            Bl = x.shape[0]
+            q, k, v = self._qkv(p_l, x, pos[:, None])
+            with scope("cache.write"):
+                ck = mine["k"].at[jnp.arange(Bl), pos].set(
+                    k[:, 0].astype(mine["k"].dtype))
+                cv = mine["v"].at[jnp.arange(Bl), pos].set(
+                    v[:, 0].astype(mine["v"].dtype))
+            x = self._attn_residual(
+                p_l, x, self._attn_from_cache(q, ck, cv, pos + 1), wire=wire)
+            x = self._dense_mlp_residual(p_l, x, wire=wire)
             mine = {"k": ck, "v": cv}
             return x, (per_layer[:l] + [mine] + per_layer[l + 1:],), carry
         with scope("attn.qkv"):
@@ -1323,7 +1306,7 @@ class TransformerLM:
                             "v": mine["v"].at[rows, at].set(
                                 mixers.lanes(v)[:, 0].astype(mine["v"].dtype))}
                 # read in place by the maps below: no copy, so no op of
-                # its own that a `cache.read` scope could name
+                # its own for a scope to name
                 ck, cv = mine["k"], mine["v"]
                 # a ring holds the last `window` positions in any order (no
                 # positional encoding): before it wraps, rows <= pos
@@ -1400,10 +1383,11 @@ class TransformerLM:
 
     @scope("cache.write")
     def cache_store(self, cache, kept, slot, ok):
-        """Write one prompt's ``kept`` (:meth:`prefill`'s, batch of 1) into
-        lane ``slot`` of this device's ``cache``; ``ok`` false (the slot
-        lives on another dp shard) writes the lane's OWN current rows back:
-        the select is block-sized, never a full-cache copy. A state-space
+        """Write ``kept`` (:meth:`prefill`'s: one prompt from the engine,
+        every row of the batch from ``generate``) into this device's
+        ``cache`` from lane ``slot`` on; ``ok`` false (the slot lives on
+        another dp shard) writes the lanes' OWN current rows back: the
+        select is block-sized, never a full-cache copy. A state-space
         layer's state and tail are written WHOLE: whatever the lane's last
         tenant left there is gone."""
         def put(buf, new, idx):
@@ -1442,7 +1426,6 @@ class TransformerLM:
         K/V are cached post-RoPE, so each cache row is rotated by its own
         absolute position exactly as in the training forward.
         """
-        c = self.cfg
         self._needs_dense("generate")
         self.check_decode_grid()
         prompts = jnp.asarray(prompts, jnp.int32)
@@ -1457,7 +1440,11 @@ class TransformerLM:
         Sb = self.prompt_bucket(S0)
         S_max = Sb + max_new_tokens
 
-        def generate(params, toks, n_valid, key):
+        dp_axes = ("dcn", "dp") if self._has_dcn else "dp"
+        shapes, cache_specs, _ = self.cache_layout(B, S_max, dp_axes)
+
+        def decode(params, *rest):
+            *cache, toks, n_valid, key = rest
             Bl = toks.shape[0]
             # independent sampling noise per data-parallel shard — a
             # replicated key would draw IDENTICAL continuations for equal
@@ -1466,19 +1453,11 @@ class TransformerLM:
             if self._has_dcn:
                 dp_idx = lax.axis_index("dcn") * self.dp + dp_idx
             key = jax.random.fold_in(key, dp_idx)
-            stage_params = self._stage_params(params)
-            dtype = c.compute_dtype
-            Hs = c.n_heads // self.tp
-            caches_k = jnp.zeros((c.n_layers, Bl, S_max, Hs, c.head_dim),
-                                 dtype)
-            caches_v = jnp.zeros_like(caches_k)
 
-            # ---- prefill: causal pass over the padded prompt ---- #
-            ks, vs, logits0 = self._prompt_kv_logits(params, toks, n_valid)
-            with scope("cache.write"):
-                for l in range(c.n_layers):
-                    caches_k = caches_k.at[l, :, :Sb].set(ks[l])
-                    caches_v = caches_v.at[l, :, :Sb].set(vs[l])
+            # ---- prefill: causal pass over the padded prompt, every row
+            # of the batch kept at once ---- #
+            kept, logits0 = self.prefill(params, toks, n_valid)
+            cache = self.cache_store(tuple(cache), kept, jnp.int32(0), True)
 
             def sample(logits, key):
                 with scope("sample"):
@@ -1490,45 +1469,42 @@ class TransformerLM:
             key0, key = jax.random.split(key)
             first = sample(logits0, key0)
 
-            # ---- decode scan ---- #
+            # ---- decode scan: the engine's step body, the whole batch at
+            # position t, the cache as the carry ---- #
             def step(carry, key_t):
-                caches_k, caches_v, tok, t = carry
-                with scope("embed"):
-                    x = params["embed"][tok].astype(dtype)[:, None, :]
-                pos = jnp.full((Bl,), t, jnp.int32)
-                new_k, new_v = caches_k, caches_v
-                for l in range(c.n_layers):
-                    p_l = self._cast_params(stage_params, l)
-                    with scope("cache.read"):
-                        ck_l, cv_l = new_k[l], new_v[l]
-                    x, ckl, cvl = self._cache_layer_step(
-                        p_l, x, ck_l, cv_l, pos)
-                    with scope("cache.write"):
-                        new_k = new_k.at[l].set(ckl)
-                        new_v = new_v.at[l].set(cvl)
-                logits = self._head(params, x)[:, 0]
-                nxt = sample(logits, key_t)
-                return (new_k, new_v, nxt, t + 1), tok
+                cache, tok, t = carry
+                logits, cache = self.decode_step_logits(
+                    params, cache, tok, jnp.full((Bl,), t, jnp.int32))
+                return (cache, sample(logits, key_t), t + 1), tok
 
             # first came from the prefill; N-1 scan steps yield the rest
             # (each step consumes the previous token and emits the next)
             keys = jax.random.split(key, max_new_tokens)[1:]
-            (_, _, last, _), toks_out = lax.scan(
-                step, (caches_k, caches_v, first, n_valid), keys)
+            (_, last, _), toks_out = lax.scan(
+                step, (cache, first, n_valid), keys)
             # toks_out: (N-1, Bl) tokens FED at each step; append the final
             return jnp.concatenate(
                 [jnp.swapaxes(toks_out, 0, 1), last[:, None]], axis=1)
 
-        data_spec = P(("dcn", "dp"), None) if self._has_dcn \
-            else P("dp", None)
+        data_spec = P(dp_axes, None)
         cache_key = ("generate", B, Sb, max_new_tokens, float(temperature))
         fn = self._step_cache.get(cache_key)
         if fn is None:
-            fn = jax.jit(shard_map(
-                generate, mesh=self.grid.mesh,
-                in_specs=(self.param_specs(), data_spec, P(), P()),
-                out_specs=data_spec, check_vma=False))
-            self._step_cache[cache_key] = fn
+            sharded = shard_map(
+                decode, mesh=self.grid.mesh,
+                in_specs=(self.param_specs(), *cache_specs, data_spec, P(),
+                          P()),
+                out_specs=data_spec, check_vma=False)
+
+            @jax.jit
+            def generate(params, toks, n_valid, key):
+                # the tree `cache_layout` describes for B rows and S_max
+                # positions, zeroed: a temporary of this one program
+                cache = jax.tree.map(
+                    lambda sd: jnp.zeros(sd.shape, sd.dtype), shapes)
+                return sharded(params, *cache, toks, n_valid, key)
+
+            fn = self._step_cache[cache_key] = generate
         padded = jnp.pad(prompts, ((0, 0), (0, Sb - S0)))
         toks_sharded = jax.device_put(
             padded, NamedSharding(self.grid.mesh, data_spec))

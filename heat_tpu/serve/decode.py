@@ -51,11 +51,14 @@ persistent, device-resident KV cache:
   batch executor uses: slot grants are priority-ordered (FIFO within a
   priority), tenant ``slo_ms`` is the default deadline, and per-tenant
   admitted/completed/shed counters fold into ``runtime_stats()``.
-* **Fault containment.** A failed decode-step dispatch degrades that
-  step to the eager path (plain global-array jnp ops, no compiled step
-  executable) with every future intact — ``serve.decode_fallbacks`` ticks
-  and the chaos matrix pins fault-free-equal tokens
-  (``serve.decode.step`` in ``doc/robustness.md``).
+* **Fault containment.** A failed decode-step dispatch that left the
+  donated buffers intact degrades that step to the step program's OWN
+  body run uncompiled (``jax.disable_jit()`` around the same call: op by
+  op on one device, under its ``shard_map`` on dp x tp), with every
+  future intact — ``serve.decode_fallbacks`` ticks and the chaos matrix
+  pins fault-free-equal tokens (``serve.decode.step`` in
+  ``doc/robustness.md``). The engine holds no layer mathematics: what a
+  layer does with a token is ``TransformerLM``'s to say.
 
 ``serve_transformer(model, params, seq_len, decode=True)`` is the adapter
 entry point; ``examples/nn/gpt_parallel.py --serve`` drives it.
@@ -794,19 +797,22 @@ class DecodeEngine:
         if self._live_dev is None:
             self._live_dev = jax.device_put(
                 live, NamedSharding(self.model.grid.mesh, self._vec_spec))
+        args = (self.params, *self._cache, self._pos, self._live_dev,
+                self._toks, skey)
         try:
             _faults.check("serve.decode.step")
             with span("decode.step.dispatch"):
-                out = prog(self.params, *self._cache, self._pos,
-                           self._live_dev, self._toks, skey)
+                out = prog(*args)
         except Exception:
             if self._donated_gone():
                 raise  # donated buffers invalidated mid-dispatch (PR 8)
-            # DEGRADED: the eager per-slot path — same mathematics, one
-            # slot at a time in plain global-array ops, futures intact
+            # DEGRADED: the step program's own body, uncompiled (op by op,
+            # under its `shard_map` on dp x tp): the same tokens, nothing
+            # donated, every future intact
             _pm.inc("serve.decode_fallbacks")
             self._fallbacks += 1
-            out = self._step_eager(live, skey)
+            with jax.disable_jit():
+                out = prog(*args)
         *cache, self._pos, toks2 = out
         self._cache = tuple(cache)
         self._toks = toks2
@@ -876,89 +882,3 @@ class DecodeEngine:
                     req.future.set_exception(exc)
             except Exception:
                 pass
-
-    # ------------------------------------------------------------------ #
-    # the eager per-slot degraded path                                   #
-    # ------------------------------------------------------------------ #
-    def _step_eager(self, live: np.ndarray, skey):
-        """One decode step as plain per-slot global-array jnp ops — no
-        compiled step executable involved. Slow (one slot at a time,
-        GSPMD per-op dispatch) but it keeps every future intact when the
-        step dispatch fails; values match the compiled step (same masked
-        attention over the same cache rows). Host-known per-slot
-        positions/tokens drive it, so shapes stay static. A pattern
-        model runs the step's own body op by op instead, every slot at
-        once (:meth:`_step_eager_pattern`)."""
-        from ..nn.transformer import _rmsnorm, rope_apply
-
-        m, c = self.model, self.model.cfg
-        if c.pattern:
-            return self._step_eager_pattern(live, skey)
-        params = self.params
-        dtype = c.compute_dtype
-        stage_params = m._stage_params(params)
-        pos_h = self._fetch(self._pos)
-        toks_h = self._fetch(self._toks)
-        lanes = list(self._cache[0])   # a layer's {"k", "v"} each
-        new_toks = toks_h.copy()
-        for s in np.nonzero(live)[0]:
-            s = int(s)
-            p = jnp.int32(int(pos_h[s]))
-            x = params["embed"][int(toks_h[s])].astype(dtype)[None, None, :]
-            for l in range(c.n_layers):
-                p_l = m._cast_params(stage_params, l)
-                a_in = _rmsnorm(x, p_l["ln1"])
-                qkv = jnp.einsum("bsd,dohk->bsohk", a_in, p_l["wqkv"])
-                q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
-                if c.rope:
-                    q = rope_apply(q, p[None], c.rope_theta)
-                    k = rope_apply(k, p[None], c.rope_theta)
-                ck, cv = lanes[l]["k"], lanes[l]["v"]
-                ck = ck.at[s, p].set(k[0, 0].astype(ck.dtype))
-                cv = cv.at[s, p].set(v[0, 0].astype(cv.dtype))
-                lanes[l] = {"k": ck, "v": cv}
-                attn = m._attn_from_cache(q, ck[s][None], cv[s][None], p + 1)
-                x = x + jnp.einsum("bshk,hkd->bsd", attn, p_l["wproj"])
-                m_in = _rmsnorm(x, p_l["ln2"])
-                x = x + jax.nn.gelu(m_in @ p_l["w_up"]) @ p_l["w_down"]
-            logits = m._head(params, x)[0, 0]
-            temp = float(self.config.temperature)
-            if temp == 0.0:
-                nxt = int(self._fetch(jnp.argmax(logits)))
-            else:
-                nxt = int(self._fetch(jax.random.categorical(
-                    jax.random.fold_in(skey, s), logits / temp)))
-            new_toks[s] = nxt
-        pos2 = jax.device_put(
-            pos_h + live.astype(np.int32),
-            NamedSharding(self.model.grid.mesh, self._vec_spec))
-        toks2 = jax.device_put(
-            new_toks,
-            NamedSharding(self.model.grid.mesh, self._vec_spec))
-        return lanes, pos2, toks2
-
-    def _step_eager_pattern(self, live: np.ndarray, skey):
-        """The degraded step of a pattern model: the compiled step's body
-        (``decode_step_logits``) as plain global-array ops, all slots
-        together; dead slots keep their token and position."""
-        m = self.model
-        mesh = m.grid.mesh
-        logits, cache = m.decode_step_logits(
-            self.params, self._cache, self._toks, self._pos,
-            wire=self._wire())
-        temp = float(self.config.temperature)
-        if temp == 0.0:
-            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        else:
-            keys = jax.vmap(lambda i: jax.random.fold_in(skey, i))(
-                jnp.arange(self.slots))
-            nxt = jax.vmap(lambda k, lg: jax.random.categorical(
-                k, lg / temp))(keys, logits).astype(jnp.int32)
-        live_d = jnp.asarray(live)
-        vec_sh = NamedSharding(mesh, self._vec_spec)
-        toks2 = jax.device_put(jnp.where(live_d, nxt, self._toks), vec_sh)
-        pos2 = jax.device_put(self._pos + live_d.astype(jnp.int32), vec_sh)
-        cache = jax.tree.map(
-            lambda a, spec: jax.device_put(a, NamedSharding(mesh, spec)),
-            cache, self._cache_specs)
-        return (*cache, pos2, toks2)

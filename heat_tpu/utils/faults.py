@@ -150,7 +150,8 @@ SITES: Dict[str, tuple] = {
         FaultInjected,
         "continuous-batching decode-step dispatch "
         "(serve/decode.py::DecodeEngine._dispatch_step) — that step "
-        "degrades to the eager per-slot path with every future intact, "
+        "degrades to the step program's own body run uncompiled, with "
+        "every future intact, "
         "counted in serve.decode_fallbacks"),
     # distributed data engine (data/engine.py, data/streaming.py)
     "data.exchange.dispatch": (

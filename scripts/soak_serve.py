@@ -2,7 +2,7 @@
 """Open-loop serve soak with p99-under-load acceptance (ISSUE 14).
 
 Brings up a multi-tenant :class:`heat_tpu.serve.ServingExecutor` over the
-launch mesh (the ladder/bench run it at 4 virtual CPU devices), registers
+launch mesh (the ladder runs it at 4 virtual CPU devices), registers
 two tenants —
 
 * ``hi``: priority 10, an SLO-derived deadline, a small share of traffic
@@ -18,7 +18,7 @@ that deterministically pushes the queue past its bound. A final breaker
 phase opens the ``lo`` circuit under a persistent dispatch fault and
 measures fast-fail latency against the dispatch-retry failure path.
 
-Verdicts (exit 1 if any fails — the ladder/bench gate on this):
+Verdicts (exit 1 if any fails — the ladder gates on this):
 
 * ``worker_alive``   — the dispatch worker survived every phase;
 * ``zero_untyped``   — every rejected request carried a *typed* serve
@@ -64,8 +64,8 @@ def main() -> int:
                     help="fault plan armed during the >=2x phases "
                          "('' disarms)")
     ap.add_argument("--quick", action="store_true",
-                    help="short deterministic form for the CI ladder / "
-                         "bench stage (~10 s of phases)")
+                    help="short deterministic form for the CI ladder "
+                         "(~10 s of phases)")
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--max-rps", type=float, default=2000.0,
                     help="offered-rate clamp (a python generator thread "

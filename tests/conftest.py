@@ -134,7 +134,7 @@ def pytest_runtest_teardown(item, nextitem):
                 c.get("op_engine.hier_fallbacks", 0)),
             # continuous-batching decode engine (the --decode-smoke
             # ladder stage reads these: which tests dispatched slot
-            # steps, and whether any degraded to the eager per-slot path)
+            # steps, and whether any degraded to the uncompiled step)
             "serve_decode_steps": int(c.get("serve.decode_steps", 0)),
             "serve_decode_fallbacks": int(
                 c.get("serve.decode_fallbacks", 0)),

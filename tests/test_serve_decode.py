@@ -257,6 +257,77 @@ def test_programs_are_plain_jits_on_one_device_only(mesh):
 
 
 # --------------------------------------------------------------------- #
+# the degraded step                                                     #
+# --------------------------------------------------------------------- #
+# three requests granted in one turn into an engine of four slots: by the
+# third step two are live, one has finished (a dead slot that was live) and
+# one slot was never granted
+BURST = ((5, 10), (9, 6), (3, 2))
+
+
+def _burst(mesh, fault=None):
+    """Serve ``BURST`` (under the fault plan ``fault``). Returns (the
+    answers, the final per-slot positions and tokens, the engine's
+    stats)."""
+    import contextlib
+
+    from heat_tpu.utils import faults
+
+    with _engine(mesh, slots=4) as eng:
+        eng.pause()
+        futs = [eng.submit(_prompt(500 + i, s0), mn)
+                for i, (s0, mn) in enumerate(BURST)]
+        with faults.inject(fault) if fault else contextlib.nullcontext():
+            eng.resume()
+            outs = [f.result(timeout=300) for f in futs]
+        return outs, np.asarray(eng._pos), np.asarray(eng._toks), eng.stats()
+
+
+@pytest.mark.parametrize("mesh", MESHES)
+def test_the_degraded_step_serves_the_same_tokens(mesh):
+    """A step whose dispatch fails runs the step program's OWN body
+    uncompiled, on the engine's mesh (directly on one device, under its
+    ``shard_map`` on dp 2 x tp 2): the answers are the unfaulted run's
+    (and ``generate()``'s), the step counts as ONE fallback, and a dead
+    slot's token and position stay as the compiled step leaves them."""
+    want, pos0, toks0, st0 = _burst(mesh)
+    assert st0["decode_fallbacks"] == 0
+    fb0 = int(_pm.counters().get("serve.decode_fallbacks", 0))
+    got, pos, toks, st = _burst(mesh, fault="serve.decode.step=nth:3")
+    assert st["decode_fallbacks"] == 1
+    assert int(_pm.counters()["serve.decode_fallbacks"]) == fb0 + 1
+    assert st["decode_steps"] == st0["decode_steps"] == 9
+    for i, (s0, mn) in enumerate(BURST):
+        np.testing.assert_array_equal(got[i], want[i])
+        np.testing.assert_array_equal(
+            got[i], _ref(_prompt(500 + i, s0), mn, mesh))
+    # slot 2 finished after the first step and slot 3 never ran: the
+    # degraded third step advanced neither
+    np.testing.assert_array_equal(pos, pos0)
+    np.testing.assert_array_equal(toks, toks0)
+    assert pos[2] == 3 + 1 and pos[3] == 0
+
+
+def test_serve_holds_no_layer_math():
+    """``serve/decode.py`` is a client of ``TransformerLM``'s four decode
+    functions: it imports no private of ``nn/transformer.py`` and has no
+    degraded step of its own, so a second statement of a layer cannot grow
+    back unseen."""
+    import re
+
+    import heat_tpu.serve.decode as mod
+
+    with open(mod.__file__) as f:
+        text = f.read()
+    assert "_step_eager" not in text
+    imported = re.findall(
+        r"from \.\.nn\.transformer import (\([^)]*\)|[^\n]*)", text)
+    assert not [n for names in imported for n in re.findall(r"\w+", names)
+                if n.startswith("_")]
+    assert not re.search(r"\b(_rmsnorm|rope_apply|einsum)\b", text)
+
+
+# --------------------------------------------------------------------- #
 # steady state + codec keying                                           #
 # --------------------------------------------------------------------- #
 def test_steady_state_zero_misses_with_codec_toggles():
