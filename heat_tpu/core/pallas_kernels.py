@@ -8,9 +8,13 @@ GB/s-critical tiles the framework runs in its hot loops:
 * :func:`cdist_tile` — one fused pairwise-L2 block: the norm terms, the
   ``-2·x·yᵀ`` GEMM on the MXU, the clamp and the sqrt all execute inside a
   single VMEM-resident tile, so the ``(bm, bn)`` distance block is produced
-  in one pass with no HBM round-trip for intermediates. This is the tile
-  under the ``ppermute`` ring of :mod:`heat_tpu.spatial.distance` (the
-  reference's systolic loop, ``distance.py:280-362``).
+  in one pass with no HBM round-trip for intermediates, and the kernel's
+  output array has the result's own ``(m, n)`` shape: the last row and
+  column of tiles are edge blocks whose out-of-range part Mosaic does not
+  store, so the result is written once and nothing slices it afterwards.
+  This is the tile under the ``ppermute`` ring of
+  :mod:`heat_tpu.spatial.distance` (the reference's systolic loop,
+  ``distance.py:280-362``).
 * :func:`flash_attention` — blockwise attention with online-softmax
   statistics (flash style). Returns the normalized block output together
   with the log-sum-exp per query row, which is exactly the merge state ring
@@ -156,7 +160,17 @@ def cdist_tile(x, y, sqrt: bool = True, block_m: int = 256,
     its norm terms and MXU GEMM entirely in VMEM. ``sqrt=False`` returns
     squared distances (the KMeans assignment form). ``out_dtype`` overrides
     the output dtype (the kernel accumulates in f32/f64 regardless — rbf
-    passes f32 here so the exp sees unrounded distances)."""
+    passes f32 here so the exp sees unrounded distances).
+
+    The result is written ONCE, at its own shape: ``out_shape`` is
+    ``(m, n)`` and the grid ``cdiv(m, bm) × cdiv(n, bn)``, so the last row
+    and the last column of tiles are edge blocks. An edge tile computes all
+    of its ``(bm, bn)`` entries and Mosaic stores only the part that lies
+    inside ``(m, n)``; nothing slices or copies the result afterwards. The
+    INPUTS stay padded (rows to the tile, features to 128 lanes, with
+    zeros), so an edge tile reads zeros and never unspecified memory; and
+    entry ``(i, j)`` reads row ``i`` of ``x`` and row ``j`` of ``y`` only,
+    so what an out-of-range row holds cannot reach an in-range entry."""
     m, d = x.shape
     n = y.shape[0]
     if out_dtype is None:
@@ -174,7 +188,7 @@ def cdist_tile(x, y, sqrt: bool = True, block_m: int = 256,
     xp = _pad_axis(_pad_axis(x, 0, mp), 1, dp)
     yp = _pad_axis(_pad_axis(y, 0, np_), 1, dp)
 
-    out = pl.pallas_call(
+    return pl.pallas_call(
         functools.partial(_cdist_kernel, sqrt=sqrt, acc_dtype=acc_dtype),
         grid=(mp // bm, np_ // bn),
         in_specs=[
@@ -182,11 +196,10 @@ def cdist_tile(x, y, sqrt: bool = True, block_m: int = 256,
             pl.BlockSpec((bn, dp), lambda i, j: (_i32(j), _i32(0))),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (_i32(i), _i32(j))),
-        out_shape=_sds((mp, np_), out_dtype, vma=_vma(xp, yp)),
+        out_shape=_sds((m, n), out_dtype, vma=_vma(xp, yp)),
         name="cdist_tile",
         interpret=_interpret(),
     )(xp, yp)
-    return out[:m, :n]
 
 
 # --------------------------------------------------------------------------- #

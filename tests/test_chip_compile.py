@@ -17,6 +17,8 @@ the test — the program has no option for it.
 
 import math
 import os
+import re
+import types
 
 import numpy as np
 import pytest
@@ -28,6 +30,8 @@ from jax.sharding import SingleDeviceSharding
 
 from heat_tpu.core import pallas_kernels as pk
 from heat_tpu.core._compat import shard_map
+from heat_tpu.core.communication import TPUCommunication
+from heat_tpu.spatial import distance
 
 
 @pytest.fixture(scope="module")
@@ -77,11 +81,41 @@ def test_topology_is_the_v5e(topo):
     assert topo.devices[0].device_kind == "TPU v5 lite"
 
 
+# (float32, 40000, 18): the benchmark's cdist cell (heat-cdist-40k.cdist),
+# rows no multiple of the 256 tile; (bfloat16, 5000, 64): ragged too, rows no
+# multiple of bfloat16's 16-row packing either
 @pytest.mark.parametrize("dtype,n,d", [(jnp.float32, 8192, 18),
-                                       (jnp.bfloat16, 8192, 64)])
+                                       (jnp.bfloat16, 8192, 64),
+                                       (jnp.float32, 40000, 18),
+                                       (jnp.bfloat16, 5000, 64)])
 def test_cdist_tile_compiles(on_chip, one_chip, dtype, n, d):
     x = jax.ShapeDtypeStruct((n, d), dtype, sharding=one_chip)
     _compiled_text(lambda a, b: pk.cdist_tile(a, b, sqrt=True), x, x)
+
+
+def test_cdist_job_writes_its_result_once(on_chip, topo):
+    """The cdist cell's own program (`spatial.distance._ring_kernel` on one
+    chip, 40,000 x 18 float32): the Mosaic kernel's output IS the result.
+    A `slice`, `copy` or `dynamic-update-slice` of the n x n matrix after it
+    is a second pass over 6.4 GB that costs more than the kernel did (PR 32's
+    trace: 19.85 ms against 16.78), and a padded result is 6.46 GB of
+    temporaries beside it."""
+    n = 40000
+    comm = TPUCommunication(devices=topo.devices[:1])
+    xs = jax.ShapeDtypeStruct((n, 18), jnp.float32,
+                              sharding=comm.sharding(2, 0))
+    x = types.SimpleNamespace(larray=xs, shape=xs.shape)  # what it reads of a DNDarray
+    fn = distance._ring_kernel(x, x, distance._euclidean_tile, True,
+                               jnp.dtype(jnp.float32), comm, ("euclidean",))
+    compiled = fn.lower(xs, xs).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    second_pass = [line.strip()[:120] for line in text.splitlines()
+                   if re.search(rf"= f32\[{n},{n}\]\S* "
+                                r"(slice|copy|dynamic-update-slice)\(", line)]
+    assert not second_pass, second_pass
+    assert re.search(rf"ROOT %cdist_tile\S* = f32\[{n},{n}\]", text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2 ** 20
 
 
 # (8, 16, 1024, 64): chip_smoke.py's train phase; (2, 16, 2048, 128): the
@@ -154,7 +188,6 @@ def test_flash_inside_check_vma_shard_map_over_four_chips(on_chip, topo):
 
 
 def _copy_sizes(compiled):
-    import re
 
     return [int(np.prod([int(d) for d in dims.split(",")]))
             for dims in re.findall(r"= \w+\[([\d,]+)\][^ ]* copy\(",
@@ -166,7 +199,6 @@ def _slice_fusions(compiled):
     names a fusion after what it holds: ``slice_bitcast_fusion``,
     ``bitcast_dynamic-update-slice_fusion``, ...): (name, elements of the
     output, elements of each operand, largest first)."""
-    import re
 
     text = compiled.as_text()
     entry = text[text.index("\nENTRY"):]

@@ -28,10 +28,16 @@ def force_pallas():
     pk.set_pallas(None)
 
 
+def _direct64(x, y):
+    """The direct form's SQUARED distances in float64, over the values the
+    kernel was handed (a bfloat16 input counts as what it holds)."""
+    x64 = np.asarray(x).astype(np.float64)
+    y64 = np.asarray(y).astype(np.float64)
+    return ((x64[:, None, :] - y64[None, :, :]) ** 2).sum(-1)
+
+
 def _ref_cdist(x, y):
-    return np.sqrt(
-        np.maximum(((x[:, None, :] - y[None, :, :]) ** 2).sum(-1), 0.0)
-    ).astype(np.float32)
+    return np.sqrt(_direct64(x, y)).astype(np.float32)
 
 
 def _ref_attention(q, k, v, causal=False):
@@ -44,15 +50,40 @@ def _ref_attention(q, k, v, causal=False):
     return np.asarray(jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(logits, -1), v))
 
 
+# against the 256 x 256 tile (rows in eights, columns in 128 lanes): the
+# first three are the oldest; then both axes ragged, m < 8, n < 128, one axis a
+# multiple and the other not (each way), several tiles with a ragged last
+# tile on both axes
+_CDIST_SHAPES = [(37, 53, 19), (128, 128, 64), (8, 300, 5), (300, 200, 18),
+                 (5, 700, 18), (520, 100, 18), (512, 300, 18), (300, 256, 18),
+                 (520, 700, 18)]
+# form: (input dtype, sqrt, out_dtype argument, result dtype, tolerance)
+_CDIST_FORMS = {
+    "float32": (jnp.float32, True, None, jnp.float32, 1e-4),
+    "squared": (jnp.float32, False, None, jnp.float32, 1e-3),
+    "bfloat16": (jnp.bfloat16, True, None, jnp.bfloat16, 1e-2),
+    # rbf's form: squared distances of bfloat16 rows, kept in float32
+    "rbf": (jnp.bfloat16, False, "float32", jnp.float32, 1e-3),
+}
+
+
 class TestCdistTile:
-    @pytest.mark.parametrize("shape", [(37, 53, 19), (128, 128, 64), (8, 300, 5)])
-    def test_matches_reference(self, shape):
+    @pytest.mark.parametrize("form", list(_CDIST_FORMS))
+    @pytest.mark.parametrize("shape", _CDIST_SHAPES, ids=str)
+    def test_matches_reference(self, shape, form):
+        """EVERY entry, the last row and column included: the result has
+        its own shape, so an edge tile's store must end where it ends."""
         m, n, d = shape
+        dtype, sqrt, out_dtype, res_dtype, tol = _CDIST_FORMS[form]
         rng = np.random.default_rng(0)
-        x = rng.standard_normal((m, d)).astype(np.float32)
-        y = rng.standard_normal((n, d)).astype(np.float32)
-        out = np.asarray(pk.cdist_tile(jnp.asarray(x), jnp.asarray(y)))
-        np.testing.assert_allclose(out, _ref_cdist(x, y), rtol=1e-4, atol=1e-4)
+        x = jnp.asarray(rng.standard_normal((m, d)), dtype)
+        y = jnp.asarray(rng.standard_normal((n, d)), dtype)
+        out = pk.cdist_tile(x, y, sqrt=sqrt, out_dtype=out_dtype)
+        assert out.shape == (m, n) and out.dtype == res_dtype
+        ref = _direct64(x, y)
+        np.testing.assert_allclose(
+            np.asarray(out.astype(jnp.float32), np.float64),
+            np.sqrt(ref) if sqrt else ref, rtol=tol, atol=tol)
 
     def test_squared(self):
         rng = np.random.default_rng(1)
@@ -68,6 +99,26 @@ class TestCdistTile:
         # compare squared distances: the expansion form's cancellation error
         # near zero is amplified unboundedly by the final sqrt
         np.testing.assert_allclose(d.numpy() ** 2, _ref_cdist(x, x) ** 2, rtol=1e-3, atol=1e-3)
+
+    @pytest.mark.parametrize("op", ["cdist", "rbf"])
+    def test_split_rows_not_a_multiple_of_the_tile(self, force_pallas, op):
+        """601 rows split over two devices (one, on a mesh of one): a
+        device's 301 rows are more than one 256-row tile and not two, and
+        the ring's padded last row (602) is cut from the result."""
+        comm = ht.get_comm()
+        sub = comm.Split(list(range(min(2, comm.size))))
+        rng = np.random.default_rng(3)
+        x = rng.standard_normal((601, 6)).astype(np.float32)
+        X = ht.array(x, split=0, comm=sub)
+        d2 = _direct64(x, x)
+        if op == "cdist":
+            got = ht.spatial.cdist(X, X, quadratic_expansion=True).numpy() ** 2
+            want = d2
+        else:
+            got = ht.spatial.rbf(X, X, sigma=2.0, quadratic_expansion=True).numpy()
+            want = np.exp(-d2 / 8.0)
+        assert got.shape == (601, 601) and got.dtype == np.float32
+        np.testing.assert_allclose(got, want, rtol=1e-3, atol=1e-3)
 
 
 class TestFlashAttention:
