@@ -1,8 +1,10 @@
 """Token mixers beside plain causal attention, as ``TransformerLM``'s
 per-layer pattern names them: a Mamba-1 selective state-space layer
 (arXiv:2312.00752), differential attention (arXiv:2410.05258) over a full, a
-windowed or a borrowed (cross) key/value set, and the gated memory unit of the
-decoder-hybrid-decoder (arXiv:2507.06607). Pure functions of a layer's
+windowed or a borrowed (cross) key/value set, the gated memory unit of the
+decoder-hybrid-decoder (arXiv:2507.06607), a Mamba-2 layer (arXiv:2405.21060:
+a scalar decay a head, the chunked state-space-dual form over a prompt) and
+plain grouped-query attention against a cache lane. Pure functions of a layer's
 parameters and its input, each in two forms: over a whole prompt (``*_prompt``:
 what the padded prefill runs, returning what the cache keeps) and for one token
 against what the cache holds (``*_step``).
@@ -10,12 +12,15 @@ against what the cache holds (``*_step``).
 Matrix products take ``compute_dtype`` operands and accumulate in float32; the
 softmax, the scan and its state are float32. The recurrent state is laid out
 ``(..., d_state, d_inner)`` and the convolution tail ``(..., d_conv - 1,
-d_inner)``: the wide axis last, so that a TPU tile holds no padding.
+d_inner)``: the wide axis last, so that a TPU tile holds no padding. A Mamba-2
+state is ``(..., heads, d_head, d_state)``, its 128-wide axis last for the same
+reason, and its tail spans the convolved channels (x, B and C together).
 
 Names inside the programs (``heat_tpu.utils.profiling.scope``) nest under the
 scopes the trace reduction already knows: ``attn.qkv/ssm.in``,
 ``attn.core/ssm.scan``, ``attn.core/ssm.step``, ``attn.core/attn.window``,
-``attn.core/attn.full``, ``attn.core/attn.cross``, ``attn.core/gmu``.
+``attn.core/attn.full``, ``attn.core/attn.cross``, ``attn.core/gmu``,
+``attn.core/attn.gqa``.
 """
 
 from __future__ import annotations
@@ -28,10 +33,11 @@ from jax import lax
 
 from ..utils.profiling import scope
 
-__all__ = ["layernorm", "mm", "diff_lambda", "diff_attention",
+__all__ = ["layernorm", "rmsnorm", "mm", "diff_lambda", "diff_attention",
            "diff_attention_lanes", "diff_heads", "diff_finish", "lanes",
            "window_mask", "window_attention_prompt", "ring_rows",
-           "mamba_in", "mamba_prompt", "mamba_step", "gmu"]
+           "mamba_in", "mamba_prompt", "mamba_step", "gmu",
+           "mamba2_in", "mamba2_prompt", "mamba2_step", "gqa_lanes"]
 
 F32 = jnp.float32
 
@@ -41,6 +47,12 @@ def layernorm(x, scale, bias, eps):
     mu = jnp.mean(xf, axis=-1, keepdims=True)
     var = jnp.mean(jnp.square(xf - mu), axis=-1, keepdims=True)
     return ((xf - mu) * lax.rsqrt(var + eps) * scale + bias).astype(x.dtype)
+
+
+def rmsnorm(x, scale, eps):
+    xf = x.astype(F32)
+    var = jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
+    return (xf * lax.rsqrt(var + eps) * scale).astype(x.dtype)
 
 
 def mm(a, w):
@@ -291,3 +303,147 @@ def gmu(p, u, memory):
         h = (memory.astype(F32) * gate).astype(u.dtype)
     with scope("attn.proj"):
         return mm(h, p["w2"])
+
+
+# ---------------------------------------------------------------------- #
+# Mamba-2                                                                #
+# ---------------------------------------------------------------------- #
+def mamba2_in(p, u, tail, d_state: int):
+    """Everything before the recurrence, for ``u`` (B, S, D) and the ``tail``
+    (B, K-1, d_inner + 2 d_state) of convolution inputs that came before it:
+    ``[z, xBC, dt] = u W_in``, the causal depthwise filter over x, B and C
+    TOGETHER, and the step size a head. One group: B and C (d_state wide) are
+    shared by all heads. Returns (xBC pre-convolution, x (B, S, H, P), z,
+    delta (B, S, H) float32, B, C (B, S, d_state) float32)."""
+    with scope("attn.qkv"), scope("ssm.in"):
+        di = p["w_out"].shape[0]
+        H = p["A_log"].shape[0]
+        zxd = jnp.dot(u, p["w_in"].astype(u.dtype), preferred_element_type=F32)
+        z = zxd[..., :di].astype(u.dtype)
+        xbc_in = zxd[..., di:di + di + 2 * d_state].astype(u.dtype)
+        delta = jax.nn.softplus(zxd[..., 2 * di + 2 * d_state:]
+                                + p["dt_bias"].astype(F32))
+        K = p["conv_w"].shape[0]
+        S = u.shape[1]
+        seq = jnp.concatenate([tail.astype(xbc_in.dtype), xbc_in], axis=1)
+        conv, filt = p["conv_b"].astype(F32), p["conv_w"].astype(F32)
+        for j in range(K):
+            conv = conv + seq[:, j:j + S].astype(F32) * filt[j]
+        xbc = jax.nn.silu(conv)
+        x = xbc[..., :di].astype(u.dtype).reshape(*u.shape[:2], H, di // H)
+        return (xbc_in, x, z, delta, xbc[..., di:di + d_state],
+                xbc[..., di + d_state:])
+
+
+def _mamba2_out(p, y, x, z, eps):
+    """y + D x a head, gated by silu(z), RMS-normed over the whole inner
+    width (one group), projected out."""
+    y = y + p["D_skip"].astype(F32)[:, None] * x.astype(F32)
+    y = y.reshape(*y.shape[:-2], -1) * jax.nn.silu(z.astype(F32))
+    with scope("attn.proj"):
+        return mm(rmsnorm(y, p["gnorm"].astype(F32), eps).astype(x.dtype),
+                  p["w_out"])
+
+
+def mamba2_prompt(p, u, n_valid, d_state: int, chunk: int, eps: float):
+    """The layer over a padded prompt ``u`` (B, S, D), from a zero state, in
+    the CHUNKED (state-space-dual) form: inside a chunk of ``chunk``
+    positions the decay-masked ``C B^T`` product, between chunks the carried
+    state (a scan over S / chunk states, not over S positions). A pad
+    position (>= ``n_valid``) has step size 0: it decays nothing and adds
+    nothing, so the state after the last chunk is the state after the last
+    valid position. The recurrence's products and the state are float32.
+    Returns (out (B, S, D), state (B, H, P, d_state) float32, the last K-1
+    valid inputs of the convolution (B, K-1, d_inner + 2 d_state))."""
+    B, S, _ = u.shape
+    K, width = p["conv_w"].shape
+    zero_tail = jnp.zeros((B, K - 1, width), u.dtype)
+    xbc_in, x, z, delta, Bm, Cm = mamba2_in(p, u, zero_tail, d_state)
+    H, P = x.shape[2:]
+    with scope("attn.core"), scope("ssm.scan"):
+        a = -jnp.exp(p["A_log"].astype(F32))                        # (H,)
+        live = (jnp.arange(S) < n_valid)[None, :, None]
+        dt = jnp.where(live, delta, 0.0)
+        Q = min(chunk, S)
+        pad = -S % Q
+        nc = (S + pad) // Q
+
+        def chunks(t):          # (B, S, ...) -> (B, nc, Q, ...), pad rows zero
+            t = jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2))
+            return t.reshape(B, nc, Q, *t.shape[2:])
+
+        dx = chunks(dt[..., None] * x.astype(F32))               # (B,nc,Q,H,P)
+        Bc, Cc = chunks(Bm), chunks(Cm)                          # (B,nc,Q,N)
+        cum = jnp.cumsum(jnp.moveaxis(chunks(dt * a), 2, 3), axis=-1)
+        # inside a chunk: y_i += sum_{j<=i} exp(cum_i - cum_j) (C_i.B_j) dx_j
+        seen = jnp.arange(Q)[:, None] >= jnp.arange(Q)[None, :]
+        decay = jnp.exp(jnp.where(seen, cum[..., :, None] - cum[..., None, :],
+                                  -jnp.inf))                     # (B,nc,H,Q,Q)
+        cb = jnp.einsum("bcin,bcjn->bcij", Cc, Bc)
+        y = jnp.einsum("bchij,bcjhp->bcihp", cb[:, :, None] * decay, dx)
+        # what a chunk adds to the state, decayed to the chunk's end
+        to_end = jnp.exp(cum[..., -1:] - cum)                    # (B,nc,H,Q)
+        added = jnp.einsum("bcjhp,bcjn->bchpn",
+                           dx * jnp.moveaxis(to_end, 2, 3)[..., None], Bc)
+        whole = jnp.exp(cum[..., -1])                            # (B,nc,H)
+
+        def carry_on(s, inp):
+            keep, add = inp
+            return keep[..., None, None] * s + add, s
+
+        s_end, before = lax.scan(
+            carry_on, jnp.zeros((B, H, P, d_state), F32),
+            (jnp.moveaxis(whole, 1, 0), jnp.moveaxis(added, 1, 0)))
+        # what the state a chunk starts from adds inside it
+        y = y + jnp.einsum("bcin,cbhpn->bcihp", Cc, before) \
+            * jnp.moveaxis(jnp.exp(cum), 2, 3)[..., None]
+        y = y.reshape(B, S + pad, H, P)[:, :S]
+        padded = jnp.concatenate([zero_tail, xbc_in], axis=1)
+        tail = lax.dynamic_slice_in_dim(padded, n_valid, K - 1, axis=1)
+    return _mamba2_out(p, y, x, z, eps), s_end, tail
+
+
+def mamba2_step(p, u, s, tail, d_state: int, eps: float):
+    """One token ``u`` (B, 1, D) against the state ``s`` (B, H, P, d_state)
+    and the convolution's ``tail``. Returns (out (B, 1, D), new state, new
+    tail)."""
+    xbc_in, x, z, delta, Bm, Cm = mamba2_in(p, u, tail, d_state)
+    with scope("attn.core"), scope("ssm.step"):
+        a = -jnp.exp(p["A_log"].astype(F32))
+        dt = delta[:, 0]                                         # (B, H)
+        dx = dt[..., None] * x[:, 0].astype(F32)                 # (B, H, P)
+        s = (jnp.exp(dt * a)[..., None, None] * s
+             + dx[..., None] * Bm[:, 0, None, None, :])
+        y = jnp.sum(s * Cm[:, 0, None, None, :], axis=-1)
+        new_tail = jnp.concatenate(
+            [tail[:, 1:], xbc_in.astype(tail.dtype)], axis=1)
+    return _mamba2_out(p, y[:, None], x, z, eps), s, new_tail
+
+
+# ---------------------------------------------------------------------- #
+# plain grouped-query attention                                          #
+# ---------------------------------------------------------------------- #
+def gqa_lanes(q, kl, vl, seen, scale: float):
+    """One query row a slot, ``q`` (B, 1, H, d), against cache lanes ``kl``,
+    ``vl`` (B, Sk, Hkv d) of which row r counts iff r < ``seen`` (B,); query
+    head h reads key/value head h // (H / Hkv); scores times ``scale``. As
+    :func:`diff_attention_lanes` the lanes are read AS THEY LIE, as two plain
+    matrix products a slot (the queries in a (Hkv d, H) matrix that is zero
+    outside each head's own key head's rows; of the (H, Hkv d) product with
+    the values each head keeps its own d columns): Hkv times the needed
+    FLOPs, nothing transposed or copied. Returns (B, 1, H, d) float32."""
+    B, _one, H, d = q.shape
+    Sk, Hkv = kl.shape[1], kl.shape[2] // d
+    own = (jnp.arange(H) // (H // Hkv))[None, :] == jnp.arange(Hkv)[:, None]
+    qm = jnp.where(own[None, :, None, :],
+                   jnp.swapaxes(q[:, 0], 1, 2)[:, None, :, :], 0)
+    s = jnp.einsum("bkx,bxh->bhk", kl, qm.reshape(B, Hkv * d, H),
+                   preferred_element_type=F32) * scale
+    s = jnp.where(jnp.arange(Sk)[None, None, :] < seen[:, None, None], s,
+                  -jnp.inf)
+    w = jax.nn.softmax(s, axis=-1)
+    full = jnp.einsum("bhk,bkx->bhx", w.astype(vl.dtype), vl,
+                      preferred_element_type=F32)
+    full = full.reshape(B, Hkv, H // Hkv, Hkv, d)
+    a = jnp.stack([full[:, g, :, g] for g in range(Hkv)], axis=1)
+    return a.reshape(B, 1, H, d)

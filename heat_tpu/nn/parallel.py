@@ -37,6 +37,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..utils.profiling import scope
+
 __all__ = [
     "column_parallel_dense",
     "row_parallel_dense",
@@ -45,6 +47,8 @@ __all__ = [
     "tp_attention_out",
     "switch_moe",
     "moe_capacity",
+    "routed_experts",
+    "gated_ffn",
     "pipeline_apply",
 ]
 
@@ -270,3 +274,98 @@ def pipeline_apply(stage_fn: Callable, stage_params, x_micro, *, axis: str):
     # broadcast the last stage's buffer to every pp rank
     mask = (stage == pp - 1).astype(out_buf.dtype)
     return lax.psum(out_buf * mask, axis)
+
+
+# --------------------------------------------------------------------- #
+# routed experts, a share of them held here (serving)                   #
+# --------------------------------------------------------------------- #
+
+def gated_ffn(u, w1, w2):
+    """``(silu(g) * p) W2`` with ``[g, p] = u W1``: operands in ``u``'s
+    dtype, accumulated in float32, the gate in float32."""
+    F = w2.shape[0]
+    gp = jnp.dot(u, w1.astype(u.dtype), preferred_element_type=jnp.float32)
+    h = (jax.nn.silu(gp[..., :F]) * gp[..., F:]).astype(u.dtype)
+    return jnp.dot(h, w2.astype(u.dtype),
+                   preferred_element_type=jnp.float32).astype(u.dtype)
+
+
+def _top_k_gates(logits, k: int):
+    """Each row's ``k`` largest logits: (their gates, a softmax over THOSE k;
+    their places)."""
+    top, chosen = lax.top_k(logits, k)
+    return jax.nn.softmax(top, axis=-1), chosen
+
+
+def routed_experts(u, w_r, w1, w2, *, k: int, held, valid=None, at=None):
+    """The routed half of an expert layer on a device that is TOLD which
+    experts it holds. ``u`` (T, D) tokens; ``w_r`` (D, E) the router over ALL
+    E experts; ``w1`` (count, D, 2F) and ``w2`` (count, F, D) the gated
+    experts ``held = (first, count)``, that is experts ``first .. first +
+    count - 1``.
+
+    Every token chooses its ``k`` largest router logits (float32) and gates
+    them by a softmax over THOSE k. Of the T k (token, expert) pairs, the
+    ones whose expert is held are sorted by expert and go through two grouped
+    matrix products (``lax.ragged_dot``: a group an expert, as many rows as
+    it was sent); each token sums its held pairs' results by their gates.
+    What the experts held elsewhere would add is left out: the result is
+    this device's PART of the layer. No capacity: a pair is never dropped,
+    whatever the imbalance; the shapes are static all the same (the pairs'
+    array has T k rows, the held ones first).
+
+    Returns (the part (T, D) in ``u``'s dtype, the count of pairs by held
+    expert (count,) int32). ``valid`` (T,) bool keeps tokens that are no
+    one's (a bucket's pad rows, a dead slot) out of the COUNT; they are
+    computed like any other.
+
+    ``at``: ``w1`` and ``w2`` are several layers' experts STACKED, (R, count,
+    ...), and this layer's are entry ``at`` (an int, or a scan's traced
+    index). The grouped products then take the whole stack as R count groups,
+    all empty but this layer's: they read the weights where they lie. A slice
+    handed to them would be copied out first, 0.7 GB a layer at the sizes
+    served (the compiler fuses a slice into a plain product, not into a
+    grouped one)."""
+    first, count = held
+    T, D = u.shape
+    F = w2.shape[-2]
+    with scope("moe.route"):
+        logits = jnp.dot(u, w_r.astype(u.dtype),
+                         preferred_element_type=jnp.float32)
+        gates, chosen = _top_k_gates(logits, k)                     # (T, k)
+        local = chosen - first
+        here = (local >= 0) & (local < count)
+        group = jnp.where(here, local, count).reshape(-1)          # (T k,)
+        order = jnp.argsort(group, stable=True)
+        sent = jax.nn.one_hot(group, count, dtype=jnp.int32)   # unheld: no 1
+        sizes = pairs = jnp.sum(sent, axis=0, dtype=jnp.int32)
+        if valid is not None:
+            pairs = jnp.sum(jnp.where(jnp.repeat(valid, k)[:, None], sent, 0),
+                            axis=0, dtype=jnp.int32)
+    with scope("moe.experts"):
+        if at is not None:
+            sizes = lax.dynamic_update_slice(
+                jnp.zeros(w1.shape[0] * count, jnp.int32), sizes,
+                (at * count,))
+            w1 = w1.reshape(-1, *w1.shape[2:])
+            w2 = w2.reshape(-1, *w2.shape[2:])
+        rows = jnp.take(u, order // k, axis=0)                     # (T k, D)
+        # the operands are in one dtype: nothing for a precision to split
+        # (the package's default "high" takes the grouped product off the
+        # TPU's own kernel and onto a masked product over every group)
+        gp = lax.ragged_dot(rows, w1.astype(u.dtype), sizes,
+                            precision=lax.Precision.DEFAULT,
+                            preferred_element_type=jnp.float32)
+        h = (jax.nn.silu(gp[:, :F]) * gp[:, F:]).astype(u.dtype)
+        out = lax.ragged_dot(h, w2.astype(u.dtype), sizes,
+                             precision=lax.Precision.DEFAULT,
+                             preferred_element_type=u.dtype)
+    with scope("moe.combine"):
+        # back into (token, choice) order; rows past the held groups hold
+        # whatever the grouped product left there and are masked, not scaled
+        back = jnp.zeros_like(order).at[order].set(
+            jnp.arange(T * k, dtype=order.dtype))
+        out = jnp.take(out, back, axis=0).reshape(T, k, D)
+        part = jnp.sum(jnp.where(here[..., None], out.astype(jnp.float32), 0.0)
+                       * gates[..., None], axis=1)
+    return part.astype(u.dtype), pairs

@@ -14,11 +14,15 @@ Dense-MLP models only (the decode grid's own restriction).
 
 :func:`pattern_logits` is the same kind of oracle for a model with a
 per-layer PATTERN (``TransformerLMConfig.pattern``: Mamba-1, window, full
-and cross differential attention, gated memory units; pre-norm LayerNorm, a
-gated SiLU MLP, no positional encoding, the head tied to the embedding): one
-full forward over a whole sequence, a ``lax.scan`` over positions for the
-state-space layers, every head pair written out. It shares nothing with
-``heat_tpu.nn.mixers`` but the parameter tree.
+and cross differential attention, gated memory units, Mamba-2, plain
+grouped-query attention; pre-norm LayerNorm or RMSNorm; a gated SiLU MLP or
+routed experts beside a shared one; the embedding, residual, attention and
+logit multipliers; no positional encoding, the head tied to the embedding):
+one full forward over a whole sequence, a ``lax.scan`` over positions for the
+state-space layers, every head pair written out, the held experts one after
+another under a mask. It shares nothing with ``heat_tpu.nn.mixers`` or
+``heat_tpu.nn.parallel`` but the parameter tree. :func:`pattern_routing` is
+the experts each position chose, layer by layer.
 """
 
 from __future__ import annotations
@@ -31,7 +35,8 @@ import jax
 import jax.numpy as jnp
 
 __all__ = ["host_params", "reference_logits", "reference_loss",
-           "greedy_gaps", "prefill_logits", "pattern_logits"]
+           "greedy_gaps", "prefill_logits", "pattern_logits",
+           "pattern_routing"]
 
 
 def host_params(params):
@@ -233,17 +238,99 @@ def _diff_attention(p, q, k, v, mask, layer, cfg, fp8):
     return _mul(jnp.concatenate(pairs, axis=-1), p["wo"], fp8) + p["bo"]
 
 
+def _mamba2(p, u, cfg, fp8):
+    """Mamba-2 over ``u`` (S, D): one group, a scalar decay a head, the
+    recurrence a position at a time."""
+    di, N, K, Hs = cfg.d_inner, cfg.d_state, cfg.d_conv, cfg.ssm_heads
+    S, P = u.shape[0], cfg.d_inner // cfg.ssm_heads
+    zxd = _mul(u, p["w_in"], fp8)
+    z, xbc, dt = zxd[:, :di], zxd[:, di:2 * di + 2 * N], zxd[:, 2 * di + 2 * N:]
+    xp = jnp.concatenate([jnp.zeros((K - 1, di + 2 * N)), xbc])
+    xbc = _silu(sum(xp[j:j + S] * p["conv_w"][j] for j in range(K))
+                + p["conv_b"])
+    x, Bm, Cm = xbc[:, :di].reshape(S, Hs, P), xbc[:, di:di + N], xbc[:, di + N:]
+    delta = jax.nn.softplus(dt + p["dt_bias"])               # (S, Hs)
+    a = -jnp.exp(p["A_log"])                                 # (Hs,)
+
+    def step(s, inp):                                        # s (Hs, P, N)
+        d_t, x_t, b_t, c_t = inp
+        s = (jnp.exp(d_t * a)[:, None, None] * s
+             + (d_t[:, None] * x_t)[:, :, None] * b_t[None, None, :])
+        return s, s @ c_t + p["D_skip"][:, None] * x_t
+
+    _, y = jax.lax.scan(step, jnp.zeros((Hs, P, N)), (delta, x, Bm, Cm))
+    y = y.reshape(S, di) * _silu(z)
+    y = y / jnp.sqrt(jnp.mean(y * y, axis=-1, keepdims=True)
+                     + cfg.norm_eps) * p["gnorm"]
+    return _mul(y, p["w_out"], fp8)
+
+
+def _gqa(p, u, cfg, fp8):
+    """Plain causal grouped-query attention over ``u`` (S, D), a head at a
+    time; scores times ``attention_multiplier``."""
+    S = u.shape[0]
+    H, Hkv, dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    qkv = _mul(u, p["wqkv"], fp8).reshape(S, H + 2 * Hkv, dh)
+    q, k, v = qkv[:, :H], qkv[:, H:H + Hkv], qkv[:, H + Hkv:]
+    bias = jnp.where(jnp.arange(S)[None, :] <= jnp.arange(S)[:, None], 0.0,
+                     -jnp.inf)
+    heads = []
+    for h in range(H):
+        g = h // (H // Hkv)
+        w = jax.nn.softmax(_mul(q[:, h], k[:, g].T, fp8)
+                           * cfg.attention_multiplier + bias, axis=-1)
+        heads.append(_mul(w, v[:, g], fp8))
+    return _mul(jnp.concatenate(heads, axis=-1), p["wo"], fp8)
+
+
+def _gated(u, w1, w2, fp8):
+    gp = _mul(u, w1, fp8)
+    F = gp.shape[1] // 2
+    return _mul(_silu(gp[:, :F]) * gp[:, F:], w2, fp8)
+
+
+def _experts(p, u, cfg, fp8):
+    """The HELD experts' part of the routed layer plus the shared expert, on
+    ``u`` (S, D): each position's k largest router logits, gates a softmax
+    over those k; the held experts one after another, each over every
+    position, kept where the position chose it. Returns (out, the experts
+    chosen (S, k))."""
+    first, count = cfg.experts_held
+    top, chosen = jax.lax.top_k(_mul(u, p["router"], fp8),
+                                cfg.experts_per_token)
+    gates = jax.nn.softmax(top, axis=-1)
+    out = jnp.zeros_like(u)
+    for e in range(count):
+        gate = jnp.sum(jnp.where(chosen == first + e, gates, 0.0), axis=-1)
+        out = out + gate[:, None] * _gated(u, p["we1"][e], p["we2"][e], fp8)
+    if cfg.d_shared:
+        out = out + _gated(u, p["ws1"], p["ws2"], fp8)
+    return out, chosen
+
+
 def _pattern_forward(hp, toks, cfg, fp8):
     S = toks.shape[0]
     H, Hkv, dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     t, s_ = jnp.arange(S)[:, None], jnp.arange(S)[None, :]
     causal = s_ <= t
-    h = hp["embed"][toks]
+
+    def norm(x, p, name):
+        if cfg.norm_kind == "rmsnorm":
+            return x / jnp.sqrt(jnp.mean(x * x, axis=-1, keepdims=True)
+                                + cfg.norm_eps) * p[name]
+        return _ln(x, p[name], p[name + "_b"], cfg.norm_eps)
+
+    h = cfg.embedding_multiplier * hp["embed"][toks]
     memory = full_k = full_v = None
+    routing = []
     for l, (kind, p) in enumerate(zip(cfg.pattern, hp["layers"])):
-        u = _ln(h, p["ln1"], p["ln1_b"], cfg.norm_eps)
+        u = norm(h, p, "ln1")
         if kind == "mamba":
             mixed, memory = _mamba(p, u, cfg, fp8)
+        elif kind == "mamba2":
+            mixed = _mamba2(p, u, cfg, fp8)
+        elif kind == "gqa":
+            mixed = _gqa(p, u, cfg, fp8)
         elif kind == "gmu":
             mixed = _mul(memory * _silu(_mul(u, p["w1"], fp8)), p["w2"], fp8)
         elif kind == "cross":
@@ -259,20 +346,34 @@ def _pattern_forward(hp, toks, cfg, fp8):
             else:
                 mask = causal & (s_ > t - cfg.window)
             mixed = _diff_attention(p, q, k, v, mask, l, cfg, fp8)
-        h = h + mixed
-        gu = _mul(_ln(h, p["ln2"], p["ln2_b"], cfg.norm_eps),
-                  p["w_gate_up"], fp8)
-        F = gu.shape[1] // 2
-        h = h + _mul(_silu(gu[:, :F]) * gu[:, F:], p["w_down"], fp8)
-    return _mul(_ln(h, hp["final_ln"], hp["final_ln_b"], cfg.norm_eps),
-                hp["embed"].T, fp8)
+        h = h + cfg.residual_multiplier * mixed
+        u = norm(h, p, "ln2")
+        if cfg.ffn[l] == "moe":
+            out, chosen = _experts(p, u, cfg, fp8)
+            routing.append(chosen)
+        else:
+            out = _gated(u, p["w_gate_up"], p["w_down"], fp8)
+        h = h + cfg.residual_multiplier * out
+    logits = _mul(norm(h, hp, "final_ln"), hp["embed"].T, fp8)
+    return logits / cfg.logits_scaling, routing
 
 
 def pattern_logits(hp, toks, cfg, fp8=False):
     """``(S,)`` int tokens of ONE sequence -> ``(S, vocab)`` float32 logits
     of a pattern model; ``hp`` is :func:`host_params` of its tree.
     ``fp8=True`` is the control: every matrix product's operands rounded to
-    float8."""
-    fn = jax.jit(lambda hp, toks: _pattern_forward(hp, toks, cfg, fp8))
+    float8. A model that holds a share of its experts (``experts_held``) is
+    given the same share here: what the other experts would add is left
+    out, and that partial result goes on to the next layer."""
+    fn = jax.jit(lambda hp, toks: _pattern_forward(hp, toks, cfg, fp8)[0])
     with jax.default_matmul_precision("highest"):
         return fn(hp, jnp.asarray(toks, jnp.int32))
+
+
+def pattern_routing(hp, toks, cfg):
+    """The experts (of ALL ``n_experts``) that each position of ``toks``
+    (S,) chose in each "moe" layer: (moe layers, S, experts_per_token) int."""
+    fn = jax.jit(lambda hp, toks: _pattern_forward(hp, toks, cfg, False)[1])
+    with jax.default_matmul_precision("highest"):
+        return np.stack([np.asarray(c) for c in
+                         fn(hp, jnp.asarray(toks, jnp.int32))])

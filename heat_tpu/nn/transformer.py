@@ -51,13 +51,19 @@ from ..utils.profiling import scope, span
 from . import mixers
 from .attention import (_ring_body, _ulysses_core, _zigzag_core,
                         local_attention, zigzag_layout, zigzag_unlayout)
-from .parallel import pipeline_apply, switch_moe
+from .parallel import (gated_ffn, pipeline_apply, routed_experts,
+                       switch_moe)
 
 __all__ = ["TransformerLM", "TransformerLMConfig", "MIXER_KINDS",
-           "sambay_pattern"]
+           "FFN_KINDS", "sambay_pattern"]
 
 # what a layer's token mixer may be (``TransformerLMConfig.pattern``)
-MIXER_KINDS = ("attn", "mamba", "window", "full", "cross", "gmu")
+MIXER_KINDS = ("attn", "mamba", "window", "full", "cross", "gmu", "mamba2",
+               "gqa")
+# the mixers that are differential attention (pairs of heads)
+DIFF_KINDS = ("window", "full", "cross")
+# what a pattern layer's feed-forward may be (``TransformerLMConfig.ffn``)
+FFN_KINDS = ("mlp", "moe")
 
 
 def sambay_pattern(n_layers: int) -> Tuple[str, ...]:
@@ -120,9 +126,10 @@ class TransformerLMConfig:
     # -- the per-layer pattern (None: every layer plain causal attention, a
     # GELU MLP and RMSNorm, what training and ``generate`` support). A
     # pattern is SERVED (``serve_transformer(..., decode=True)``): one mixer
-    # kind a layer out of MIXER_KINDS, pre-norm LayerNorm with bias, a gated
-    # SiLU MLP, differential attention over grouped key/value heads, no
-    # positional encoding, the head tied to the embedding.
+    # kind a layer out of MIXER_KINDS, pre-norm, no positional encoding, the
+    # head tied to the embedding. Its pieces are fields of their own, each
+    # with the first served pattern's choice as its default: LayerNorm with
+    # bias, a gated SiLU MLP in every layer, no multipliers.
     pattern: Optional[Tuple[str, ...]] = None
     n_kv_heads: Optional[int] = None    # default n_heads
     window: int = 0                     # rows a "window" layer sees and keeps
@@ -130,8 +137,27 @@ class TransformerLMConfig:
     d_state: int = 16
     d_conv: int = 4
     dt_rank: Optional[int] = None       # default ceil(d_model / 16)
-    norm_eps: float = 1e-5              # LayerNorm and the pairs' RMSNorm
+    norm_eps: float = 1e-5              # every norm of a pattern
     param_dtype: Any = jnp.float32      # what the matrices are HELD in
+    norm_kind: str = "layernorm"        # | "rmsnorm" (a scale, no bias)
+    ssm_heads: int = 0                  # "mamba2": heads of d_inner/ssm_heads,
+    ssm_chunk: int = 256                # each a scalar decay; a prompt's chunk
+    # one feed-forward kind a layer out of FFN_KINDS (None: "mlp" everywhere).
+    # "moe": ``experts_per_token`` of ``n_experts`` routed gated experts of
+    # width ``d_expert``, gates a softmax over the chosen, beside one shared
+    # gated expert of width ``d_shared`` (0: none). The device HOLDS experts
+    # ``experts_held = (first, count)`` (None: all) and computes their part.
+    ffn: Optional[Tuple[str, ...]] = None
+    n_experts: int = 0
+    experts_per_token: int = 1
+    d_expert: int = 0
+    d_shared: int = 0
+    experts_held: Optional[Tuple[int, int]] = None
+    embedding_multiplier: float = 1.0   # h0 = m * E[tok]
+    residual_multiplier: float = 1.0    # h += m * Mixer(..), h += m * FFN(..)
+    attention_multiplier: Optional[float] = None    # "gqa" scores' scale,
+    #                                     default 1 / sqrt(head_dim)
+    logits_scaling: float = 1.0         # logits = .. / m
 
     def __post_init__(self):
         if self.d_ff is None:
@@ -144,9 +170,19 @@ class TransformerLMConfig:
             self.d_inner = 2 * self.d_model
         if self.dt_rank is None:
             self.dt_rank = -(-self.d_model // 16)
+        if self.attention_multiplier is None:
+            self.attention_multiplier = 1.0 / math.sqrt(self.head_dim)
         if self.pattern is not None:
             self.pattern = tuple(self.pattern)
+            self.ffn = (tuple(self.ffn) if self.ffn is not None
+                        else ("mlp",) * len(self.pattern))
+            if self.experts_held is None:
+                self.experts_held = (0, self.n_experts)
+            self.experts_held = tuple(int(n) for n in self.experts_held)
             self._check_pattern()
+        elif self.ffn is not None:
+            raise ValueError("ffn names a pattern's feed-forward kinds: it "
+                             "needs a pattern")
         if self.attn_schedule not in ("ring", "zigzag", "ulysses"):
             raise ValueError(
                 f"attn_schedule must be 'ring', 'zigzag' or 'ulysses', got "
@@ -171,9 +207,47 @@ class TransformerLMConfig:
                 f"pattern kinds must be of {MIXER_KINDS[1:]}, got {unknown}")
         if self.moe_experts or self.rope:
             raise ValueError(
-                "a pattern has a gated dense MLP and no positional "
-                "encoding: moe_experts=0, rope=False")
-        if self.n_heads % 4 or self.n_heads != 2 * self.n_kv_heads:
+                "a pattern has a gated dense MLP (or, by `ffn`, routed "
+                "experts) and no positional encoding: moe_experts=0, "
+                "rope=False")
+        if self.norm_kind not in ("layernorm", "rmsnorm"):
+            raise ValueError(
+                f"norm_kind must be 'layernorm' or 'rmsnorm', got "
+                f"{self.norm_kind!r}")
+        if "gqa" in kinds and self.n_heads % self.n_kv_heads:
+            raise ValueError(
+                f"grouped-query attention shares a key/value head among "
+                f"n_heads / n_kv_heads query heads: {self.n_heads} is no "
+                f"multiple of {self.n_kv_heads}")
+        if "mamba2" in kinds and (self.ssm_heads < 1
+                                  or self.d_inner % self.ssm_heads):
+            raise ValueError(
+                f"a 'mamba2' layer needs ssm_heads >= 1 that divides d_inner "
+                f"({self.d_inner}), got {self.ssm_heads}")
+        if len(self.ffn) != self.n_layers:
+            raise ValueError(
+                f"ffn names {len(self.ffn)} layers, n_layers is "
+                f"{self.n_layers}")
+        unknown = sorted(set(self.ffn) - set(FFN_KINDS))
+        if unknown:
+            raise ValueError(
+                f"ffn kinds must be of {FFN_KINDS}, got {unknown}")
+        if "moe" in self.ffn:
+            E, (first, count) = self.n_experts, self.experts_held
+            if not 1 <= self.experts_per_token <= E:
+                raise ValueError(
+                    f"experts_per_token ({self.experts_per_token}) must lie "
+                    f"in 1..n_experts ({E})")
+            if first < 0 or count < 1 or first + count > E:
+                raise ValueError(
+                    f"experts_held {self.experts_held} (first, count) "
+                    f"reaches outside the {E} experts there are")
+            if self.d_expert < 1 or self.d_shared < 0:
+                raise ValueError(
+                    f"a 'moe' layer needs d_expert >= 1 and d_shared >= 0, "
+                    f"got {self.d_expert} and {self.d_shared}")
+        if set(kinds) & set(DIFF_KINDS) and (
+                self.n_heads % 4 or self.n_heads != 2 * self.n_kv_heads):
             raise ValueError(
                 "differential attention pairs adjacent heads and each pair "
                 "of pairs shares two key/value heads: n_heads must be a "
@@ -272,11 +346,21 @@ class TransformerLM:
         self.mesh_size = self.dcn * self.dp * self.pp * self.tp * self.sp
         # one mixer kind a layer; "attn" everywhere is the dense model
         self.kinds = c.pattern if c.pattern else ("attn",) * c.n_layers
+        # and one feed-forward kind (a pattern's; the dense model has its own)
+        self.ffn = c.ffn if c.pattern else ("mlp",) * c.n_layers
+        self.has_experts = "moe" in self.ffn
         # a pattern's layers by (first layer, period, repeats): its
         # parameters are stacked by repeat and a prompt's forward scans them
         # (the dense model's are stacked by stage: a layer a segment here)
-        self.segments = (_segments(self.kinds) if c.pattern else
+        self.segments = (_segments(tuple(zip(self.kinds, self.ffn)))
+                         if c.pattern else
                          tuple((l, 1, 1) for l in range(c.n_layers)))
+        if self.has_experts and (self.pp, self.tp) != (1, 1):
+            raise ValueError(
+                f"a 'moe' layer holds its experts {c.experts_held} whole on "
+                f"every device of the grid: it is served with pp = tp = 1 "
+                f"(experts under tp, or exchanged between devices, are not "
+                f"built), got pp={self.pp} tp={self.tp}")
         if c.pattern and (self.pp, self.tp, self.sp) != (1, 1, 1):
             raise ValueError(
                 f"pattern {self._pattern_name()} is served on dp-only grids "
@@ -320,17 +404,27 @@ class TransformerLM:
         layer's mixer kind has with a leading axis of ``repeats`` (entry i
         of dict j is layer ``first + i * period + j``;
         :meth:`layer_params` picks one, :meth:`stack_layers` builds them).
-        Matrices and their biases in ``param_dtype``; norm scales, the
-        state-space layer's ``A_log``, ``D_skip``, ``b_dt`` and the
-        attention layers' lambda vectors and pair-norm scale in float32."""
+        Matrices and their biases in ``param_dtype``; norm scales, a
+        state-space layer's ``A_log``, ``D_skip``, ``b_dt`` / ``dt_bias`` and
+        the differential layers' lambda vectors and pair-norm scale in
+        float32. A "moe" layer's routed experts are stacked ``(repeats,
+        count, ...)``: the ``count`` of ``experts_held`` alone, beside the
+        router over all ``n_experts``."""
         c = self.cfg
         D, F, H, Hkv, d = (c.d_model, c.d_ff, c.n_heads, c.n_kv_heads,
                            c.head_dim)
         di, N, K, R = c.d_inner, c.d_state, c.d_conv, c.dt_rank
         w, f = jnp.dtype(c.param_dtype), jnp.dtype(jnp.float32)
-        common = {"ln1": ((D,), f), "ln1_b": ((D,), f), "ln2": ((D,), f),
-                  "ln2_b": ((D,), f), "w_gate_up": ((D, 2 * F), w),
-                  "w_down": ((F, D), w)}
+        norms = {"ln1": ((D,), f), "ln2": ((D,), f)}
+        if c.norm_kind == "layernorm":
+            norms.update(ln1_b=((D,), f), ln2_b=((D,), f))
+        held, Fe, Fs = c.experts_held[1], c.d_expert, c.d_shared
+        ffn = {"mlp": {"w_gate_up": ((D, 2 * F), w), "w_down": ((F, D), w)},
+               "moe": {"router": ((D, c.n_experts), w),
+                       "we1": ((held, D, 2 * Fe), w),
+                       "we2": ((held, Fe, D), w)}}
+        if Fs:
+            ffn["moe"].update(ws1=((D, 2 * Fs), w), ws2=((Fs, D), w))
         attn = {"wo": ((H * d, D), w), "bo": ((D,), w), "lam": ((4, d), f),
                 "subln": ((2 * d,), f)}
         own = {
@@ -341,17 +435,28 @@ class TransformerLM:
                       "w_out": ((di, D), w)},
             "gmu": {"w1": ((D, di), w), "w2": ((di, D), w)},
             "cross": dict(attn, wq=((D, H * d), w), bq=((H * d,), w)),
+            "mamba2": {"w_in": ((D, 2 * di + 2 * N + c.ssm_heads), w),
+                       "conv_w": ((K, di + 2 * N), w),
+                       "conv_b": ((di + 2 * N,), w),
+                       "dt_bias": ((c.ssm_heads,), f),
+                       "A_log": ((c.ssm_heads,), f),
+                       "D_skip": ((c.ssm_heads,), f), "gnorm": ((di,), f),
+                       "w_out": ((di, D), w)},
+            "gqa": {"wqkv": ((D, (H + 2 * Hkv) * d), w),
+                    "wo": ((H * d, D), w)},
         }
         own["window"] = own["full"] = dict(
             attn, wqkv=((D, (H + 2 * Hkv) * d), w),
             bqkv=(((H + 2 * Hkv) * d,), w))
         tree = {"embed": ((c.vocab, D), w), "final_ln": ((D,), f),
-                "final_ln_b": ((D,), f),
                 "segments": [
                     [{n: ((reps,) + shape, dt) for n, (shape, dt) in
-                      dict(common, **own[self.kinds[first + j]]).items()}
+                      dict(norms, **ffn[self.ffn[first + j]],
+                           **own[self.kinds[first + j]]).items()}
                      for j in range(period)]
                     for first, period, reps in self.segments]}
+        if c.norm_kind == "layernorm":
+            tree["final_ln_b"] = ((D,), f)
         return jax.tree.map(lambda sd: jax.ShapeDtypeStruct(*sd), tree,
                             is_leaf=lambda sd: isinstance(sd, tuple))
 
@@ -447,20 +552,24 @@ class TransformerLM:
         make unstable: ``A_log`` = log(1..N) a channel, ``D_skip`` 1,
         ``b_dt`` the inverse softplus of a log-uniform step in [1e-3, 1e-1],
         the convolution's filter uniform within 1/sqrt(K); the lambda
-        vectors N(0, 0.1)."""
+        vectors N(0, 0.1). Mamba-2 (a scalar a head): ``A`` uniform in
+        [1, 16], ``dt_bias`` as ``b_dt``."""
         c = self.cfg
         rng = np.random.default_rng(seed)
 
         def leaf(path, sd):
             name = path[-1].key
-            if name in ("ln1", "ln2", "final_ln", "subln", "D_skip"):
+            if name in ("ln1", "ln2", "final_ln", "subln", "D_skip",
+                        "gnorm"):
                 a = np.ones(sd.shape)
             elif name.endswith("_b") or name in ("bo", "bq", "bqkv"):
                 a = np.zeros(sd.shape)
+            elif name == "A_log" and len(sd.shape) == 2:    # (repeats, heads)
+                a = np.log(rng.uniform(1.0, 16.0, sd.shape))
             elif name == "A_log":
                 a = np.broadcast_to(
                     np.log(np.arange(1, c.d_state + 1))[:, None], sd.shape)
-            elif name == "b_dt":
+            elif name in ("b_dt", "dt_bias"):
                 dt = np.exp(rng.uniform(np.log(1e-3), np.log(1e-1), sd.shape))
                 a = dt + np.log(-np.expm1(-dt))
             elif name == "conv_w":
@@ -634,12 +743,14 @@ class TransformerLM:
         """Final norm + unembed; logits upcast to f32 only after the GEMM —
         an f32 norm scale would push the largest matmul off the bf16 path."""
         c = self.cfg
-        if c.pattern:       # LayerNorm, the head tied to the embedding
-            h = mixers.layernorm(h, params["final_ln"], params["final_ln_b"],
-                                 c.norm_eps)
-            return jnp.einsum("bsd,vd->bsv", h,
-                              params["embed"].astype(c.compute_dtype),
-                              preferred_element_type=jnp.float32)
+        if c.pattern:       # the head tied to the embedding
+            h = self._pre_norm(params, "final_ln", h)
+            logits = jnp.einsum("bsd,vd->bsv", h,
+                                params["embed"].astype(c.compute_dtype),
+                                preferred_element_type=jnp.float32)
+            if c.logits_scaling != 1.0:
+                logits = logits / c.logits_scaling
+            return logits
         h = _rmsnorm(h, params["final_ln"].astype(c.compute_dtype))
         return (h @ params["unembed"].astype(c.compute_dtype)).astype(jnp.float32)
 
@@ -1055,8 +1166,10 @@ class TransformerLM:
 
     def check_decode_grid(self) -> None:
         """Decode is token-recurrent: a pipelined or sequence-sharded
-        layout would idle on the single live token, and MoE routing at
-        S=1 degenerates. Shared guard for generate() and DecodeEngine."""
+        layout would idle on the single live token, and Switch-MoE's
+        capacity routing at S=1 degenerates (a pattern's "moe" layers have
+        no capacity and are served). Shared guard for generate() and
+        DecodeEngine."""
         if self.pp != 1 or self.sp != 1:
             raise ValueError(
                 "generate requires a pp=1, sp=1 grid (token-recurrent "
@@ -1090,16 +1203,47 @@ class TransformerLM:
     # layer keeps between tokens is the model's to say (`cache_layout`):
     # the engine holds that tree, donates it, and never looks inside.
 
+    # a "moe" layer's routed experts: read by grouped products, which take a
+    # run's whole stack and the layer's place in it (`routed_experts`)
+    EXPERT_STACKS = ("we1", "we2")
+
     def _layer_picker(self, params):
-        """``pick(l)``: layer ``l``'s parameters in the dtype it computes
-        in. Out of a pattern's stacked ``segments`` that is a static slice,
-        which the products read in place."""
-        if self.cfg.pattern:
-            return partial(self.layer_params, params)
-        return partial(self._cast_params, self._stage_params(params))
+        """``pick(l)``: (layer ``l``'s parameters in the dtype it computes
+        in, its place in its run's stack). Out of a pattern's stacked
+        ``segments`` a leaf is a static slice, which a product reads in
+        place; the routed experts stay STACKED, ``(repeats, count, ...)``,
+        for the grouped products to read in place too."""
+        if not self.cfg.pattern:
+            stage = self._stage_params(params)
+            return lambda l: (self._cast_params(stage, l), None)
+
+        def pick(l):
+            for s, (first, period, reps) in enumerate(self.segments):
+                if l < first + period * reps:
+                    i, j = divmod(l - first, period)
+                    return {n: a if n in self.EXPERT_STACKS else a[i]
+                            for n, a in params["segments"][s][j].items()}, i
+            raise IndexError(l)
+
+        return pick
 
     def _pre_norm(self, p, name, x):
+        if self.cfg.norm_kind == "rmsnorm":
+            return mixers.rmsnorm(x, p[name], self.cfg.norm_eps)
         return mixers.layernorm(x, p[name], p[name + "_b"], self.cfg.norm_eps)
+
+    def _embed(self, params, toks):
+        c = self.cfg
+        with scope("embed"):
+            x = params["embed"][toks].astype(c.compute_dtype)
+            if c.pattern and c.embedding_multiplier != 1.0:
+                x = x * jnp.asarray(c.embedding_multiplier, x.dtype)
+            return x
+
+    def _residual(self, x, y):
+        """x + m y, m the residual multiplier (1: no product at all)."""
+        m = self.cfg.residual_multiplier
+        return x + y if m == 1.0 else x + jnp.asarray(m, y.dtype) * y
 
     @scope("mlp")
     def _gated_mlp_residual(self, p, x):
@@ -1109,7 +1253,51 @@ class TransformerLM:
         gu = mixers.mm(self._pre_norm(p, "ln2", x), p["w_gate_up"])
         h = (jax.nn.silu(gu[..., :F].astype(jnp.float32))
              * gu[..., F:].astype(jnp.float32)).astype(x.dtype)
-        return x + mixers.mm(h, p["w_down"])
+        return self._residual(x, mixers.mm(h, p["w_down"]))
+
+    @scope("moe")
+    def _experts_residual(self, p, x, counted, at=None):
+        """Pre-norm expert layer + residual: this device's part of the routed
+        experts (:func:`routed_experts`: top-k of all, the held ones
+        computed, no capacity) beside the shared expert, which every token
+        passes. ``counted`` (B, S) bool: the tokens whose pairs are counted;
+        ``at``: the layer's place in ``p``'s stacked experts (None: they are
+        its own). Returns (x, pairs by held expert (count,) int32)."""
+        c = self.cfg
+        B, S, D = x.shape
+        u = self._pre_norm(p, "ln2", x).reshape(B * S, D)
+        out, pairs = routed_experts(
+            u, p["router"], p["we1"], p["we2"], k=c.experts_per_token,
+            held=c.experts_held, valid=counted.reshape(B * S), at=at)
+        if c.d_shared:
+            with scope("moe.shared"):
+                out = out + gated_ffn(u, p["ws1"], p["ws2"])
+        return self._residual(x, out.reshape(B, S, D)), pairs
+
+    def _ffn_residual(self, ffn, p, x, carry, at=None):
+        """A layer's feed-forward by its kind ``ffn``; a "moe" layer adds the
+        pairs by held expert of the tokens ``carry["counted"]`` to
+        ``carry["pairs"]`` (:meth:`_fresh_carry`)."""
+        if ffn == "mlp":
+            return self._gated_mlp_residual(p, x), carry
+        x, pairs = self._experts_residual(p, x, carry["counted"], at)
+        return x, dict(carry, pairs=carry["pairs"] + pairs)
+
+    def _gqa_qkv(self, p, u):
+        """Query, key and value heads of ``u`` (B, S, D): one product, no
+        bias, no positions."""
+        c = self.cfg
+        H, Hkv = c.n_heads, c.n_kv_heads
+        with scope("attn.qkv"):
+            qkv = lax.optimization_barrier(mixers.mm(u, p["wqkv"]))
+            qkv = qkv.reshape(*u.shape[:2], -1, c.head_dim)
+            return qkv[:, :, :H], qkv[:, :, H:H + Hkv], qkv[:, :, H + Hkv:]
+
+    @scope("attn.proj")
+    def _gqa_out(self, p, a):
+        """The heads' outputs ``a`` (B, S, H, Dh) -> the layer's (B, S, D)."""
+        return mixers.mm(a.reshape(*a.shape[:2], -1).astype(
+            self.cfg.compute_dtype), p["wo"])
 
     def _diff_qkv(self, p, u, kind):
         """Query heads (and, but for a cross layer, this layer's own key and
@@ -1137,12 +1325,15 @@ class TransformerLM:
         with scope("attn.proj"):
             return mixers.mm(o, p["wo"]) + p["bo"].astype(dtype)
 
-    def _prompt_layer(self, kind, l, p_l, x, pos0, n_valid, carry, wire):
-        """Layer ``l`` (of ``kind``; the index may be traced: a scanned
-        segment's) over a padded prompt ``x`` (Bl, Sp, D) whose rows
-        >= ``n_valid`` are pad. Returns (x, what the cache keeps of it,
-        carry); ``carry`` hands later layers the last state-space output
-        (``m``) and the full layer's keys and values (``k``, ``v``)."""
+    def _prompt_layer(self, kind, ffn, l, p_l, x, pos0, n_valid, carry,
+                      wire, at=None):
+        """Layer ``l`` (mixer ``kind``, feed-forward ``ffn``; the index may be
+        traced: a scanned segment's) over a padded prompt ``x`` (Bl, Sp, D)
+        whose rows >= ``n_valid`` are pad. Returns (x, what the cache keeps
+        of it, carry); ``carry`` hands later layers the last state-space
+        output (``m``) and the full layer's keys and values (``k``, ``v``),
+        and sums the expert layers' routed pairs (``pairs``). ``at``: the
+        layer's place in ``p_l``'s stacked experts (None: they are its own)."""
         c, dtype = self.cfg, self.cfg.compute_dtype
         if kind == "attn":
             q, k, v = self._qkv(p_l, x, pos0)
@@ -1163,6 +1354,22 @@ class TransformerLM:
             carry = dict(carry, m=mem)
         elif kind == "gmu":
             mixed = mixers.gmu(p_l, u, carry["m"])
+        elif kind == "mamba2":
+            mixed, s_end, tail = mixers.mamba2_prompt(
+                p_l, u, n_valid, c.d_state, c.ssm_chunk, c.norm_eps)
+            kept = {"s": s_end, "conv": tail}
+        elif kind == "gqa":
+            q, k, v = self._gqa_qkv(p_l, u)
+            kept = {"k": mixers.lanes(k), "v": mixers.lanes(v)}
+            # causal flash attention, each key/value head under its queries
+            with scope("attn.core"), scope("attn.gqa"):
+                r = c.n_heads // c.n_kv_heads
+                a = jnp.moveaxis(local_attention(
+                    jnp.moveaxis(q, 2, 1),
+                    jnp.moveaxis(jnp.repeat(k, r, axis=2), 2, 1),
+                    jnp.moveaxis(jnp.repeat(v, r, axis=2), 2, 1),
+                    scale=c.attention_multiplier, causal=True), 1, 2)
+            mixed = self._gqa_out(p_l, a)
         else:
             q, k, v = self._diff_qkv(p_l, u, kind)
             if kind == "window":
@@ -1187,7 +1394,9 @@ class TransformerLM:
                         causal=True), 1, 2).astype(jnp.float32)
                     a = a.reshape(a.shape[0], a.shape[1], c.n_heads, -1)
             mixed = self._diff_out(p_l, a, l, dtype)
-        return self._gated_mlp_residual(p_l, x + mixed), kept, carry
+        x, carry = self._ffn_residual(ffn, p_l, self._residual(x, mixed),
+                                      carry, at)
+        return x, kept, carry
 
     def _prompt_segment(self, segment, stacked, x, pos0, n_valid, carry,
                         wire):
@@ -1197,6 +1406,7 @@ class TransformerLM:
         :meth:`_prompt_layer` does, ``kept`` a list in layer order."""
         first, period, reps = segment
         kinds = self.kinds[first:first + period]
+        ffns = self.ffn[first:first + period]
         c, (B, S, _) = self.cfg, x.shape
         # a scan's carry keeps one structure: what the period's layers hand
         # on is there from the start
@@ -1207,14 +1417,22 @@ class TransformerLM:
             z = jnp.zeros((B, S, c.n_kv_heads, c.head_dim), x.dtype)
             carry = dict({"k": z, "v": z}, **carry)
 
+        # the routed experts are not scanned over (a scan hands its body a
+        # COPY of each repeat's slice): the body takes the stack and `i`
+        whole = [{n: a for n, a in place.items() if n in self.EXPERT_STACKS}
+                 for place in stacked]
+        stacked = [{n: a for n, a in place.items() if n not in whole[j]}
+                   for j, place in enumerate(stacked)]
+
         def period_of(xc, inp):
             x, carry = xc
             i, p_i = inp
             kept = []
             for j, kind in enumerate(kinds):
                 x, kept_j, carry = self._prompt_layer(
-                    kind, first + i * period + j, p_i[j], x, pos0, n_valid,
-                    carry, wire)
+                    kind, ffns[j], first + i * period + j,
+                    dict(p_i[j], **whole[j]), x, pos0, n_valid, carry, wire,
+                    at=i)
                 kept.append(kept_j)
             return (x, carry), kept
 
@@ -1228,7 +1446,9 @@ class TransformerLM:
         rows >= ``n_valid`` (a traced scalar) being pad. Returns what each
         layer's cache keeps of the prompt (a list, one dict a layer, leaves
         (Bl, rows, ...); see :meth:`cache_layout`) and the f32 logits at
-        position ``n_valid - 1``. Causal attention never reads a later
+        position ``n_valid - 1``; a model with "moe" layers returns a third:
+        the valid rows' routed pairs by held expert, summed over its layers
+        ((count,) int32). Causal attention never reads a later
         column and the state-space scan stops at ``n_valid``, so valid rows
         are exactly the unpadded forward's; padded K/V rows carry garbage
         the caller must keep masked (col < upto) until its own decode
@@ -1236,15 +1456,16 @@ class TransformerLM:
         c = self.cfg
         pick = self._layer_picker(params)
         Sp = toks.shape[1]
-        with scope("embed"):
-            x = params["embed"][toks].astype(c.compute_dtype)
+        x = self._embed(params, toks)
         pos0 = jnp.arange(Sp)
-        kept, carry = [], {}
+        kept, carry = [], self._fresh_carry(
+            jnp.broadcast_to(pos0 < n_valid, toks.shape))
         for s, (first, period, reps) in enumerate(self.segments):
             if reps == 1:
+                p_l, at = pick(first)
                 x, kept_l, carry = self._prompt_layer(
-                    self.kinds[first], first, pick(first), x, pos0, n_valid,
-                    carry, wire)
+                    self.kinds[first], self.ffn[first], first, p_l, x, pos0,
+                    n_valid, carry, wire, at)
                 kept.append(kept_l)
             else:
                 x, kept_s, carry = self._prompt_segment(
@@ -1252,15 +1473,30 @@ class TransformerLM:
                     n_valid, carry, wire)
                 kept.extend(kept_s)
         h_last = lax.dynamic_slice_in_dim(x, n_valid - 1, 1, axis=1)
-        return kept, self._head(params, h_last)[:, 0]
+        logits = self._head(params, h_last)[:, 0]
+        if self.has_experts:
+            return kept, logits, carry["pairs"]
+        return kept, logits
 
-    def _step_layer(self, l, p_l, x, cache, pos, carry, wire):
+    def _fresh_carry(self, counted) -> dict:
+        """What a walk over the layers hands from layer to layer, before the
+        first: the expert layers' count of pairs by held expert, and the
+        tokens it is a count of (``counted`` (B, S) bool: not a bucket's pad
+        rows, not a dead slot)."""
+        if not self.has_experts:
+            return {}
+        return {"pairs": jnp.zeros(self.cfg.experts_held[1], jnp.int32),
+                "counted": counted}
+
+    def _step_layer(self, l, p_l, x, cache, pos, carry, wire, at=None):
         """Layer ``l`` on a single-token batch ``x`` (Bl, 1, D), every row
         at its own position ``pos`` (Bl,). Reads and writes only what its
         kind keeps: the dense layer row ``pos`` of its own lanes; a window
-        layer ring row ``pos mod window``; the full layer lane row ``pos``; a
-        state-space layer its state and convolution tail, in place; cross
-        and gated-memory layers nothing of their own."""
+        layer ring row ``pos mod window``; a full or grouped-query layer lane
+        row ``pos``; a state-space layer its state and convolution tail, in
+        place; cross and gated-memory layers nothing of their own. ``at``:
+        the layer's place in ``p_l``'s stacked experts (None: they are its
+        own)."""
         c, kind, dtype = self.cfg, self.kinds[l], self.cfg.compute_dtype
         (per_layer,) = cache
         mine = per_layer[l]
@@ -1292,6 +1528,23 @@ class TransformerLM:
             carry = dict(carry, m=mem)
         elif kind == "gmu":
             mixed = mixers.gmu(p_l, u, carry["m"])
+        elif kind == "mamba2":
+            mixed, s, tail = mixers.mamba2_step(
+                p_l, u, mine["s"], mine["conv"], c.d_state, c.norm_eps)
+            with scope("cache.write"), scope("state"):
+                mine = {"s": s, "conv": tail.astype(mine["conv"].dtype)}
+        elif kind == "gqa":
+            q, k, v = self._gqa_qkv(p_l, u)
+            rows = jnp.arange(x.shape[0])
+            with scope("cache.write"), scope("lane"):
+                mine = {"k": mine["k"].at[rows, pos].set(
+                            mixers.lanes(k)[:, 0].astype(mine["k"].dtype)),
+                        "v": mine["v"].at[rows, pos].set(
+                            mixers.lanes(v)[:, 0].astype(mine["v"].dtype))}
+            with scope("attn.core"), scope("attn.gqa"):
+                a = mixers.gqa_lanes(q, mine["k"], mine["v"], pos + 1,
+                                     c.attention_multiplier)
+            mixed = self._gqa_out(p_l, a)
         else:
             q, k, v = self._diff_qkv(p_l, u, kind)
             if kind == "cross":
@@ -1317,28 +1570,38 @@ class TransformerLM:
                 a = mixers.diff_attention_lanes(q, ck, cv, seen)
             mixed = self._diff_out(p_l, a, l, dtype)
         cache = (per_layer[:l] + [mine] + per_layer[l + 1:],)
-        return self._gated_mlp_residual(p_l, x + mixed), cache, carry
+        x, carry = self._ffn_residual(
+            self.ffn[l], p_l, self._residual(x, mixed), carry, at)
+        return x, cache, carry
 
-    def decode_step_logits(self, params, cache, toks, pos, wire=None):
+    def decode_step_logits(self, params, cache, toks, pos, wire=None,
+                           live=None):
         """One token a row: ``toks`` (Bl,) at positions ``pos`` (Bl,) against
         ``cache`` (:meth:`cache_layout`'s tuple of trees). Returns (f32 logits
-        (Bl, vocab), the cache with this token written)."""
+        (Bl, vocab), the cache with this token written); a model with "moe"
+        layers returns a third: the routed pairs by held expert of the rows
+        that are ``live`` ((Bl,) bool, default all), summed over its layers
+        ((count,) int32)."""
         c = self.cfg
         pick = self._layer_picker(params)
-        with scope("embed"):
-            x = params["embed"][toks].astype(c.compute_dtype)[:, None, :]
-        carry = {}
+        x = self._embed(params, toks)[:, None, :]
+        carry = self._fresh_carry(
+            jnp.ones(x.shape[:2], bool) if live is None else live[:, None])
         for l in range(c.n_layers):
+            p_l, at = pick(l)
             x, cache, carry = self._step_layer(
-                l, pick(l), x, cache, pos, carry, wire)
-        return self._head(params, x)[:, 0], cache
+                l, p_l, x, cache, pos, carry, wire, at)
+        logits = self._head(params, x)[:, 0]
+        if self.has_experts:
+            return logits, cache, carry["pairs"]
+        return logits, cache
 
     # what the cache holds, by the name `DecodeEngine.stats()` reports it
     # under: the dense layers' lanes (together the arena), a window layer's
     # ring, the full layer's lane, a state-space layer's state and
     # convolution tail
     CACHE_KINDS = {"attn": "arena", "window": "ring", "full": "lane",
-                   "mamba": "state"}
+                   "mamba": "state", "mamba2": "state", "gqa": "lane"}
 
     def cache_layout(self, slots: int, s_cap: int, dp_axes="dp"):
         """What a decode engine of ``slots`` lanes and ``s_cap`` positions
@@ -1351,8 +1614,11 @@ class TransformerLM:
         ring of ``window`` rows, the full layer a lane of ``s_cap`` rows (the
         cross layers read it and keep nothing), a state-space layer its
         float32 state ``(slots, d_state, d_inner)`` and convolution tail
-        ``(slots, d_conv - 1, d_inner)``, a gated memory unit nothing. A leaf
-        a layer is what lets a program write a row and read a lane where
+        ``(slots, d_conv - 1, d_inner)``, a gated memory unit nothing; a
+        Mamba-2 layer its float32 state ``(slots, heads, d_head, d_state)``
+        (the 128-wide axis last) and a tail over the convolved channels
+        ``(slots, d_conv - 1, d_inner + 2 d_state)``, a grouped-query layer a
+        lane of its own like the full layer's. A leaf a layer is what lets a program write a row and read a lane where
         they lie: a layer's lane inside one arena of all layers had to be
         sliced out and written back whole, every layer of every step."""
         c, dtype = self.cfg, jnp.dtype(self.cfg.compute_dtype)
@@ -1366,8 +1632,15 @@ class TransformerLM:
         per_kind = {
             "attn": {"k": lane, "v": lane},
             "window": kv(c.window), "full": kv(s_cap),
+            "gqa": kv(s_cap),
             "mamba": {"s": sds((slots, c.d_state, c.d_inner), jnp.float32),
                       "conv": sds((slots, c.d_conv - 1, c.d_inner), dtype)}}
+        if c.ssm_heads:
+            per_kind["mamba2"] = {
+                "s": sds((slots, c.ssm_heads, c.d_inner // c.ssm_heads,
+                          c.d_state), jnp.float32),
+                "conv": sds((slots, c.d_conv - 1, c.d_inner + 2 * c.d_state),
+                            dtype)}
         shapes = ([per_kind.get(kind, {}) for kind in self.kinds],)
         # a dense lane's heads go over tp; a pattern runs on dp-only grids
         spec_of = {"attn": P(dp_axes, None, "tp", None)}

@@ -17,9 +17,10 @@ persistent, device-resident KV cache:
   window-attention layer, ONE lane of ``S_cap`` rows for the full-attention
   layer (the cross layers read it), a float32 recurrent state and a
   convolution tail for a state-space layer, nothing for a gated memory
-  unit. A step writes one row a slot into each leaf and reads each leaf
-  once, where it lies; a prefill writes one slot's rows: on one device no
-  lane is copied, sliced out or written back. Plus per-slot position and
+  unit, a lane of its own for a grouped-query layer. A step writes one
+  row a slot into each leaf and reads each leaf once, where it lies; a
+  prefill writes one slot's rows: on one device no lane is copied, sliced
+  out or written back. Plus per-slot position and
   last-token vectors — all device-resident
   for the engine's lifetime. ``S_cap`` is a rung of the power-of-two
   sequence ladder (``TransformerLM.prompt_bucket``), and every prompt pads
@@ -28,6 +29,22 @@ persistent, device-resident KV cache:
   the prompt's scan starts from zero and overwrites the state and the tail
   whole (a freed slot's state is live garbage: it is not masked by a
   position the way stale K/V rows are).
+* **Experts.** A pattern's "moe" layers route every token over ALL experts
+  and compute the ones the device holds (``TransformerLM``'s
+  ``experts_held``), with no capacity. Both programs hand the count of
+  routed pairs by held expert back in the SAME vector as the sampled tokens
+  (one fetch a dispatch, as before); ``stats()`` sums them as
+  ``moe_pairs_total`` / ``moe_pairs_held`` / ``moe_pairs_by_expert``. A
+  model without such a layer has none of the three and its programs are
+  what they were.
+* **Log-probabilities.** ``DecodeConfig(logprobs=True)``: both programs
+  also take each sampled token's log-probability (float32, the softmax of
+  the step's own logits) and hand it back in that same one vector; a
+  request's are on its future as ``future.logprobs`` (one a generated
+  token) from the moment it is done. What a caller ranks answers by, and
+  what lets a judge hold the engine's OWN arithmetic, slot by slot, against
+  a reference where the tokens alone say little. Off (the default), the
+  programs are what they were.
 * **Exactly TWO executables per (bucket, codec) signature.** A bucketed
   PREFILL program (runs the padded prompt forward, writes what the cache
   keeps of it into a free slot, samples the first token) and ONE
@@ -111,6 +128,8 @@ class DecodeConfig:
     default_deadline_ms: Optional[float] = None
     temperature: float = 0.0        # 0 = greedy (the parity-checked mode)
     seed: int = 0                   # sampling stream (temperature > 0)
+    logprobs: bool = False          # each token's log-probability rides the
+    #                                 fetch with it -> ``future.logprobs``
 
     def __post_init__(self):
         if self.queue_limit < 1:
@@ -126,8 +145,8 @@ _SEQ = itertools.count()  # FIFO tiebreaker within a priority
 
 class _DecodeRequest:
     __slots__ = ("prompt", "max_new", "eos_id", "tenant", "priority", "seq",
-                 "enq_t", "deadline_t", "future", "generated", "slot",
-                 "span", "stage")
+                 "enq_t", "deadline_t", "future", "generated", "logprobs",
+                 "slot", "span", "stage")
 
     def __init__(self, prompt: np.ndarray, max_new: int,
                  eos_id: Optional[int], deadline_t: Optional[float],
@@ -142,6 +161,7 @@ class _DecodeRequest:
         self.deadline_t = deadline_t
         self.future = Future()
         self.generated: List[int] = []
+        self.logprobs: List[float] = []     # ``DecodeConfig.logprobs``
         self.slot = -1
         # profiling: the request's life (`decode.request`, token times as
         # its events) and the stage it is in (`decode.queue`, then
@@ -164,8 +184,9 @@ class DecodeEngine:
     model : TransformerLM
         A pp=1, sp=1 model without Switch-MoE (``check_decode_grid``): the
         dense model on any dp×tp grid, optionally with the leading dcn tier
-        axis, or a per-layer pattern of state-space, window, full, cross
-        and gated-memory mixers on a dp-only grid.
+        axis, or a per-layer pattern (state-space, window, full, cross,
+        grouped-query and gated-memory mixers; a gated MLP or routed experts)
+        on a dp-only grid.
     params : pytree
         The model's sharded parameters (``model.init`` / ``shard_params``);
         held in the configuration's ``param_dtype``.
@@ -225,13 +246,24 @@ class DecodeEngine:
         self._paused = False
         self._step_seq = 0
         self._prefill_seq = 0
-        # per-engine figures (process-wide serve.decode_* counters mirror)
+        # per-engine figures (process-wide serve.decode_* counters mirror).
+        # Prefills, steps and the tokens they gave change TOGETHER under
+        # `_count_lock`, and `stats()` reads them under it: with every slot
+        # live, a snapshot that held a step without its tokens (or the other
+        # way round) read more live slots than there are
+        self._count_lock = threading.Lock()
         self._prefills = 0
         self._prefill_tokens = 0
         self._state_resets = 0
         self._steps = 0
         self._tokens_out = 0
         self._fallbacks = 0
+        # a model with "moe" layers: pairs routed (all experts counted), and
+        # those that fell on the experts held here, by expert
+        self._moe_layers = model.ffn.count("moe")
+        self._moe_pairs = 0
+        self._moe_held = np.zeros(
+            c.experts_held[1] if self._moe_layers else 0, np.int64)
         self._occupancy = deque(maxlen=512)
         self._worker = threading.Thread(
             target=self._run, name=f"heat-decode-{name}", daemon=True)
@@ -249,7 +281,9 @@ class DecodeEngine:
         full int32 token sequence (prompt + generated — the
         ``generate()`` contract per request). Generation stops at
         ``max_new_tokens`` or on sampling ``eos_id`` (included in the
-        result). Raises the typed serve errors on shed/close."""
+        result). Under ``DecodeConfig(logprobs=True)`` the done future also
+        carries ``.logprobs``, float32, one a generated token. Raises the
+        typed serve errors on shed/close."""
         prompt = np.asarray(prompt, np.int32).reshape(-1)
         if prompt.size < 1:
             raise ValueError("prompt must have at least one token")
@@ -441,18 +475,26 @@ class DecodeEngine:
         tenant detail."""
         occ = list(self._occupancy)
         adm = self._admission
+        with self._count_lock:
+            prefills, steps, tokens_out = (self._prefills, self._steps,
+                                           self._tokens_out)
+        moe = {} if not self._moe_layers else {
+            "moe_pairs_total": self._moe_pairs,
+            "moe_pairs_held": int(self._moe_held.sum()),
+            "moe_pairs_by_expert": self._moe_held.tolist()}
         return {
+            **moe,
             "slots": self.slots,
             "live": self.live_slots,
             "queue_depth": len(self._q),
             "seq_bucket": self.S_cap,
             "occupancy": (sum(occ) / len(occ)) if occ else 0.0,
-            "prefills": self._prefills,
+            "prefills": prefills,
             "prefill_tokens": self._prefill_tokens,
             "state_resets": self._state_resets,
             "cache_bytes": dict(self._cache_bytes),
-            "decode_steps": self._steps,
-            "tokens_out": self._tokens_out,
+            "decode_steps": steps,
+            "tokens_out": tokens_out,
             "decode_fallbacks": self._fallbacks,
             "program_cache": self.program_cache.stats(),
             "tenants": adm.tenant_stats() if adm is not None else {},
@@ -512,6 +554,27 @@ class DecodeEngine:
             body, mesh=self.model.grid.mesh, in_specs=in_specs,
             out_specs=out_specs, check_vma=False), donate_argnums=donate)
 
+    def _packed(self, toks, logp, pairs, sharded):
+        """What the host fetches as ONE int32 vector: ``toks``, then their
+        log-probabilities (``logp``: a list of none or one, float32, bit for
+        bit), then the experts' ``pairs`` (alike). On a dp grid a step's are
+        a shard's own (``sharded``: gathered, summed); a prefill is computed
+        alike on all."""
+        per_slot = [toks.reshape(-1)] + [lax.bitcast_convert_type(
+            lp.reshape(-1), jnp.int32) for lp in logp]
+        if sharded and not self._one_device:
+            axes = self._dp_axes
+            per_slot = [lax.all_gather(v, axes, tiled=True) for v in per_slot]
+            pairs = [lax.psum(p, axes) for p in pairs]
+        return jnp.concatenate(per_slot + list(pairs))
+
+    @staticmethod
+    def _logprob_of(logits, chosen):
+        """log softmax(``logits``)[``chosen``] a row, float32."""
+        with scope("sample"):
+            at = jnp.take_along_axis(logits, chosen[..., None], -1)[..., 0]
+            return at - jax.nn.logsumexp(logits, axis=-1)
+
     def _dp_index(self):
         m = self.model
         if self._one_device:
@@ -540,10 +603,14 @@ class DecodeEngine:
     def _step_prog(self):
         """THE decode-step executable: (params, *cache, pos, live, toks,
         key) -> (*cache, pos', toks'), carries donated. One per (S_cap,
-        slots, temperature, codec-keys) signature."""
+        slots, temperature, codec-keys) signature. With ``logprobs`` or a
+        model with "moe" layers it returns one more, the vector the host
+        fetches (``_packed``): every slot's token, their log-probabilities,
+        the live slots' routed pairs by held expert."""
         wire = self._wire()
         temp = float(self.config.temperature)
-        key = ("decode_step", self.S_cap, self.slots, temp) + wire
+        want_lp = bool(self.config.logprobs)
+        key = ("decode_step", self.S_cap, self.slots, temp, want_lp) + wire
 
         def build():
             m = self.model
@@ -551,8 +618,8 @@ class DecodeEngine:
             def decode_step(params, *rest):
                 *cache, pos, live, toks, skey = rest
                 Bl = toks.shape[0]
-                logits, cache = m.decode_step_logits(
-                    params, tuple(cache), toks, pos, wire=wire)
+                logits, cache, *pairs = m.decode_step_logits(
+                    params, tuple(cache), toks, pos, wire=wire, live=live)
                 with scope("sample"):
                     if temp == 0.0:
                         nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
@@ -567,21 +634,29 @@ class DecodeEngine:
                 # on the same already-masked row every step)
                 toks2 = jnp.where(live, nxt, toks)
                 pos2 = pos + live.astype(jnp.int32)
-                return (*cache, pos2, toks2)
+                logp = [self._logprob_of(logits, nxt)] if want_lp else []
+                if not (pairs or logp):
+                    return (*cache, pos2, toks2)
+                return (*cache, pos2, toks2,
+                        self._packed(toks2, logp, pairs, sharded=True))
 
             cs, vs = self._cache_specs, self._vec_spec
             n = len(cs)
             return self._program(
                 decode_step,
                 (self.model.param_specs(), *cs, vs, vs, vs, P()),
-                (*cs, vs, vs), (*range(1, n + 2), n + 3))
+                (*cs, vs, vs) + (P(),) * (self.model.has_experts or want_lp),
+                (*range(1, n + 2), n + 3))
 
         return self.program_cache.get_custom(key, build)
 
     def _prefill_prog(self, Sp: int):
         """The bucketed prefill executable for prompt bucket ``Sp``:
         (params, *cache, pos, toks, prompt, n_valid, slot, key) ->
-        (*cache, pos', toks', first_token); carries donated.
+        (*cache, pos', toks', first_token); carries donated. With
+        ``logprobs`` or a model with "moe" layers the first token's
+        log-probability and the prompt's routed pairs by held expert stand
+        behind it, in one vector (``_packed``).
 
         The prompt rides replicated (every dp shard runs the forward,
         only the owning shard keeps the K/V write) and joins dispatch
@@ -597,14 +672,16 @@ class DecodeEngine:
         needs."""
         wire = self._wire()
         temp = float(self.config.temperature)
-        key = ("decode_prefill", Sp, self.S_cap, self.slots, temp) + wire
+        want_lp = bool(self.config.logprobs)
+        key = ("decode_prefill", Sp, self.S_cap, self.slots, temp,
+               want_lp) + wire
 
         def build():
             m = self.model
 
             def decode_prefill(params, *rest):
                 *cache, pos, toks, prompt, n_valid, slot, skey = rest
-                kept, logits = m.prefill(
+                kept, logits, *pairs = m.prefill(
                     params, prompt[None], n_valid, wire=wire)
                 with scope("sample"):
                     if temp == 0.0:
@@ -627,6 +704,9 @@ class DecodeEngine:
                 hit = ok & (jnp.arange(ls) == lc)
                 pos = jnp.where(hit, n_valid, pos)
                 toks = jnp.where(hit, first, toks)
+                logp = [self._logprob_of(logits[0], first)] if want_lp else []
+                if pairs or logp:
+                    first = self._packed(first, logp, pairs, sharded=False)
                 return (*cache, pos, toks, first)
 
             cs, vs = self._cache_specs, self._vec_spec
@@ -747,18 +827,30 @@ class DecodeEngine:
                        self._next_key(2 * self._prefill_seq + 1))
         *cache, self._pos, self._toks, first = out
         self._cache = tuple(cache)
+        first = self._fetch(first).reshape(-1)
+        n_lp = int(bool(self.config.logprobs))
         if record:
-            self._prefills += 1
             self._prefill_tokens += S0
             self._state_resets += "state" in self._cache_bytes
+            self._count_pairs(S0, first[1 + n_lp:])
+            with self._count_lock:      # the prefill and its one token
+                self._prefills += 1
+                self._tokens_out += 1
             _pm.inc("serve.decode_prefills")
-        return int(self._fetch(first))
+            _pm.inc("serve.decode_tokens_out")
+        return int(first[0]), first[1:1 + n_lp].view(np.float32).tolist()
+
+    def _count_pairs(self, tokens: int, held) -> None:
+        """``tokens`` tokens went through every "moe" layer; ``held``: their
+        pairs by held expert, as the program counted them."""
+        if self._moe_layers:
+            self._moe_pairs += (tokens * self._moe_layers
+                                * self.model.cfg.experts_per_token)
+            self._moe_held += held
 
     def _do_prefill(self, req: _DecodeRequest, slot: int) -> None:
-        from ..utils import metrics as _pm
-
         try:
-            first = self._dispatch_prefill(req.prompt, slot)
+            first, req.logprobs = self._dispatch_prefill(req.prompt, slot)
         except Exception as exc:
             # a failed prefill fails ITS request only; the slot stays
             # free and the engine (and every other lane) lives on
@@ -771,8 +863,6 @@ class DecodeEngine:
         req.stage.end()                 # granted until the first token
         req.span.event("token")
         req.generated = [first]
-        self._tokens_out += 1
-        _pm.inc("serve.decode_tokens_out")
         if req.max_new <= 1 or (req.eos_id is not None
                                 and first == req.eos_id):
             self._finish(slot, req)
@@ -813,30 +903,36 @@ class DecodeEngine:
             self._fallbacks += 1
             with jax.disable_jit():
                 out = prog(*args)
-        *cache, self._pos, toks2 = out
+        n = len(self._cache)
+        cache, (self._pos, self._toks, *packed) = out[:n], out[n:]
         self._cache = tuple(cache)
-        self._toks = toks2
+        got = self._fetch(packed[0] if packed else self._toks)
+        n_lp = self.slots * bool(self.config.logprobs)
         if record:
-            self._steps += 1
+            n_live = int(live.sum())
+            self._count_pairs(n_live, got[self.slots + n_lp:])
+            with self._count_lock:      # the step and its tokens
+                self._steps += 1
+                self._tokens_out += n_live
             _pm.inc("serve.decode_steps")
-        return self._fetch(toks2)
+            _pm.inc("serve.decode_tokens_out", n_live)
+        return got[:self.slots], got[self.slots:self.slots + n_lp].view(
+            np.float32)
 
     def _do_step(self) -> None:
-        from ..utils import metrics as _pm
-
         live = self._live.copy()
         n_live = int(live.sum())
         with span("decode.step", n_live=n_live):
-            toks_np = self._dispatch_step(live)
+            toks_np, logp_np = self._dispatch_step(live)
             self._occupancy.append(n_live / self.slots)
-            self._tokens_out += n_live
-            _pm.inc("serve.decode_tokens_out", n_live)
             for slot in np.nonzero(live)[0]:
                 req = self._slot_req[slot]
                 if req is None:
                     continue
                 t = int(toks_np[slot])
                 req.generated.append(t)
+                if logp_np.size:
+                    req.logprobs.append(float(logp_np[slot]))
                 req.span.event("token")
                 done = (len(req.generated) >= req.max_new
                         or (req.eos_id is not None and t == req.eos_id))
@@ -854,6 +950,8 @@ class DecodeEngine:
             if self._admission is not None:
                 self._admission.count(req.tenant, "completed")
             req.end_spans()
+            if self.config.logprobs:
+                req.future.logprobs = np.asarray(req.logprobs, np.float32)
             req.future.set_result(np.concatenate(
                 [req.prompt, np.asarray(req.generated, np.int32)]))
 

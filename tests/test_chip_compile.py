@@ -310,6 +310,96 @@ def test_pattern_prefill_at_published_widths_scans_its_runs(topo, on_chip):
     assert max(_copy_sizes(compiled)) < 64 * 4096 * 1280
 
 
+# the benchmark's expert cell (granite-4.0-h-small-d10e36.decode-rag-closed96):
+# one chip's share of granite-4.0-h-small at its published widths: layers 0-9,
+# experts 0-35 of 72 a layer, 64 slots of 4,096 positions, bfloat16
+_GRANITE_STATE = 128 * 64 * 128                 # one slot's state of a layer
+_GRANITE_LANE = 4096 * 8 * 128                  # one slot's K (or V) lane
+
+
+def _described_granite_engine(topo):
+    """(engine, params, cache, slot vector, live mask, ``on``) as
+    :func:`_described_pattern_engine`."""
+    import heat_tpu as ht
+    from heat_tpu.nn.transformer import TransformerLM, TransformerLMConfig
+
+    grid = ht.MeshGrid((1, 1, 1, 1), ("dp", "pp", "tp", "sp"),
+                       devices=topo.devices[:1])
+    model = TransformerLM(grid, TransformerLMConfig(
+        vocab=100352, d_model=4096, n_heads=32, n_kv_heads=8, n_layers=10,
+        rope=False, pattern=("mamba2",) * 5 + ("gqa",) + ("mamba2",) * 4,
+        ffn=("moe",) * 10, norm_kind="rmsnorm", d_inner=8192, d_state=128,
+        d_conv=4, ssm_heads=128, ssm_chunk=256, n_experts=72,
+        experts_per_token=10, d_expert=768, d_shared=1536,
+        experts_held=(0, 36), embedding_multiplier=12.0,
+        residual_multiplier=0.22, attention_multiplier=0.0078125,
+        logits_scaling=16.0, compute_dtype=jnp.bfloat16,
+        param_dtype=jnp.bfloat16))
+    slots, s_cap = 64, 4096
+    eng, on = _described_engine(model, slots, s_cap)
+    eng.config.logprobs = True      # as the cell serves: its judge reads them
+    assert eng._cache_bytes == {
+        "state": 9 * slots * (_GRANITE_STATE * 4 + 3 * 8448 * 2),
+        "lane": 2 * slots * _GRANITE_LANE * 2}          # 3.52 GB together
+    params = jax.tree.map(on, model.pattern_param_shapes())
+    assert sum(math.prod(a.shape) for a in jax.tree.leaves(params)) \
+        == 4962732672                                   # 4.96 B, 9.93 GB
+    cache = jax.tree.map(on, eng._cache_shapes, eng._cache_specs)
+    vec = on(jax.ShapeDtypeStruct((slots,), jnp.int32), P("dp"))
+    live = on(jax.ShapeDtypeStruct((slots,), jnp.bool_), P("dp"))
+    return eng, params, cache, vec, live, on
+
+
+def test_expert_decode_step_at_published_widths_reads_its_experts_in_place(
+        topo):
+    """The share's step program compiles for one v5e beside its 13.44 GB of
+    parameters and cache with next to no temporaries: the 20 grouped products
+    (`lax.ragged_dot`, two a layer) are the TPU's own kernel
+    (``tpu_custom_call``), not a masked product over every group, and read a
+    run's stacked experts where they lie (a slice of the stack handed to them
+    was copied out first: 0.45 GB a layer, 2.3 GB of temporaries); no state
+    and no lane is copied whole."""
+    eng, params, cache, vec, live, _on = _described_granite_engine(topo)
+    compiled = eng._step_prog().lower(
+        params, *cache, vec, live, vec,
+        jax.eval_shape(lambda: jax.random.key(0))).compile()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes < 13.5e9          # 9.93 GB + 3.52 GB
+    assert mem.alias_size_in_bytes > 3.51e9             # the cache, in place
+    assert mem.temp_size_in_bytes < 0.2e9
+    assert compiled.as_text().count("tpu_custom_call") >= 20
+    copies = _copy_sizes(compiled)
+    assert copies and max(copies) < min(_GRANITE_STATE, _GRANITE_LANE), \
+        max(copies)
+
+
+def test_expert_prefill_at_published_widths_fits_beside_the_cache(topo,
+                                                                  on_chip):
+    """The 2,048-token prefill program, the cell's largest bucket: arguments
+    and temporaries together stay under the chip's 17.18 GB (so 64 slots of
+    4,096 positions stay), the two runs of Mamba-2 layers are `while` loops
+    whose bodies take the stacked experts whole, the cache is updated in
+    place and nothing as large as a layer's lanes or states is copied."""
+    eng, params, cache, vec, _live, on = _described_granite_engine(topo)
+    assert eng.model.segments == ((0, 1, 5), (5, 1, 1), (6, 1, 4))
+    i32 = on(jax.ShapeDtypeStruct((), jnp.int32))
+    compiled = eng._prefill_prog(2048).lower(
+        params, *cache, vec, vec,
+        on(jax.ShapeDtypeStruct((2048,), jnp.int32)), i32, i32,
+        jax.eval_shape(lambda: jax.random.key(0))).compile()
+    text = compiled.as_text()
+    assert text.count(" while(") >= 2 and "tpu_custom_call" in text
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes > 3.51e9
+    assert mem.temp_size_in_bytes < 1.5e9
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16.0e9
+    assert max(_copy_sizes(compiled)) < 64 * min(_GRANITE_STATE,
+                                                 _GRANITE_LANE)
+    # one program a bucket, one step program
+    eng._step_prog()
+    assert eng.program_cache.stats()["compiles"] == 2
+
+
 # the benchmark's dense decode cell (pythia-1.4b-d8.decode-conv-closed48):
 # Pythia-1.4b's widths, 8 layers, 32 slots of 2,048 positions, a bfloat16
 # cache under float32 parameters

@@ -235,6 +235,29 @@ def test_engine_matches_generate_on_one_device_and_on_dp_tp(mesh, case):
 
 
 @pytest.mark.parametrize("mesh", MESHES)
+def test_logprobs_are_the_full_forwards_log_softmax_at_the_served_token(mesh):
+    """``DecodeConfig(logprobs=True)``: the same tokens as without it, and on
+    each done future one float32 a generated token, the log-softmax of the
+    logits its slot's step computed (against ``logits_fn``'s one forward over
+    the served sequence), with a second request live beside it."""
+    fx = _fx(mesh)
+    model = fx["model"]
+    sizes = ((9, 6), (4, 9), (20, 5))
+    with _engine(mesh, logprobs=True) as eng:
+        futs = [eng.submit(_prompt(500 + i, s0), mn)
+                for i, (s0, mn) in enumerate(sizes)]
+        outs = [f.result(120) for f in futs]
+    for i, ((s0, mn), fut, out) in enumerate(zip(sizes, futs, outs)):
+        np.testing.assert_array_equal(out, _ref(_prompt(500 + i, s0), mn, mesh))
+        logits = np.asarray(model.logits_fn()(
+            fx["params"], np.tile(out, (model.dp_world, 1))))[0, s0 - 1:-1]
+        want = np.asarray(jax.nn.log_softmax(logits.astype(np.float64), -1))[
+            np.arange(mn), out[s0:]]
+        assert fut.logprobs.dtype == np.float32
+        np.testing.assert_allclose(fut.logprobs, want, atol=1e-4)
+
+
+@pytest.mark.parametrize("mesh", MESHES)
 def test_programs_are_plain_jits_on_one_device_only(mesh):
     """On a mesh of ONE device the step and the prefill are plain ``jit``s
     (a ``shard_map`` of one shard computes the same and may copy donated
