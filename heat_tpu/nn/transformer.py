@@ -493,26 +493,61 @@ class TransformerLM:
             "stages": stages,
         }
 
+    # the dense QKV weights as a decode engine holds them: (pp, Ls, 3, H, D,
+    # Dh), each head's (D, Dh) matrix contiguous. Out of `wqkv`'s (D, 3, H,
+    # Dh) the product re-lays the whole stack to this every time a program
+    # runs (a parameter's layout is fixed at the program's edge)
+    HELD_QKV = "wqkv_ohdk"
+
+    @property
+    def _holds_a_copy(self) -> bool:
+        """A decode engine of this model holds a tree of its own making
+        (the dense model under a ``compute_dtype`` other than float32)."""
+        return not self.cfg.pattern and (
+            jnp.dtype(self.cfg.compute_dtype) != jnp.float32)
+
+    def serving_param_specs(self) -> Dict[str, Any]:
+        """:meth:`param_specs` of the tree :meth:`serving_params` returns."""
+        specs = self.param_specs()
+        if not self._holds_a_copy:
+            return specs
+        stages = dict(specs["stages"])
+        del stages["wqkv"]
+        stages[self.HELD_QKV] = P("pp", None, None, "tp", None, None)
+        return dict(specs, stages=stages)
+
     def serving_params(self, params) -> Dict[str, Any]:
-        """``params`` as a decode engine HOLDS them: the matrices in the
-        configuration's ``param_dtype``, so that a model stated in bfloat16
-        takes half the memory and its step casts nothing. Leaves that are
-        in that dtype already come back as they are (the float32 default:
-        the tree itself); any other floating leaf is cast, UP as well as
-        down: a dense model handed bfloat16 parameters under the float32
-        default is held in float32."""
+        """``params`` as a decode engine HOLDS them: what its step reads, so
+        that no program of the engine casts or re-lays a weight. For a
+        pattern that is its matrices in ``param_dtype`` beside float32 norm
+        scales and state-space vectors (:meth:`pattern_param_shapes`),
+        sharded as they came. For the dense model it is every floating leaf
+        in ``compute_dtype`` (what :meth:`_cast_params` would make of it in
+        every step) with ``wqkv`` as ``HELD_QKV``, placed by
+        :meth:`serving_param_specs`; under a float32 ``compute_dtype`` the
+        tree itself, whatever it holds. A tree that is held already comes
+        back as it is; the dense model's copy is made by ONE jitted program.
+        The caller keeps the masters."""
         if self.cfg.pattern:
             want = jax.tree.map(lambda sd: sd.dtype,
                                 self.pattern_param_shapes())
-        else:
-            dt = jnp.dtype(self.cfg.param_dtype)
-            want = jax.tree.map(
-                lambda a: dt if jnp.issubdtype(a.dtype, jnp.floating)
-                else a.dtype, params)
-        if all(a.dtype == d for a, d in zip(jax.tree.leaves(params),
-                                            jax.tree.leaves(want))):
+            if all(a.dtype == d for a, d in zip(jax.tree.leaves(params),
+                                                jax.tree.leaves(want))):
+                return params
+            return jax.tree.map(lambda a, d: a.astype(d), params, want)
+        if not self._holds_a_copy or self.HELD_QKV in params["stages"]:
             return params
-        return jax.tree.map(lambda a, d: a.astype(d), params, want)
+
+        def held(tree):
+            tree = self._cast_params(tree)
+            stages = dict(tree["stages"])
+            stages[self.HELD_QKV] = jnp.moveaxis(stages.pop("wqkv"), 2, 4)
+            return dict(tree, stages=stages)
+
+        return jax.jit(held, out_shardings=jax.tree.map(
+            lambda s: NamedSharding(self.grid.mesh, s),
+            self.serving_param_specs(),
+            is_leaf=lambda s: isinstance(s, P)))(params)
 
     def stack_layers(self, layer_of) -> list:
         """The ``segments`` of a pattern's parameter tree from
@@ -685,19 +720,27 @@ class TransformerLM:
         c = self.cfg
         if layer is not None:
             p = jax.tree.map(lambda a: a[layer], p)
-        if c.compute_dtype == jnp.float32:
+        dt = jnp.dtype(c.compute_dtype)
+        if dt == jnp.float32:
             return p
+        # a leaf that is held in `dt` already (a decode engine's tree,
+        # `serving_params`) is read as it lies
         return jax.tree.map(
-            lambda a: a.astype(c.compute_dtype)
-            if jnp.issubdtype(a.dtype, jnp.floating) else a, p)
+            lambda a: a.astype(dt) if a.dtype != dt
+            and jnp.issubdtype(a.dtype, jnp.floating) else a, p)
 
     @scope("attn.qkv")
     def _qkv(self, p, x, pos):
         """Pre-norm qkv projection for the local head subset, with rotary
-        rotation by the GLOBAL positions ``pos``."""
+        rotation by the GLOBAL positions ``pos``. The weights as ``init``
+        makes them, ``wqkv`` (D, 3, H, Dh), or as a decode engine holds
+        them (:meth:`serving_params`), ``HELD_QKV`` (3, H, D, Dh)."""
         c = self.cfg
         a_in = _rmsnorm(x, p["ln1"])
-        qkv = jnp.einsum("bsd,dohk->bsohk", a_in, p["wqkv"])
+        if "wqkv" in p:
+            qkv = jnp.einsum("bsd,dohk->bsohk", a_in, p["wqkv"])
+        else:
+            qkv = jnp.einsum("bsd,ohdk->bsohk", a_in, p[self.HELD_QKV])
         q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
         if c.rope:
             q = rope_apply(q, pos, c.rope_theta)

@@ -188,8 +188,11 @@ class DecodeEngine:
         grouped-query and gated-memory mixers; a gated MLP or routed experts)
         on a dp-only grid.
     params : pytree
-        The model's sharded parameters (``model.init`` / ``shard_params``);
-        held in the configuration's ``param_dtype``.
+        The model's sharded parameters (``model.init`` / ``shard_params``).
+        The engine holds ``model.serving_params(params)``: the tree as its
+        step reads it (dtype and, for the dense QKV weights, layout), made
+        once here, never in a program; ``params`` itself only where it is
+        that already. The masters stay the caller's.
     config : DecodeConfig, optional
     program_cache : ProgramCache, optional
         Counters aggregate under ``serve.program_*`` like every serving
@@ -205,6 +208,8 @@ class DecodeEngine:
         model.check_decode_grid()
         self.model = model
         self.params = model.serving_params(params)
+        self._param_bytes = sum(
+            int(a.nbytes) for a in jax.tree.leaves(self.params))
         self.config = config if config is not None else DecodeConfig()
         self.name = name
         self.program_cache = (program_cache if program_cache is not None
@@ -492,6 +497,7 @@ class DecodeEngine:
             "prefills": prefills,
             "prefill_tokens": self._prefill_tokens,
             "state_resets": self._state_resets,
+            "param_bytes": self._param_bytes,
             "cache_bytes": dict(self._cache_bytes),
             "decode_steps": steps,
             "tokens_out": tokens_out,
@@ -644,7 +650,7 @@ class DecodeEngine:
             n = len(cs)
             return self._program(
                 decode_step,
-                (self.model.param_specs(), *cs, vs, vs, vs, P()),
+                (self.model.serving_param_specs(), *cs, vs, vs, vs, P()),
                 (*cs, vs, vs) + (P(),) * (self.model.has_experts or want_lp),
                 (*range(1, n + 2), n + 3))
 
@@ -712,7 +718,8 @@ class DecodeEngine:
             cs, vs = self._cache_specs, self._vec_spec
             return self._program(
                 decode_prefill,
-                (self.model.param_specs(), *cs, vs, vs, P(), P(), P(), P()),
+                (self.model.serving_param_specs(), *cs, vs, vs, P(), P(), P(),
+                 P()),
                 (*cs, vs, vs, P()), tuple(range(1, len(cs) + 3)))
 
         return self.program_cache.get_custom(key, build)

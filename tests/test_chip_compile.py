@@ -194,6 +194,28 @@ def _copy_sizes(compiled):
                                    compiled.as_text())]
 
 
+def _passes(compiled, op):
+    """The ENTRY computation's passes over memory that are an ``op``: the
+    bare instructions and the fusions XLA names after an ``op`` they hold
+    (``convert.164``, ``convert_bitcast_fusion.1``, ``copy_bitcast_fusion``:
+    the names a trace of the chip shows): (name, elements of the largest
+    array it writes). An ``op`` in the body of a fusion named for something
+    else (a product that widens its operand in registers) is not one, nor
+    is a ``dynamic-update-slice`` fusion, which returns the array it updates
+    in place (``_assert_touches_no_lane`` holds those to a slot's rows)."""
+    text = compiled.as_text()
+    out = []
+    for name, types, opcode in re.findall(
+            r"\n\s+(?:ROOT )?(%[\w.\-]+) = (.*?) (fusion|copy|convert)\(",
+            text[text.index("\nENTRY"):]):
+        if opcode == op or (opcode == "fusion" and op in name
+                            and "update-slice" not in name):
+            out.append((name, max(
+                int(np.prod([int(d) for d in dims.split(",") if d]))
+                for dims in re.findall(r"\w+\[([\d,]*)\]", types))))
+    return out
+
+
 def _slice_fusions(compiled):
     """The ENTRY computation's fusions with ``slice`` in their name (XLA
     names a fusion after what it holds: ``slice_bitcast_fusion``,
@@ -402,16 +424,19 @@ def test_expert_prefill_at_published_widths_fits_beside_the_cache(topo,
 
 # the benchmark's dense decode cell (pythia-1.4b-d8.decode-conv-closed48):
 # Pythia-1.4b's widths, 8 layers, 32 slots of 2,048 positions, a bfloat16
-# cache under float32 parameters
+# cache beside the bfloat16 copy the engine makes of float32 parameters
 _DENSE = dict(vocab=50304, d_model=2048, n_heads=16, n_layers=8, d_ff=8192)
 _DENSE_SLOTS, _DENSE_S_CAP = 32, 2048
 _SLOT_LANE = _DENSE_S_CAP * 16 * 128            # one slot's rows of a layer
 _LANE = _DENSE_SLOTS * _SLOT_LANE               # one layer's K (or V) lane
+_DENSE_COMPILED: dict = {}                      # program -> its compile
 
 
 def _described_dense_engine(topo):
     """The dense engine at the decode cell's own size on one described v5e:
-    (engine, params, cache, slot vector, live mask, ``on``)."""
+    (engine, params, cache, slot vector, live mask, ``on``). ``params`` is
+    the tree the engine HOLDS: ``serving_params`` of the float32 shapes a
+    caller hands in."""
     import heat_tpu as ht
     from heat_tpu.nn.transformer import TransformerLM, TransformerLMConfig
 
@@ -428,13 +453,37 @@ def _described_dense_engine(topo):
               "stages": {"ln1": (1, L, D), "wqkv": (1, L, D, 3, H, D // H),
                          "wproj": (1, L, H, D // H, D), "ln2": (1, L, D),
                          "w_up": (1, L, D, F), "w_down": (1, L, F, D)}}
-    params = jax.tree.map(
-        lambda shape, spec: on(jax.ShapeDtypeStruct(shape, jnp.float32), spec),
-        shapes, model.param_specs(), is_leaf=lambda s: isinstance(s, tuple))
+    given = jax.tree.map(
+        lambda shape: jax.ShapeDtypeStruct(shape, jnp.float32), shapes,
+        is_leaf=lambda s: isinstance(s, tuple))
+    params = jax.tree.map(on, jax.eval_shape(model.serving_params, given),
+                          model.serving_param_specs())
+    assert params["stages"][model.HELD_QKV].shape == (1, L, 3, H, D, D // H)
+    assert {sd.dtype for sd in jax.tree.leaves(params)} == {
+        jnp.dtype(jnp.bfloat16)}
     cache = jax.tree.map(on, eng._cache_shapes, eng._cache_specs)
     vec = on(jax.ShapeDtypeStruct((_DENSE_SLOTS,), jnp.int32), P("dp"))
     live = on(jax.ShapeDtypeStruct((_DENSE_SLOTS,), jnp.bool_), P("dp"))
     return eng, params, cache, vec, live, on
+
+
+def _dense_compiled(topo, program):
+    """The engine's step (``program`` "step") or the prefill of a bucket,
+    compiled ONCE a module for one described v5e, over the held tree."""
+    if program not in _DENSE_COMPILED:
+        eng, params, cache, vec, live, on = _described_dense_engine(topo)
+        key = jax.eval_shape(lambda: jax.random.key(0))
+        if program == "step":
+            lowered = eng._step_prog().lower(
+                params, *cache, vec, live, vec, key)
+        else:
+            i32 = on(jax.ShapeDtypeStruct((), jnp.int32))
+            lowered = eng._prefill_prog(program).lower(
+                params, *cache, vec, vec,
+                on(jax.ShapeDtypeStruct((program,), jnp.int32)), i32, i32,
+                key)
+        _DENSE_COMPILED[program] = lowered.compile()
+    return _DENSE_COMPILED[program]
 
 
 def _assert_touches_no_lane(compiled):
@@ -455,38 +504,59 @@ def _assert_touches_no_lane(compiled):
             assert out < _LANE, (name, out)
 
 
-def test_dense_decode_step_at_the_cell_size_touches_no_lane(topo):
+def test_dense_decode_step_at_the_cell_size_touches_no_lane(topo, on_chip):
     """The dense step at the decode cell's size compiles for one v5e and
     writes ``slots`` rows a layer where they lie: before a layer's lanes were
     leaves of their own the step sliced every layer's lane out of a
     ``(layers, slots, S_cap, H, Dh)`` arena and wrote it back whole (32
     fusions of 0.8 ms, half the step: PERF.md section 6, PR 34)."""
-    eng, params, cache, vec, live, _on = _described_dense_engine(topo)
-    compiled = eng._step_prog().lower(
-        params, *cache, vec, live, vec,
-        jax.eval_shape(lambda: jax.random.key(0))).compile()
-    assert compiled.memory_analysis().argument_size_in_bytes < 6.8e9
+    compiled = _dense_compiled(topo, "step")
     _assert_touches_no_lane(compiled)
     # the row scatter is the only thing that returns a lane
     assert not [name for name, out, _ops in _slice_fusions(compiled)
                 if out >= _LANE]
 
 
+_WQKV = 8 * 2048 * 3 * 16 * 128                 # the stacked QKV weights
+
+
 @pytest.mark.parametrize("bucket", [1024, 32])
 def test_dense_prefill_at_the_cell_size_is_o_prompt(topo, on_chip, bucket):
     """The dense prefill programs of the cell's largest and smallest
     buckets: one slot's ``bucket`` rows a layer are written in place, and
-    but for the QKV weights (the per-step cast re-lays them: not the
-    cache's) no copy is larger than the prompt's own rows: a prefill is
-    O(prompt), not O(cache) (it took 36.9 ms whatever the prompt while each
-    of the two arenas was copied twice: PERF.md section 5, PR 32)."""
-    eng, params, cache, vec, _live, on = _described_dense_engine(topo)
-    i32 = on(jax.ShapeDtypeStruct((), jnp.int32))
-    compiled = eng._prefill_prog(bucket).lower(
-        params, *cache, vec, vec,
-        on(jax.ShapeDtypeStruct((bucket,), jnp.int32)), i32, i32,
-        jax.eval_shape(lambda: jax.random.key(0))).compile()
+    but for the QKV weights (a prompt of 128 tokens or more re-lays them
+    for its wide product: not the cache's) no copy is larger than the
+    prompt's own rows: a prefill is O(prompt), not O(cache) (it took
+    36.9 ms whatever the prompt while each of the two arenas was copied
+    twice: PERF.md section 5, PR 32)."""
+    compiled = _dense_compiled(topo, bucket)
     _assert_touches_no_lane(compiled)
-    wqkv = math.prod(params["stages"]["wqkv"].shape)
-    rest = [n for n in _copy_sizes(compiled) if n != wqkv]
+    rest = [n for n in _copy_sizes(compiled) if n != _WQKV]
     assert max(rest) <= 2 * bucket * 16 * 128 <= 2 * _SLOT_LANE, max(rest)
+
+
+@pytest.mark.parametrize("program", ["step", 1024, 32])
+def test_dense_programs_at_the_cell_size_cast_no_weight(topo, on_chip,
+                                                        program):
+    """The engine holds its weights as its step reads them
+    (``serving_params``: cast once when it is built, each head's QKV matrix
+    contiguous), so no program converts a matrix and the step re-lays none:
+    no pass of the optimised HLO named for a ``convert`` or a ``copy`` is as
+    large as the smallest stacked matrix, the step's temporaries are a few
+    megabytes, and it takes the held 1.22 GB, not 2.43 GB of float32 (of a
+    15 ms step 7.1 ms was that cast and re-lay, done again every step:
+    PERF.md section 6, PR 38). What stays: a prompt of 128 tokens or more
+    wants the QKV weights transposed for its wide product and copies them,
+    once a prefill."""
+    compiled = _dense_compiled(topo, program)
+    wproj = 8 * 2048 * 2048
+    assert _passes(compiled, "convert")
+    assert not [p for p in _passes(compiled, "convert") if p[1] >= wproj]
+    relaid = [n for n in _copy_sizes(compiled) if n >= wproj]
+    assert relaid == ([_WQKV] if program == 1024 else []), relaid
+    assert not [p for p in _passes(compiled, "copy")
+                if p[1] >= wproj and p[1] != _WQKV]
+    if program == "step":
+        mem = compiled.memory_analysis()
+        assert mem.argument_size_in_bytes < 5.6e9
+        assert mem.temp_size_in_bytes < 16 * 2 ** 20

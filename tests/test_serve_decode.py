@@ -32,6 +32,8 @@ import numpy as np
 import pytest
 
 import jax
+import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 import heat_tpu as ht
 from heat_tpu.core import fusion
@@ -49,10 +51,13 @@ _MEMO: dict = {}
 MESHES = ("one", "dp2tp2")
 
 
-def _fx(mesh="all"):
+def _fx(mesh="all", compute=jnp.float32):
     """Module-shared model/params/program-cache (§2b: one compile set a
-    mesh)."""
-    if mesh not in _MEMO:
+    mesh and ``compute_dtype``; the parameters are float32 under both)."""
+    key = mesh
+    if compute != jnp.float32:
+        key = (mesh, jnp.dtype(compute).name)
+    if key not in _MEMO:
         n = ht.get_comm().size
         if mesh == "all":
             tp = 2 if n % 2 == 0 else 1
@@ -65,12 +70,12 @@ def _fx(mesh="all"):
         grid = ht.MeshGrid((dp, 1, tp, 1), ("dp", "pp", "tp", "sp"),
                            devices=devices)
         cfg = TransformerLMConfig(vocab=29, d_model=32, n_heads=4,
-                                  n_layers=2, d_ff=64)
+                                  n_layers=2, d_ff=64, compute_dtype=compute)
         model = TransformerLM(grid, cfg)
-        _MEMO[mesh] = dict(model=model, params=model.init(11),
-                           cache=ProgramCache(name=f"decode-test-{mesh}"),
-                           refs={})
-    return _MEMO[mesh]
+        _MEMO[key] = dict(model=model, params=model.init(11),
+                          cache=ProgramCache(name=f"decode-test-{key}"),
+                          refs={})
+    return _MEMO[key]
 
 
 @pytest.fixture(scope="module", autouse=True)
@@ -81,8 +86,8 @@ def _drop_compiled_state():
     gc.collect()
 
 
-def _engine(mesh="all", **over):
-    fx = _fx(mesh)
+def _engine(mesh="all", compute=jnp.float32, **over):
+    fx = _fx(mesh, compute)
     kw = dict(slots=2 * fx["model"].dp_world, max_seq_len=64)
     kw.update(over)
     return DecodeEngine(fx["model"], fx["params"], DecodeConfig(**kw),
@@ -94,10 +99,10 @@ def _prompt(seed, s0):
     return rng.integers(0, _fx()["model"].cfg.vocab, (s0,)).astype(np.int32)
 
 
-def _ref(prompt, max_new, mesh="all"):
+def _ref(prompt, max_new, mesh="all", compute=jnp.float32):
     """generate()'s tokens for one request (memoized — the reference
     programs are the module's biggest compiles)."""
-    fx = _fx(mesh)
+    fx = _fx(mesh, compute)
     key = (prompt.tobytes(), int(max_new))
     if key not in fx["refs"]:
         B = fx["model"].dp_world
@@ -329,6 +334,126 @@ def test_the_degraded_step_serves_the_same_tokens(mesh):
     np.testing.assert_array_equal(pos, pos0)
     np.testing.assert_array_equal(toks, toks0)
     assert pos[2] == 3 + 1 and pos[3] == 0
+
+
+# --------------------------------------------------------------------- #
+# what the engine holds                                                 #
+# --------------------------------------------------------------------- #
+BF16 = jnp.bfloat16
+
+
+@pytest.mark.parametrize("mesh", MESHES)
+def test_engine_holds_a_compute_dtype_copy_with_the_callers_shardings(mesh):
+    """Float32 parameters under ``compute_dtype=bfloat16``: the engine holds
+    every floating leaf in bfloat16, sharded as the caller's (``wqkv`` with
+    each head's matrix contiguous, its heads over tp as before), reports
+    those bytes, and the caller's tree is untouched (the masters are its
+    own)."""
+    fx = _fx(mesh, BF16)
+    model, given = fx["model"], fx["params"]
+    with _engine(mesh, BF16) as eng:
+        want = dict(given, stages=dict(given["stages"]))
+        wqkv = want["stages"].pop("wqkv")
+        assert wqkv.sharding.spec == P("pp", None, None, None, "tp", None)
+        want["stages"][model.HELD_QKV] = jax.device_put(
+            jnp.moveaxis(wqkv, 2, 4), NamedSharding(
+                model.grid.mesh, P("pp", None, None, "tp", None, None)))
+        assert jax.tree.structure(eng.params) == jax.tree.structure(want)
+        for h, g in zip(jax.tree.leaves(eng.params), jax.tree.leaves(want)):
+            assert h.dtype == BF16 and g.dtype == jnp.float32
+            assert h.shape == g.shape and h.sharding == g.sharding
+            np.testing.assert_array_equal(np.asarray(h),
+                                          np.asarray(g.astype(BF16)))
+        assert eng.stats()["param_bytes"] == sum(
+            2 * g.size for g in jax.tree.leaves(given))
+        assert all(g.dtype == jnp.float32 for g in jax.tree.leaves(given))
+        # a tree that is held in the step's dtype already is held as it is
+        again = fx["model"].serving_params(eng.params)
+        assert again is eng.params
+
+
+@pytest.mark.parametrize("mesh", ("all",) + MESHES)
+def test_float32_compute_holds_the_callers_tree_itself(mesh):
+    fx = _fx(mesh)
+    assert fx["model"].serving_params(fx["params"]) is fx["params"]
+    with _engine(mesh) as eng:
+        assert eng.params is fx["params"]
+        assert eng.stats()["param_bytes"] == sum(
+            4 * a.size for a in jax.tree.leaves(fx["params"]))
+
+
+def _walked(fx, eng, params, prompt, n_out):
+    """Greedy tokens and their log-probabilities through the cache on
+    ``params``, the cast wherever that tree's dtypes put it: the bodies the
+    engine compiles, jitted bare (one device: they name no mesh axis), a
+    prefill stored into slot 0 and then one step of every slot a token."""
+    model, wire = fx["model"], eng._wire()
+
+    @jax.jit
+    def first(params, cache, prompt, n):
+        kept, logits = model.prefill(params, prompt[None], n, wire=wire)
+        return model.cache_store(cache, kept, jnp.int32(0),
+                                 jnp.bool_(True)), logits[0]
+
+    @jax.jit
+    def step(params, cache, toks, pos):
+        logits, cache = model.decode_step_logits(params, cache, toks, pos,
+                                                 wire=wire)
+        return cache, logits[0]
+
+    shapes, _specs, _bytes = model.cache_layout(eng.slots, eng.S_cap)
+    cache = jax.tree.map(lambda sd: jnp.zeros(sd.shape, sd.dtype), shapes)
+    n = len(prompt)
+    padded = np.zeros(model.serving_bucket(n), np.int32)
+    padded[:n] = prompt
+    cache, logits = first(params, cache, jnp.asarray(padded), jnp.int32(n))
+    toks, pos = np.zeros(eng.slots, np.int32), np.zeros(eng.slots, np.int32)
+    seq, logp = [], []
+    for i in range(n_out):
+        seq.append(int(jnp.argmax(logits)))
+        logp.append(np.asarray(eng._logprob_of(logits, jnp.int32(seq[-1]))))
+        toks[0], pos[0] = seq[-1], n + i
+        cache, logits = step(params, cache, jnp.asarray(toks),
+                             jnp.asarray(pos))
+    return np.asarray(seq, np.int32), np.asarray(logp, np.float32)
+
+
+@pytest.mark.parametrize("s0", (5, 11))
+def test_the_held_copy_computes_what_the_cast_inside_computed(s0):
+    """One rounding, done once: a prompt and 16 steps of the programs' own
+    bodies on the caller's float32 tree (``_cast_params`` and ``_head``
+    casting inside, as in every step before the engine held a copy) and on
+    the held bfloat16 tree give the same greedy tokens and the same
+    log-probabilities, bit for bit. The engine serves those tokens, and
+    those log-probabilities to the float32 rounding of a log-sum that its
+    program fuses with the step."""
+    fx = _fx("one", BF16)
+    prompt = _prompt(700 + s0, s0)
+    with _engine("one", BF16, logprobs=True) as eng:
+        fut = eng.submit(prompt, 17)
+        out = fut.result(120)
+        inside = _walked(fx, eng, fx["params"], prompt, 17)
+        once = _walked(fx, eng, eng.params, prompt, 17)
+    np.testing.assert_array_equal(once[0], inside[0])
+    np.testing.assert_array_equal(once[1], inside[1])
+    np.testing.assert_array_equal(out[s0:], inside[0])
+    np.testing.assert_allclose(fut.logprobs, inside[1], rtol=0, atol=1e-6)
+
+
+@pytest.mark.parametrize("mesh", ("all", "dp2tp2"))
+def test_the_held_copy_on_dp_tp_gives_generates_tokens(mesh):
+    """Under ``shard_map`` the held tree goes in where the float32 one did
+    (``param_specs()`` says nothing of dtypes): the engine's greedy tokens
+    are those of ``generate()``, one program over the caller's float32 tree
+    with the cast inside, across prompt buckets and slots."""
+    with _engine(mesh, BF16) as eng:
+        sizes = ((5, 17), (9, 6), (12, 9), (3, 12))
+        futs = [eng.submit(_prompt(800 + i, s0), mn)
+                for i, (s0, mn) in enumerate(sizes)]
+        for i, ((s0, mn), fut) in enumerate(zip(sizes, futs)):
+            np.testing.assert_array_equal(
+                fut.result(120), _ref(_prompt(800 + i, s0), mn, mesh, BF16))
+        assert eng.stats()["decode_fallbacks"] == 0
 
 
 def test_serve_holds_no_layer_math():
