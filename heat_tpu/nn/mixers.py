@@ -32,13 +32,17 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
+from ..core.lane_kernels import lane_attention
+from ..core.pallas_kernels import interpret_vma_hazard, pallas_enabled
 from ..utils.profiling import scope
 from .attention import local_attention
 
 __all__ = ["layernorm", "rmsnorm", "mm", "diff_lambda", "diff_attention",
-           "diff_attention_lanes", "diff_heads", "diff_finish", "lanes",
+           "diff_attention_lanes", "lanes_in_kernel", "diff_heads",
+           "diff_finish", "lanes",
            "window_mask", "window_attention_prompt", "ring_rows",
            "mamba_in", "mamba_prompt", "mamba_step", "gmu",
            "mamba2_in", "mamba2_prompt", "mamba2_step", "gqa_lanes",
@@ -112,17 +116,24 @@ def diff_attention(q, k, v, mask):
 def diff_attention_lanes(q, kl, vl, seen):
     """:func:`diff_attention` for the step: ``q`` (B, 1, H, d) against cache
     lanes ``kl``, ``vl`` (B, Sk, Hkv d) of which row r counts iff r <
-    ``seen`` (B,). The lanes are read AS THEY LIE, as two plain matrix
-    products a slot: every head's query sits in a (Hkv d, H) matrix that is
-    zero outside its own key head's rows, and of the (H, Hkv d) product with
-    the values each head keeps its own pair's 2d columns. That multiplies
-    zeros (Hkv times the needed FLOPs, still far under the time the lane's
-    bytes take) and in exchange nothing is transposed or copied: a product
-    batched over heads would make XLA re-lay the whole lane every step."""
+    ``seen`` (B,). Where the kernels run, :func:`lane_attention` reads each
+    slot's lanes only up to ``seen``. Else the lanes are read AS THEY LIE,
+    whole, as two plain matrix products a slot: every head's query sits in a
+    (Hkv d, H) matrix that is zero outside its own key head's rows, and of
+    the (H, Hkv d) product with the values each head keeps its own pair's 2d
+    columns. That multiplies zeros (Hkv times the needed FLOPs, still far
+    under the time the lane's bytes take) and in exchange nothing is
+    transposed or copied: a product batched over heads would make XLA re-lay
+    the whole lane every step."""
     B, _one, H, d = q.shape
     Sk, Hkv = kl.shape[1], kl.shape[2] // d
-    h = jnp.arange(H)
-    own = (2 * (h // 4) + h % 2)[None, :] == jnp.arange(Hkv)[:, None]
+    h = np.arange(H)
+    k_of = 2 * (h // 4) + h % 2
+    if lanes_in_kernel(q, kl, vl):
+        return lane_attention(
+            q[:, 0], kl, vl, seen, k_of=tuple(k_of.tolist()),
+            v_of=tuple((h // 4).tolist()), scale=1.0 / math.sqrt(d))[:, None]
+    own = k_of[None, :] == np.arange(Hkv)[:, None]
     qm = jnp.where(own[None, :, None, :],
                    jnp.swapaxes(q[:, 0], 1, 2)[:, None, :, :], 0)
     s = jnp.einsum("bkx,bxh->bhk", kl, qm.reshape(B, Hkv * d, H),
@@ -135,6 +146,14 @@ def diff_attention_lanes(q, kl, vl, seen):
     full = full.reshape(B, Hkv // 2, 4, Hkv // 2, 2 * d)
     a = jnp.stack([full[:, g, :, g] for g in range(Hkv // 2)], axis=1)
     return a.reshape(B, 1, H, 2 * d)
+
+
+def lanes_in_kernel(q, kl, vl) -> bool:
+    """Whether a step's attention over cache lanes runs as
+    :func:`lane_attention`: where the kernels run
+    (:func:`~heat_tpu.core.pallas_kernels.pallas_enabled`), but for operands
+    the interpreter cannot take (``interpret_vma_hazard``)."""
+    return pallas_enabled() and not interpret_vma_hazard(q, kl, vl)
 
 
 def diff_heads(k, v, n_heads: int):
@@ -432,14 +451,20 @@ def gqa_lanes(q, kl, vl, seen, scale: float):
     """One query row a slot, ``q`` (B, 1, H, d), against cache lanes ``kl``,
     ``vl`` (B, Sk, Hkv d) of which row r counts iff r < ``seen`` (B,); query
     head h reads key/value head h // (H / Hkv); scores times ``scale``. As
-    :func:`diff_attention_lanes` the lanes are read AS THEY LIE, as two plain
-    matrix products a slot (the queries in a (Hkv d, H) matrix that is zero
-    outside each head's own key head's rows; of the (H, Hkv d) product with
-    the values each head keeps its own d columns): Hkv times the needed
-    FLOPs, nothing transposed or copied. Returns (B, 1, H, d) float32."""
+    :func:`diff_attention_lanes`: where the kernels run, :func:`lane_attention`
+    reads each slot's lanes up to ``seen``; else the lanes are read AS THEY
+    LIE, whole, as two plain matrix products a slot (the queries in a (Hkv
+    d, H) matrix that is zero outside each head's own key head's rows; of the
+    (H, Hkv d) product with the values each head keeps its own d columns):
+    Hkv times the needed FLOPs, nothing transposed or copied. Returns (B, 1,
+    H, d) float32."""
     B, _one, H, d = q.shape
     Sk, Hkv = kl.shape[1], kl.shape[2] // d
-    own = (jnp.arange(H) // (H // Hkv))[None, :] == jnp.arange(Hkv)[:, None]
+    head_of = tuple((np.arange(H) // (H // Hkv)).tolist())
+    if lanes_in_kernel(q, kl, vl):
+        return lane_attention(q[:, 0], kl, vl, seen, k_of=head_of,
+                              v_of=head_of, scale=scale)[:, None]
+    own = np.array(head_of)[None, :] == np.arange(Hkv)[:, None]
     qm = jnp.where(own[None, :, None, :],
                    jnp.swapaxes(q[:, 0], 1, 2)[:, None, :, :], 0)
     s = jnp.einsum("bkx,bxh->bhk", kl, qm.reshape(B, Hkv * d, H),

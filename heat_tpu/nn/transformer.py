@@ -1294,17 +1294,29 @@ class TransformerLM:
                 "pattern), not Switch-MoE routing")
 
     @scope("attn.core")
-    def _attn_from_cache(self, q, ck, cv, upto):
-        """q (Bl, 1, Hs, Dh) against cached rows < ``upto`` (a scalar, or
-        a (Bl,) vector when every row is at its own decode depth — the
-        serving engine's per-slot live positions)."""
+    def _attn_from_cache(self, q, ck, cv, seen):
+        """q (Bl, 1, Hs, Dh) against the lanes ``ck``, ``cv`` (Bl, S, Hs
+        Dh), row r of slot b counted iff r < ``seen[b]``: the serving
+        engine's per-slot live positions. Where the kernels run,
+        :func:`lane_attention` reads each slot's rows up to ``seen`` alone;
+        else float32 products over the whole lanes. Either way the value
+        product keeps the float32 probabilities to 16 bits of mantissa
+        against the cache's values, as the package's ``high`` default does."""
+        Bl, _one, H, Dh = q.shape
+        scale = 1.0 / math.sqrt(self.cfg.head_dim)
+        if mixers.lanes_in_kernel(q, ck, cv):
+            heads = tuple(range(H))
+            out = mixers.lane_attention(q[:, 0], ck, cv, seen, k_of=heads,
+                                        v_of=heads, scale=scale,
+                                        precision=lax.Precision.HIGH)
+            return out[:, None].astype(q.dtype)
+        ck4, cv4 = (c.reshape(Bl, -1, H, Dh) for c in (ck, cv))
         s = jnp.einsum("bqhd,bshd->bhqs", q.astype(jnp.float32),
-                       ck.astype(jnp.float32)) / math.sqrt(self.cfg.head_dim)
+                       ck4.astype(jnp.float32)) * scale
         col = jnp.arange(ck.shape[1])[None, None, None, :]
-        lim = upto if jnp.ndim(upto) == 0 else upto[:, None, None, None]
-        s = jnp.where(col < lim, s, -jnp.inf)
+        s = jnp.where(col < seen[:, None, None, None], s, -jnp.inf)
         w = jax.nn.softmax(s, axis=-1)
-        out = jnp.einsum("bhqs,bshd->bqhd", w, cv.astype(jnp.float32))
+        out = jnp.einsum("bhqs,bshd->bqhd", w, cv4.astype(jnp.float32))
         return out.astype(q.dtype)
 
     # ------------------------------------------------------------- #
@@ -1505,7 +1517,8 @@ class TransformerLM:
         c, dtype = self.cfg, self.cfg.compute_dtype
         if kind == "attn":
             q, k, v = self._qkv(p_l, x, pos0)
-            kept = {"k": k.astype(dtype), "v": v.astype(dtype)}
+            kept = {"k": mixers.lanes(k).astype(dtype),
+                    "v": mixers.lanes(v).astype(dtype)}
             with scope("attn.core"):
                 attn = jnp.moveaxis(local_attention(
                     jnp.moveaxis(q, 2, 1), jnp.moveaxis(k, 2, 1),
@@ -1687,9 +1700,9 @@ class TransformerLM:
             q, k, v = self._qkv(p_l, x, pos[:, None])
             with scope("cache.write"):
                 ck = mine["k"].at[jnp.arange(Bl), pos].set(
-                    k[:, 0].astype(mine["k"].dtype))
+                    mixers.lanes(k)[:, 0].astype(mine["k"].dtype))
                 cv = mine["v"].at[jnp.arange(Bl), pos].set(
-                    v[:, 0].astype(mine["v"].dtype))
+                    mixers.lanes(v)[:, 0].astype(mine["v"].dtype))
             x = self._attn_residual(
                 p_l, x, self._attn_from_cache(q, ck, cv, pos + 1), wire=wire)
             x = self._dense_mlp_residual(p_l, x, wire=wire)
@@ -1798,11 +1811,12 @@ class TransformerLM:
         and donate; the matching ``PartitionSpec``s: slots over the
         data-parallel axes, heads over tp; bytes by kind). One list, a dict
         a layer, whatever the model: a dense layer its K and V lanes
-        ``(slots, s_cap, H, Dh)`` (together the "arena"), a window layer a
-        ring of ``window`` rows, the full layer a lane of ``s_cap`` rows (the
-        cross layers read it and keep nothing), a state-space layer its
-        float32 state ``(slots, d_state, d_inner)`` and convolution tail
-        ``(slots, d_conv - 1, d_inner)``, a gated memory unit nothing; a
+        ``(slots, s_cap, H Dh)``, a position a row with its heads side by
+        side as ``mixers.lanes`` lays them (together the "arena"), a window
+        layer a ring of ``window`` rows, the full layer a lane of ``s_cap``
+        rows (the cross layers read it and keep nothing), a state-space
+        layer its float32 state ``(slots, d_state, d_inner)`` and convolution
+        tail ``(slots, d_conv - 1, d_inner)``, a gated memory unit nothing; a
         Mamba-2 layer its float32 state ``(slots, heads, d_head, d_state)``
         (the 128-wide axis last) and a tail over the convolved channels
         ``(slots, d_conv - 1, d_inner + 2 d_state)``, a grouped-query layer a
@@ -1815,13 +1829,12 @@ class TransformerLM:
         c, dtype = self.cfg, jnp.dtype(self.cfg.compute_dtype)
         sds = jax.ShapeDtypeStruct
 
-        def kv(n_rows):     # a position a row: mixers.lanes
-            return {n: sds((slots, n_rows, c.n_kv_heads * c.head_dim),
-                           dtype) for n in ("k", "v")}
+        def kv(n_rows, heads=c.n_kv_heads):   # a position a row: mixers.lanes
+            return {n: sds((slots, n_rows, heads * c.head_dim), dtype)
+                    for n in ("k", "v")}
 
-        lane = sds((slots, s_cap, c.n_heads, c.head_dim), dtype)
         per_kind = {
-            "attn": {"k": lane, "v": lane},
+            "attn": kv(s_cap, c.n_heads),
             "window": kv(c.window), "full": kv(s_cap),
             "gqa": kv(s_cap),
             "mamba": {"s": sds((slots, c.d_state, c.d_inner), jnp.float32),
@@ -1836,8 +1849,9 @@ class TransformerLM:
                 "conv": sds((slots, c.d_conv - 1, c.d_inner + 2 * c.d_state),
                             dtype)}
         shapes = ([per_kind.get(kind, {}) for kind in self.kinds],)
-        # a dense lane's heads go over tp; a pattern runs on dp-only grids
-        spec_of = {"attn": P(dp_axes, None, "tp", None)}
+        # a dense lane's heads go over tp (a row's heads lie side by side);
+        # a pattern runs on dp-only grids
+        spec_of = {"attn": P(dp_axes, None, "tp")}
         specs = ([{n: spec_of.get(kind, P(dp_axes)) for n in tree}
                   for kind, tree in zip(self.kinds, shapes[0])],)
         nbytes = {}
@@ -1905,8 +1919,28 @@ class TransformerLM:
         if max_new_tokens < 1:
             raise ValueError(f"max_new_tokens must be >= 1, got {max_new_tokens}")
         Sb = self.prompt_bucket(S0)
-        S_max = Sb + max_new_tokens
+        fn = self._generate_prog(B, Sb, max_new_tokens, float(temperature))
+        dp_axes = ("dcn", "dp") if self._has_dcn else "dp"
+        padded = jnp.pad(prompts, ((0, 0), (0, Sb - S0)))
+        toks_sharded = jax.device_put(
+            padded, NamedSharding(self.grid.mesh, P(dp_axes, None)))
+        key = jax.random.key(seed)
+        gen = fn(params, toks_sharded, jnp.int32(S0), key)
+        return jnp.concatenate([jnp.asarray(prompts), jnp.asarray(gen)],
+                               axis=1)
 
+    def _generate_prog(self, B: int, Sb: int, max_new_tokens: int,
+                       temperature: float):
+        """:meth:`generate`'s one program for ``B`` prompts padded to the
+        bucket ``Sb``, made once a ``(B, Sb, max_new_tokens, temperature)``:
+        jitted ``(params, padded prompts, true length, key)`` -> the
+        ``max_new_tokens`` tokens, over a cache of ``Sb + max_new_tokens``
+        positions a row."""
+        cache_key = ("generate", B, Sb, max_new_tokens, temperature)
+        fn = self._step_cache.get(cache_key)
+        if fn is not None:
+            return fn
+        S_max = Sb + max_new_tokens
         dp_axes = ("dcn", "dp") if self._has_dcn else "dp"
         shapes, cache_specs, _ = self.cache_layout(B, S_max, dp_axes)
 
@@ -1954,28 +1988,18 @@ class TransformerLM:
                 [jnp.swapaxes(toks_out, 0, 1), last[:, None]], axis=1)
 
         data_spec = P(dp_axes, None)
-        cache_key = ("generate", B, Sb, max_new_tokens, float(temperature))
-        fn = self._step_cache.get(cache_key)
-        if fn is None:
-            sharded = shard_map(
-                decode, mesh=self.grid.mesh,
-                in_specs=(self.param_specs(), *cache_specs, data_spec, P(),
-                          P()),
-                out_specs=data_spec, check_vma=False)
+        sharded = shard_map(
+            decode, mesh=self.grid.mesh,
+            in_specs=(self.param_specs(), *cache_specs, data_spec, P(), P()),
+            out_specs=data_spec, check_vma=False)
 
-            @jax.jit
-            def generate(params, toks, n_valid, key):
-                # the tree `cache_layout` describes for B rows and S_max
-                # positions, zeroed: a temporary of this one program
-                cache = jax.tree.map(
-                    lambda sd: jnp.zeros(sd.shape, sd.dtype), shapes)
-                return sharded(params, *cache, toks, n_valid, key)
+        @jax.jit
+        def generate(params, toks, n_valid, key):
+            # the tree `cache_layout` describes for B rows and S_max
+            # positions, zeroed: a temporary of this one program
+            cache = jax.tree.map(
+                lambda sd: jnp.zeros(sd.shape, sd.dtype), shapes)
+            return sharded(params, *cache, toks, n_valid, key)
 
-            fn = self._step_cache[cache_key] = generate
-        padded = jnp.pad(prompts, ((0, 0), (0, Sb - S0)))
-        toks_sharded = jax.device_put(
-            padded, NamedSharding(self.grid.mesh, data_spec))
-        key = jax.random.key(seed)
-        gen = fn(params, toks_sharded, jnp.int32(S0), key)
-        return jnp.concatenate([jnp.asarray(prompts), jnp.asarray(gen)],
-                               axis=1)
+        fn = self._step_cache[cache_key] = generate
+        return fn

@@ -236,6 +236,44 @@ def _slice_fusions(compiled):
     return out
 
 
+def _lane_touches(compiled, *lanes):
+    """The ENTRY computation's instructions that take or give an array of one
+    of the ``lanes``' shapes (tuples of dims), but for parameters, bitcasts
+    and tuples: (name, op_name of their metadata). Where the step reads each
+    slot's lane only up to its position, they are ``lane_attention``'s calls
+    (under ``attn.core``) and the row writes (under ``cache.write``): no
+    copy, convert or fusion of a whole lane besides."""
+    text = compiled.as_text()
+    entry = text[text.index("\nENTRY"):]
+    want = {",".join(str(d) for d in lane) for lane in lanes}
+    shape_of = dict(re.findall(r"\n\s+(?:ROOT )?(%[\w.\-]+) = \w+\[([\d,]*)\]",
+                               entry))
+    out = []
+    for name, dims, op, args, rest in re.findall(
+            r"\n\s+(?:ROOT )?(%[\w.\-]+) = \w+\[([\d,]*)\]\S* "
+            r"([\w\-]+)\(([^)]*)\)([^\n]*)", entry):
+        if op in ("parameter", "bitcast", "tuple", "get-tuple-element"):
+            continue
+        if dims in want or want & {shape_of.get(a) for a in
+                                   re.findall(r"%[\w.\-]+", args)}:
+            meta = re.search(r'op_name="([^"]*)"', rest)
+            out.append((name, meta.group(1) if meta else ""))
+    return out
+
+
+def _assert_lanes_read_by_the_kernel(compiled, kernels, *lanes):
+    """``kernels`` calls of ``lane_attention``, each under ``attn.core``;
+    every other instruction that touches a lane writes rows under
+    ``cache.write``."""
+    touches = _lane_touches(compiled, *lanes)
+    calls = [m for n, m in touches if n.startswith("%lane_attention")]
+    assert len(calls) == kernels, calls
+    assert all("/attn.core/" in m and "lane_attention" in m for m in calls)
+    rest = [(n, m) for n, m in touches if not n.startswith("%lane_attention")]
+    assert rest and all("/cache.write" in m for _n, m in rest), \
+        [t for t in rest if "/cache.write" not in t[1]]
+
+
 def _described_engine(model, slots, s_cap):
     """A never-started decode engine of ``model`` on one described v5e (the
     constructor places its lanes; a described device holds nothing) and
@@ -289,7 +327,8 @@ def _described_pattern_engine(topo):
     return eng, params, cache, vec, live, on
 
 
-def test_pattern_decode_step_at_published_widths_copies_no_lane(topo):
+def test_pattern_decode_step_at_published_widths_copies_no_lane(topo,
+                                                                on_chip):
     """The served pattern's step program at Phi-4-mini-flash's widths
     compiles for one v5e, fits beside its 10.6 GB of parameters and cache,
     and its optimised HLO holds no copy as large as a ring: a scatter on a
@@ -298,7 +337,11 @@ def test_pattern_decode_step_at_published_widths_copies_no_lane(topo):
     (PERF.md section 6, PR 33). Nor one as large as the smallest weight
     matrix: the step reads static slices of the runs' stacked parameters
     where they lie (seen together with the split into heads, XLA re-laid
-    every QKV weight twice a step)."""
+    every QKV weight twice a step). The 8 rings, the full lane and its 7
+    readers are read by 16 calls of `lane_attention`, to each slot's
+    position; nothing else but the row writes touches a lane, and the
+    float32 score maps of the whole-lane form (64 x 40 x 4,096 x 4 B, 42 MB)
+    are gone from the temporaries (59.2 MB in the whole-lane form)."""
     eng, params, cache, vec, live, _on = _described_pattern_engine(topo)
     compiled = eng._step_prog().lower(
         params, *cache, vec, live, vec,
@@ -306,10 +349,12 @@ def test_pattern_decode_step_at_published_widths_copies_no_lane(topo):
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes < 10.7e9          # 7.71 GB + 2.89 GB
     assert mem.alias_size_in_bytes > 2.89e9             # the cache, in place
-    assert mem.temp_size_in_bytes < 0.5e9
+    assert mem.temp_size_in_bytes < 40e6
     copies = _copy_sizes(compiled)
     assert copies and max(copies) < min(64 * 512 * 1280, 2560 * 2560), \
         max(copies)
+    _assert_lanes_read_by_the_kernel(compiled, 16, (64, 512, 1280),
+                                     (64, 4096, 1280))
 
 
 def test_pattern_prefill_at_published_widths_scans_its_runs(topo, on_chip):
@@ -373,14 +418,15 @@ def _described_granite_engine(topo):
 
 
 def test_expert_decode_step_at_published_widths_reads_its_experts_in_place(
-        topo):
+        topo, on_chip):
     """The share's step program compiles for one v5e beside its 13.44 GB of
     parameters and cache with next to no temporaries: the 20 grouped products
     (`lax.ragged_dot`, two a layer) are the TPU's own kernel
     (``tpu_custom_call``), not a masked product over every group, and read a
     run's stacked experts where they lie (a slice of the stack handed to them
     was copied out first: 0.45 GB a layer, 2.3 GB of temporaries); no state
-    and no lane is copied whole."""
+    and no lane is copied whole, and the attention layer's lane is read by
+    `lane_attention` alone."""
     eng, params, cache, vec, live, _on = _described_granite_engine(topo)
     compiled = eng._step_prog().lower(
         params, *cache, vec, live, vec,
@@ -393,6 +439,8 @@ def test_expert_decode_step_at_published_widths_reads_its_experts_in_place(
     copies = _copy_sizes(compiled)
     assert copies and max(copies) < min(_GRANITE_STATE, _GRANITE_LANE), \
         max(copies)
+    # the one grouped-query layer reads its lane through `lane_attention`
+    _assert_lanes_read_by_the_kernel(compiled, 1, (64, 4096, 8 * 128))
 
 
 def test_expert_prefill_at_published_widths_fits_beside_the_cache(topo,
@@ -429,7 +477,23 @@ _DENSE = dict(vocab=50304, d_model=2048, n_heads=16, n_layers=8, d_ff=8192)
 _DENSE_SLOTS, _DENSE_S_CAP = 32, 2048
 _SLOT_LANE = _DENSE_S_CAP * 16 * 128            # one slot's rows of a layer
 _LANE = _DENSE_SLOTS * _SLOT_LANE               # one layer's K (or V) lane
+# a layer's lane as the cache holds it: a position a row, its heads side by
+# side
+_DENSE_LANE_SHAPE = (_DENSE_SLOTS, _DENSE_S_CAP, 16 * 128)
 _DENSE_COMPILED: dict = {}                      # program -> its compile
+
+
+def _dense_given():
+    """The float32 tree a caller of the dense model hands in, as shapes:
+    the dense `init`'s (it draws on the host: too slow to trace)."""
+    D, F, V, H, L = 2048, 8192, 50304, 16, 8
+    shapes = {"embed": (V, D), "final_ln": (D,), "unembed": (D, V),
+              "stages": {"ln1": (1, L, D), "wqkv": (1, L, D, 3, H, D // H),
+                         "wproj": (1, L, H, D // H, D), "ln2": (1, L, D),
+                         "w_up": (1, L, D, F), "w_down": (1, L, F, D)}}
+    return jax.tree.map(
+        lambda shape: jax.ShapeDtypeStruct(shape, jnp.float32), shapes,
+        is_leaf=lambda s: isinstance(s, tuple))
 
 
 def _described_dense_engine(topo):
@@ -447,17 +511,10 @@ def _described_dense_engine(topo):
     eng, on = _described_engine(model, _DENSE_SLOTS, _DENSE_S_CAP)
     assert eng._one_device
     assert eng._cache_bytes == {"arena": 4294967296}    # 16 lanes of 268 MB
-    # the dense `init`'s shapes (it draws on the host: too slow to trace)
-    D, F, V, H, L = 2048, 8192, 50304, 16, 8
-    shapes = {"embed": (V, D), "final_ln": (D,), "unembed": (D, V),
-              "stages": {"ln1": (1, L, D), "wqkv": (1, L, D, 3, H, D // H),
-                         "wproj": (1, L, H, D // H, D), "ln2": (1, L, D),
-                         "w_up": (1, L, D, F), "w_down": (1, L, F, D)}}
-    given = jax.tree.map(
-        lambda shape: jax.ShapeDtypeStruct(shape, jnp.float32), shapes,
-        is_leaf=lambda s: isinstance(s, tuple))
-    params = jax.tree.map(on, jax.eval_shape(model.serving_params, given),
+    params = jax.tree.map(on, jax.eval_shape(model.serving_params,
+                                             _dense_given()),
                           model.serving_param_specs())
+    D, H, L = 2048, 16, 8
     assert params["stages"][model.HELD_QKV].shape == (1, L, 3, H, D, D // H)
     assert {sd.dtype for sd in jax.tree.leaves(params)} == {
         jnp.dtype(jnp.bfloat16)}
@@ -515,24 +572,71 @@ def test_dense_decode_step_at_the_cell_size_touches_no_lane(topo, on_chip):
     # the row scatter is the only thing that returns a lane
     assert not [name for name, out, _ops in _slice_fusions(compiled)
                 if out >= _LANE]
+    # and `lane_attention` the only thing besides that reads one: the 16
+    # whole-lane fusions of 0.36 ms each (80.8% of the step, PR 38) are gone,
+    # with their float32 scores (2.1 MB of temporaries in that form)
+    _assert_lanes_read_by_the_kernel(compiled, 8, _DENSE_LANE_SHAPE)
+    assert compiled.memory_analysis().temp_size_in_bytes < 2.0e6
+
+
+def test_dense_generate_with_lanes_no_multiple_of_128_compiles(topo,
+                                                                on_chip):
+    """``generate()`` sizes its lanes to the prompt's bucket plus the tokens
+    asked for: a prompt of 1,000 tokens and 1,000 new ones make lanes of
+    1,024 + 1,000 = 2,024 rows, which no multiple of 128 divides. Its one
+    program (the prefill and the scan of steps) compiles for one v5e at the
+    dense cell's widths, each step reading the lanes through
+    `lane_attention` in 256-row blocks, the last partial: a single block of
+    the whole lane (2,024 x 2,048 bfloat16 for K and for V, double-buffered)
+    asks for 24.75 MB of the 16 MB of scoped VMEM, and the chip refuses it."""
+    import heat_tpu as ht
+    from heat_tpu.nn.transformer import TransformerLM, TransformerLMConfig
+
+    grid = ht.MeshGrid((1, 1, 1, 1), ("dp", "pp", "tp", "sp"),
+                       devices=topo.devices[:1])
+    model = TransformerLM(grid, TransformerLMConfig(
+        compute_dtype=jnp.bfloat16, **_DENSE))
+    Sb = model.prompt_bucket(1000)
+    assert Sb == 1024
+    lanes = model.cache_layout(1, Sb + 1000)[0][0][0]
+    assert lanes["k"].shape == (1, 2024, 16 * 128)
+
+    def on(sd, spec=P()):
+        return jax.ShapeDtypeStruct(
+            sd.shape, sd.dtype, sharding=NamedSharding(grid.mesh, spec))
+
+    params = jax.tree.map(on, _dense_given(), model.param_specs())
+    compiled = model._generate_prog(1, Sb, 1000, 0.0).lower(
+        params, on(jax.ShapeDtypeStruct((1, Sb), jnp.int32), P("dp", None)),
+        on(jax.ShapeDtypeStruct((), jnp.int32)),
+        jax.eval_shape(lambda: jax.random.key(0))).compile()
+    calls = re.findall(r"%lane_attention\S* = f32\[1,16,128\]\S* custom-call\("
+                       r"[^\n]*bf16\[1,2024,2048\]", compiled.as_text())
+    assert len(calls) == 8, calls                       # one a layer
+    # the float32 weights handed in and what the program makes of them (a
+    # bfloat16 copy, the lanes) fit one chip's 16 GB
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 12e9
 
 
 _WQKV = 8 * 2048 * 3 * 16 * 128                 # the stacked QKV weights
 
 
-@pytest.mark.parametrize("bucket", [1024, 32])
+@pytest.mark.parametrize("bucket", [1024, 512, 256, 128, 64, 32])
 def test_dense_prefill_at_the_cell_size_is_o_prompt(topo, on_chip, bucket):
-    """The dense prefill programs of the cell's largest and smallest
-    buckets: one slot's ``bucket`` rows a layer are written in place, and
-    but for the QKV weights (a prompt of 128 tokens or more re-lays them
-    for its wide product: not the cache's) no copy is larger than the
-    prompt's own rows: a prefill is O(prompt), not O(cache) (it took
-    36.9 ms whatever the prompt while each of the two arenas was copied
-    twice: PERF.md section 5, PR 32)."""
+    """The dense prefill programs of every bucket of the cell: one slot's
+    ``bucket`` rows a layer are written in place (``cache_store``, rows of
+    the lane's own flat form), and but for the QKV weights (a prompt of 128
+    tokens or more re-lays them for its wide product: not the cache's) no
+    copy is larger than the prompt's own rows: a prefill is O(prompt), not
+    O(cache) (it took 36.9 ms whatever the prompt while each of the two
+    arenas was copied twice: PERF.md section 5, PR 32). Nothing but the
+    slot writes touches a lane."""
     compiled = _dense_compiled(topo, bucket)
     _assert_touches_no_lane(compiled)
     rest = [n for n in _copy_sizes(compiled) if n != _WQKV]
     assert max(rest) <= 2 * bucket * 16 * 128 <= 2 * _SLOT_LANE, max(rest)
+    _assert_lanes_read_by_the_kernel(compiled, 0, _DENSE_LANE_SHAPE)
 
 
 @pytest.mark.parametrize("program", ["step", 1024, 32])

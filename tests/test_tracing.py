@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 
 import heat_tpu as ht
+from heat_tpu.core import lane_kernels as lk
 from heat_tpu.core import pallas_kernels as pk
 from heat_tpu.nn.transformer import TransformerLM, TransformerLMConfig
 from heat_tpu.utils import profiling as prof
@@ -423,9 +424,19 @@ def test_every_pallas_call_has_its_name(pallas_on):
         lambda a, b, m: pk.kmeans_step_tile(a, b, m, block_rows=128,
                                             sums_mode="loop"))(
         xs, cs, mask).jaxpr, []) == ["kmeans_step_tile"]
-    # and no pallas_call in the file is left without one
-    src = open(pk.__file__).read()
-    assert src.count("pl.pallas_call(") == src.count("        name=\"") == 5
+    ql, lane = jnp.ones((2, 4, 8), jnp.float32), jnp.ones((2, 16, 32),
+                                                          jnp.float32)
+    heads = (0, 1, 2, 3)
+    assert _kernel_names(jax.make_jaxpr(
+        lambda q, k, v, s: lk.lane_attention(q, k, v, s, k_of=heads,
+                                             v_of=heads, scale=0.5))(
+        ql, lane, lane, jnp.ones(2, jnp.int32)).jaxpr, []) \
+        == ["lane_attention"]
+    # and no pallas_call in either file is left without one
+    for mod, calls in ((pk, 5), (lk, 1)):
+        src = open(mod.__file__).read()
+        assert src.count("pl.pallas_call(") == src.count("        name=\"") \
+            == calls, mod.__name__
 
 
 # ---------------------------------------------------------------------- #
