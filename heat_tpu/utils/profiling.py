@@ -1,7 +1,7 @@
 """Profiling: the ONE module through which the program names its work.
 
 The reference has **no tracing/profiling support** (SURVEY.md §5 —
-benchmarks use bare ``time.perf_counter``). Here three things carry names,
+benchmarks use bare ``time.perf_counter``). Here four things carry names,
 and every one of them goes through this module (``grep named_scope
 TraceAnnotation heat_tpu/`` finds only this file):
 
@@ -19,6 +19,23 @@ TraceAnnotation heat_tpu/`` finds only this file):
   another thread (a request: submitted by a client, finished by the engine's
   worker); those live in the ring only, because a ``TraceAnnotation`` belongs
   to one thread.
+* **Compiler boundary** — every trace, lowering and backend compile JAX
+  makes becomes a record: ``jit.trace``, ``jit.lower`` and ``jit.compile``,
+  each with ``fun`` (JAX's name of the function: ``g``, ``jit(g)``), under
+  the recording thread's current span. A ``jit.compile`` also says what
+  JAX's persistent cache did: ``cache`` is ``hit`` (loaded), ``miss``
+  (looked up, not found, compiled; ``writable``, whether JAX then wrote it,
+  which it does when the compile reached ``jax_persistent_cache_min_compile_time_secs`` as it stood then:
+  only such a program should load in the next process) or ``off`` (no cache
+  directory, or no lookup). They come from ``jax.monitoring`` listeners, and
+  JAX reports a compile only AFTER it is over, with its start and end on
+  ``time.time()``: no ``TraceAnnotation`` can be opened around it, so these
+  are records alone, put on the ring's clock by the offset between the two
+  clocks read as JAX reports each (an offset read once drifts as the wall
+  clock is slewed). They are kept apart from the ring, one a compile event
+  and at most ``RING_SIZE``, so that a long run's spans never push set-up's
+  out, and :func:`spans` returns both; each carries ``n``, its place among
+  them since :func:`clear`, so that a reader can tell whether it has all.
 * **Operator's tools** — :func:`trace` / :func:`start_trace` /
   :func:`stop_trace` (TensorBoard/XProf device traces) and :class:`Timer`
   (a wall timer that syncs, so users do not time the dispatch).
@@ -26,7 +43,9 @@ TraceAnnotation heat_tpu/`` finds only this file):
 Recording is ON while a ``jax.profiler`` session is active (however it was
 started: ``trace`` here, a bare ``jax.profiler.trace``, a benchmark's
 ``--trace 1``) or after :func:`enable`. OFF, :func:`span` and :func:`begin`
-return one shared do-nothing object: no allocation, no ``TraceAnnotation``.
+return one shared do-nothing object: no allocation, no ``TraceAnnotation``;
+the compile listeners return at their first test, and JAX calls them only
+when something compiles, never on a step.
 Counters are not here: ``utils/metrics.py`` is the one registry.
 """
 
@@ -48,6 +67,15 @@ __all__ = ["trace", "start_trace", "stop_trace", "Timer",
 
 SPAN_PREFIX = "ht."     # host-plane name of a span: "ht." + name
 RING_SIZE = 65536       # records kept; older ones drop (counted)
+# JAX's compile events (jax/_src/dispatch.py, jax/_src/compiler.py) and the
+# ring's names for them
+_COMPILE_SPANS = {"/jax/core/compile/jaxpr_trace_duration": "jit.trace",
+                  "/jax/core/compile/jaxpr_to_mlir_module_duration": "jit.lower",
+                  "/jax/core/compile/backend_compile_duration": "jit.compile"}
+_CACHE_EVENTS = {"/jax/compilation_cache/compile_requests_use_cache": "lookup",
+                 "/jax/compilation_cache/cache_hits": "hit",
+                 # raised when JAX WRITES an entry, not on every miss
+                 "/jax/compilation_cache/cache_misses": "written"}
 
 
 def start_trace(logdir: str) -> None:
@@ -131,6 +159,9 @@ _lock = threading.Lock()
 _dropped = 0
 _ids = itertools.count(1)
 _tls = threading.local()
+# the jit.* records: out of the ring's reach, one a trace, lowering or compile
+_compiles: "collections.deque[SpanRecord]" = collections.deque(maxlen=RING_SIZE)
+_n_compiles = 0
 
 
 def _find_profile_state():
@@ -171,9 +202,9 @@ def disable() -> None:
 
 
 def spans() -> list:
-    """A snapshot of the ring, oldest first."""
+    """A snapshot of the ring and the ``jit.*`` records, by end time."""
     with _lock:
-        return list(_ring)
+        return sorted(itertools.chain(_ring, _compiles), key=lambda r: r.t1)
 
 
 def dropped() -> int:
@@ -182,10 +213,12 @@ def dropped() -> int:
 
 
 def clear() -> None:
-    global _dropped
+    global _dropped, _n_compiles
     with _lock:
         _ring.clear()
+        _compiles.clear()
         _dropped = 0
+        _n_compiles = 0
 
 
 def _stack() -> list:
@@ -274,6 +307,63 @@ def begin(name: str, parent=None, **attrs):
     sp = _Span(name, attrs, parent.id if parent is not None else 0)
     sp.t0 = time.perf_counter()
     return sp
+
+
+# ---------------------------------------------------------------------- #
+# compiler boundary: JAX's compile events, after the fact                #
+# ---------------------------------------------------------------------- #
+def _cache_seen() -> dict:
+    """This thread's persistent-cache events since its last ``jit.compile``:
+    what -> ``time.time()``."""
+    try:
+        return _tls.cache
+    except AttributeError:
+        _tls.cache = {}
+        return _tls.cache
+
+
+def _on_cache_event(event: str, **_kw) -> None:
+    if not (_enabled or _state.profile_session is not None):
+        return
+    what = _CACHE_EVENTS.get(event)
+    if what is not None:
+        _cache_seen()[what] = time.time()
+
+
+def _on_compile_span(event: str, start: float, end: float,
+                     fun_name: str = "", **_kw) -> None:
+    global _n_compiles
+    if not (_enabled or _state.profile_session is not None):
+        return
+    name = _COMPILE_SPANS.get(event)
+    if name is None:
+        return
+    attrs = {"fun": fun_name}
+    if name == "jit.compile":
+        # the cache events this thread raised inside this compile's interval
+        seen, _tls.cache = _cache_seen(), {}
+        inside = {k for k, t in seen.items() if start <= t <= end}
+        if "hit" in inside:
+            attrs["cache"] = "hit"
+        elif "lookup" in inside and jax.config.jax_compilation_cache_dir:
+            # (JAX computes a key and "looks up" with no directory too)
+            attrs["cache"] = "miss"
+            attrs["writable"] = "written" in inside
+        else:
+            attrs["cache"] = "off"
+    to_perf = time.perf_counter() - time.time()
+    stack = _stack()
+    parent = stack[-1].id if stack else 0
+    with _lock:
+        _n_compiles += 1
+        attrs["n"] = _n_compiles
+        _compiles.append(SpanRecord(
+            name, start + to_perf, end + to_perf, next(_ids), parent,
+            threading.get_ident(), attrs, ()))
+
+
+jax.monitoring.register_event_time_span_listener(_on_compile_span)
+jax.monitoring.register_event_listener(_on_cache_event)
 
 
 class Timer:

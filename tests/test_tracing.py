@@ -199,6 +199,112 @@ def test_named_sets_the_module_name():
 
 
 # ---------------------------------------------------------------------- #
+# the compiler boundary: JAX's compile events as ring records            #
+# ---------------------------------------------------------------------- #
+def _compiled(fn, name):
+    """Run a fresh jit of `fn` named `name`; its jit.* records."""
+    jax.jit(prof.named(fn, name))(jnp.ones(3, jnp.float32)).block_until_ready()
+    return [r for r in prof.spans() if r.name.startswith("jit.")
+            and r.attrs["fun"] in (name, f"jit({name})")]
+
+
+def test_compiles_leave_no_record_while_off():
+    prof.disable()
+    prof.clear()
+    assert _compiled(lambda x: x * 5.0 - 1.0, "unrecorded_fn") == []
+    assert prof.spans() == []
+
+
+def test_compile_records_carry_the_name_and_nest_in_the_span(recording):
+    with prof.span("outer") as outer:
+        recs = _compiled(lambda x: jnp.sin(x) * 2.0, "named_fn")
+    by = {r.name: r for r in recs}
+    assert set(by) == {"jit.trace", "jit.lower", "jit.compile"}
+    assert by["jit.trace"].attrs["fun"] == "named_fn"
+    assert by["jit.lower"].attrs["fun"] == by["jit.compile"].attrs["fun"] \
+        == "jit(named_fn)"
+    outer = next(r for r in prof.spans() if r.name == "outer")
+    for r in recs:
+        assert r.parent_id == outer.id and r.thread == threading.get_ident()
+        assert outer.t0 <= r.t0 <= r.t1 <= outer.t1
+    assert by["jit.trace"].t1 <= by["jit.lower"].t0 \
+        <= by["jit.lower"].t1 <= by["jit.compile"].t0
+    jit = [r for r in prof.spans() if r.name.startswith("jit.")]
+    assert sorted(r.attrs["n"] for r in jit) == list(range(1, len(jit) + 1))
+
+
+def test_compile_records_say_what_the_persistent_cache_did(recording,
+                                                           tmp_path):
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    keys = ("jax_compilation_cache_dir",
+            "jax_persistent_cache_min_compile_time_secs",
+            "jax_enable_compilation_cache")
+    was = {k: getattr(jax.config, k) for k in keys}
+
+    def fn(x):
+        return x * 3.0 + 1.0
+
+    try:
+        jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+        cc.reset_cache()
+        first = _compiled(fn, "cached_fn")[-1]
+        assert first.attrs["cache"] == "miss" and first.attrs["writable"]
+        jax.clear_caches()
+        prof.clear()
+        again = _compiled(fn, "cached_fn")[-1]
+        assert again.attrs["cache"] == "hit"
+        assert "writable" not in again.attrs
+        jax.config.update("jax_enable_compilation_cache", False)
+        cc.reset_cache()
+        jax.clear_caches()
+        off = _compiled(fn, "cached_fn")[-1]
+        assert off.attrs["cache"] == "off"
+    finally:
+        for k, v in was.items():
+            jax.config.update(k, v)
+        cc.reset_cache()
+
+
+def test_setup_split_counts_the_union_and_the_written_misses():
+    from perfbench.tools import setup_split
+
+    def rec(name, t0, t1, n, thread=1, **attrs):
+        return prof.SpanRecord(name, t0, t1, 100 + n, 0, thread,
+                               dict(attrs, n=n), ())
+
+    recs = [rec("jit.trace", 1.0, 3.0, 1, fun="f"),
+            rec("jit.trace", 2.5, 2.8, 2, fun="g"),          # nested
+            rec("jit.lower", 2.0, 4.0, 3, thread=2, fun="jit(h)"),
+            rec("jit.compile", 5.0, 6.0, 4, fun="jit(f)", cache="miss",
+                writable=True),
+            rec("jit.compile", 5.5, 7.0, 5, thread=2, fun="jit(h)",
+                cache="miss", writable=False),                # under threshold
+            rec("jit.compile", 8.0, 8.5, 6, fun="jit(k)", cache="hit"),
+            rec("jit.compile", 9.5, 11.0, 7, fun="jit(w)", cache="miss",
+                writable=True),                               # ends in window
+            prof.SpanRecord("decode.request", 0.5, 9.0, 1, 0, 1, {}, ())]
+    out = setup_split.split(recs, 10.0, 12.0)
+    assert out["setup_compile_s"] == pytest.approx(3.0 + 2.0 + 0.5)
+    assert out["to_first_compile_s"] == pytest.approx(1.0 - (10.0 - 12.0))
+    assert out["jit.trace_s"] == pytest.approx(2.0)
+    assert [out[k]["n"] for k in ("hit", "miss_written", "miss_unwritten",
+                                  "off")] == [1, 1, 1, 0]
+    assert out["written"] == [["jit(f)", 1.0]]
+    assert out["heaviest"][:2] == [["h", 3.5, ["miss_unwritten"]],
+                                   ["f", 3.0, ["miss_written"]]]
+    assert out["warm_s"] == pytest.approx(9.5)
+    assert out["compile_in_warm_s"] == pytest.approx(5.5)    # all after 0.5
+    assert out["rest_s"] == pytest.approx(12.0 - 9.5)
+    assert setup_split.split(recs[:2], 10.0, 12.0)["setup_compile_s"] \
+        == pytest.approx(2.0)
+    # a record pushed out (a gap in `n`), or no record at all: nothing
+    for kept in (recs[1:], recs[:3] + recs[4:], recs[-1:], []):
+        assert setup_split.split(kept, 10.0, 12.0) is None
+
+
+# ---------------------------------------------------------------------- #
 # names inside the programs                                              #
 # ---------------------------------------------------------------------- #
 def test_train_step_carries_every_lm_scope_and_its_module_name():
