@@ -36,6 +36,7 @@ import numpy as np
 from jax import lax
 
 from ..core.lane_kernels import lane_attention
+from ..core.latent_kernels import latent_attention
 from ..core.pallas_kernels import interpret_vma_hazard, pallas_enabled
 from ..utils.profiling import scope
 from .attention import local_attention
@@ -520,12 +521,14 @@ def mla_step(p, q_nope, q_rope, lane, seen, scale: float):
     in the ABSORBED form: a
     head's query in latent space q_lat,h = q_nope,h W_UK,h^T (C wide), its
     scores [q_lat,h | q_rope,h] . [c_kv | k_r] a row, its output (sum p
-    c_kv) W_UV,h. Every head reads the same row of a position, so the core is
-    two plain matrix products a slot, (Sk, W) x (W, H) and back, that read
-    the lane where it lies (the query is padded with zeros to W; the second
-    takes the rope and pad columns too: the one that holds 1 gives the
-    softmax's sum). ``q_nope`` (B, 1, H, dn), ``q_rope`` (B, 1, H, dr).
-    Returns (B, 1, H, dv)."""
+    c_kv) W_UV,h. Every head reads the same row of a position (the query is
+    padded with zeros to W). Where the kernels run, the core is
+    :func:`~heat_tpu.core.latent_kernels.latent_attention`: each slot's lane
+    read once, only up to ``seen``, for both products. Else it is two plain
+    matrix products a slot, (Sk, W) x (W, H) and back, that read the whole
+    lane where it lies (the second takes the rope and pad columns too: the
+    one that holds 1 gives the softmax's sum). ``q_nope`` (B, 1, H, dn),
+    ``q_rope`` (B, 1, H, dr). Returns (B, 1, H, dv)."""
     C = p["w_uk"].shape[-1]
     B, Sk, W = lane.shape
     H, dr, dt = q_rope.shape[2], q_rope.shape[-1], lane.dtype
@@ -540,20 +543,23 @@ def mla_step(p, q_nope, q_rope, lane, seen, scale: float):
                              jnp.zeros((B, H, W - C - dr), dt)],
                             axis=-1)                            # (B, H, W)
     with scope("attn.core"), scope("attn.mla"):
-        s = jnp.einsum("bkx,bxh->bhk", lane, jnp.swapaxes(q, 1, 2),
-                       preferred_element_type=F32) * scale
-        s = jnp.where(jnp.arange(Sk)[None, None, :] < seen[:, None, None], s,
-                      -jnp.inf)
-        # the softmax's weights go to the second product UNNORMALISED, in the
-        # lane's dtype; a row's column C + dr holds 1, so the product's
-        # column there is their sum: no pass over the (B, H, Sk) map of its
-        # own to normalise it or to sum it
-        e = jnp.exp(s - jnp.max(s, axis=-1, keepdims=True))
-        o = jnp.einsum("bhk,bkx->bhx", e.astype(dt), lane,
-                       preferred_element_type=F32)
-        o = o[..., :C] / o[..., C + dr:C + dr + 1]
+        if lanes_in_kernel(q, lane, lane):
+            o = latent_attention(q, lane, seen, C=C, scale=scale)
+        else:
+            s = jnp.einsum("bkx,bxh->bhk", lane, jnp.swapaxes(q, 1, 2),
+                           preferred_element_type=F32) * scale
+            s = jnp.where(
+                jnp.arange(Sk)[None, None, :] < seen[:, None, None], s,
+                -jnp.inf)
+            # the softmax's weights go to the second product UNNORMALISED,
+            # in the lane's dtype; a row's column C + dr holds 1, so the
+            # product's column there is their sum: no pass over the (B, H,
+            # Sk) map of its own to normalise it or to sum it
+            e = jnp.exp(s - jnp.max(s, axis=-1, keepdims=True))
+            o = jnp.einsum("bhk,bkx->bhx", e.astype(dt), lane,
+                           preferred_element_type=F32)
+            o = o[..., :C] / o[..., C + dr:C + dr + 1]
     with scope("attn.proj"):
-        o = jnp.einsum("hbc,hcv->bhv",
-                       jnp.swapaxes(o[..., :C], 0, 1).astype(dt),
+        o = jnp.einsum("hbc,hcv->bhv", jnp.swapaxes(o, 0, 1).astype(dt),
                        p["w_uv"].astype(dt), preferred_element_type=F32)
     return o[:, None].astype(q_nope.dtype)

@@ -47,6 +47,8 @@ from ..core._compat import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..core.communication import MeshGrid
+from ..core.latent_kernels import latent_rows
+from ..core.pallas_kernels import pallas_enabled
 from ..utils.profiling import scope, span
 from . import mixers, parallel
 from .attention import (_ring_body, _ulysses_core, _zigzag_core,
@@ -1450,6 +1452,15 @@ class TransformerLM:
         product sum the softmax's weights too (``mixers.mla_step``)."""
         c = self.cfg
         return -(-(c.kv_lora_rank + c.qk_rope_head_dim + 1) // 128) * 128
+
+    def latent_block(self, s_cap: int) -> int:
+        """Rows of the blocks in which a step's latent core fetches a slot's
+        lane of ``s_cap`` rows where the kernels run
+        (``latent_kernels.latent_attention``, from ``mixers.mla_step``); 0
+        where it reads whole lanes in XLA or the model has no "mla" layer."""
+        if "mla" not in self.kinds or not pallas_enabled():
+            return 0
+        return latent_rows(s_cap)
 
     def _mla_in(self, p, u, pos):
         """A "mla" layer's queries and latent row of ``u`` (B, S, D) at

@@ -269,6 +269,13 @@ class DecodeEngine:
         # slot's length (prompt + tokens generated), kept beside `_live`
         self._attended = 0
         self._slot_len = np.zeros(self.slots, np.int64)
+        # a model with "mla" layers whose step reads its latent lanes in the
+        # kernel: the rows it fetched, a live slot's blocks up to its
+        # position in every such layer, counted beside `_attended` (0 where
+        # the core reads whole lanes in XLA)
+        self._latent_layers = model.kinds.count("mla")
+        self._latent_block = model.latent_block(self.S_cap)
+        self._latent_rows = 0
         self._fallbacks = 0
         # a model with "moe" layers: pairs routed (all experts counted), and
         # those that fell on the experts held here, by expert
@@ -489,9 +496,9 @@ class DecodeEngine:
         occ = list(self._occupancy)
         adm = self._admission
         with self._count_lock:
-            prefills, steps, tokens_out, attended = (
+            prefills, steps, tokens_out, attended, latent_rows = (
                 self._prefills, self._steps, self._tokens_out,
-                self._attended)
+                self._attended, self._latent_rows)
         moe = {} if not self._moe_layers else {
             "moe_pairs_total": self._moe_pairs,
             "moe_pairs_held": int(self._moe_held.sum()),
@@ -511,6 +518,7 @@ class DecodeEngine:
             "decode_steps": steps,
             "tokens_out": tokens_out,
             "attended_positions": attended,
+            "latent_rows_fetched": latent_rows,
             "decode_fallbacks": self._fallbacks,
             "program_cache": self.program_cache.stats(),
             "tenants": adm.tenant_stats() if adm is not None else {},
@@ -928,6 +936,9 @@ class DecodeEngine:
         n_lp = self.slots * bool(self.config.logprobs)
         # a live slot's fed token sits at position prompt + generated - 1
         attended = int(self._slot_len[live].sum())
+        bk = self._latent_block
+        fetched = bk and self._latent_layers * int(np.minimum(
+            -(-self._slot_len[live] // bk) * bk, self.S_cap).sum())
         self._slot_len[live] += 1
         if record:
             n_live = int(live.sum())
@@ -936,6 +947,7 @@ class DecodeEngine:
                 self._steps += 1
                 self._tokens_out += n_live
                 self._attended += attended
+                self._latent_rows += fetched
             _pm.inc("serve.decode_steps")
             _pm.inc("serve.decode_tokens_out", n_live)
         return got[:self.slots], got[self.slots:self.slots + n_lp].view(

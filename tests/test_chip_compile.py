@@ -261,15 +261,16 @@ def _lane_touches(compiled, *lanes):
     return out
 
 
-def _assert_lanes_read_by_the_kernel(compiled, kernels, *lanes):
-    """``kernels`` calls of ``lane_attention``, each under ``attn.core``;
-    every other instruction that touches a lane writes rows under
+def _assert_lanes_read_by_the_kernel(compiled, kernels, *lanes,
+                                     kernel="lane_attention"):
+    """``kernels`` calls of ``kernel``, each under ``attn.core``; every
+    other instruction that touches a lane writes rows under
     ``cache.write``."""
     touches = _lane_touches(compiled, *lanes)
-    calls = [m for n, m in touches if n.startswith("%lane_attention")]
+    calls = [m for n, m in touches if n.startswith("%" + kernel)]
     assert len(calls) == kernels, calls
-    assert all("/attn.core/" in m and "lane_attention" in m for m in calls)
-    rest = [(n, m) for n, m in touches if not n.startswith("%lane_attention")]
+    assert all("/attn.core/" in m and kernel in m for m in calls)
+    rest = [(n, m) for n, m in touches if not n.startswith("%" + kernel)]
     assert rest and all("/cache.write" in m for _n, m in rest), \
         [t for t in rest if "/cache.write" not in t[1]]
 
@@ -737,23 +738,29 @@ def _pangu_compiled(topo, program):
 
 
 def test_latent_decode_step_at_published_widths_reads_its_lanes_in_place(
-        topo):
+        topo, on_chip):
     """The share's step program compiles for one v5e beside its 13.19 GB of
     parameters and latent lanes, with the lanes updated and read in place and
     no weight re-laid: a row of 576 held 576 wide made the chip's default
     layout put the POSITIONS minor, and the step copied every lane three
     times (12 GB a step); W_UQ seen together with the split into heads was
     re-laid every layer (PERF.md section 6, PR 39). The 8 grouped products
-    are the TPU's own kernel."""
+    are the TPU's own kernel. The five latent lanes are read by five calls
+    of `latent_attention`, to each slot's position; nothing else but the
+    row writes touches a lane, and the float32 score maps of the whole-lane
+    form (128 x 128 x 4,096 x 4 B, 268 MB) are gone from the temporaries
+    (14.1 MB; 282 MB in the whole-lane form)."""
     compiled = _pangu_compiled(topo, "step")
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes < 13.25e9         # 9.84 + 3.36 GB
     assert mem.alias_size_in_bytes >= 5 * _PANGU_LANE * 2
-    assert mem.temp_size_in_bytes < 0.35e9
-    assert compiled.as_text().count("tpu_custom_call") >= 8
+    assert mem.temp_size_in_bytes < 40e6
+    assert compiled.as_text().count("tpu_custom_call") >= 8 + 5
     copies = _copy_sizes(compiled)
     assert copies and max(copies) < _PANGU_W_DQ, max(copies)
     assert not _argument_copies(compiled)
+    _assert_lanes_read_by_the_kernel(compiled, 5, (128, 4096, 640),
+                                     kernel="latent_attention")
 
 
 def test_latent_prefill_at_published_widths_fits_beside_the_cache(topo,
